@@ -30,11 +30,6 @@ __all__ = ["HaloExchanger1d", "halo_exchange_1d", "left_right_halo_exchange",
            "spatial_conv2d"]
 
 
-def _axis_size(axis_name: str) -> int:
-    # psum of a literal is evaluated statically; jax 0.4.x has no axis_size
-    return jax.lax.psum(1, axis_name)
-
-
 def left_right_halo_exchange(left_output_halo, right_output_halo,
                              axis_name: str):
     """Swap halos with the line neighbors (halo_exchangers.py:30-126).
@@ -44,7 +39,7 @@ def left_right_halo_exchange(left_output_halo, right_output_halo,
     arrived from the left and right neighbors — zero-filled at the ends of
     the line (non-periodic, the reference's low_zero/high_zero).
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     # y[i].right_input comes from x[i+1].left_output: perm (i+1 -> i)
     right_input = jax.lax.ppermute(
         left_output_halo, axis_name, [(i + 1, i) for i in range(n - 1)])

@@ -11,7 +11,6 @@ same way the reference's import guards allowed.
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,25 +30,6 @@ def register(name: str, description: str, pallas: bool, fallback: str = "jnp/XLA
 
 def available_features() -> dict[str, Feature]:
     return dict(_FEATURES)
-
-
-@functools.cache
-def on_tpu() -> bool:
-    import jax
-
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
-def pallas_enabled() -> bool:
-    """Whether Pallas TPU kernels should be used (TPU backend present)."""
-    import os
-
-    if os.environ.get("APEX_TPU_DISABLE_PALLAS", "0") == "1":
-        return False
-    return on_tpu()
 
 
 # Core features (mirrors SURVEY.md §2 component inventory).

@@ -5,12 +5,17 @@ The reference gates every fused path twice: once on "was the extension built"
 (``FusedScaleMaskSoftmax.is_kernel_available``,
 apex/transformer/functional/fused_softmax.py:164-275).  Here the analogs are:
 
-- :func:`on_tpu` — Pallas TPU kernels only lower on a TPU backend.
+- :func:`on_tpu` — Pallas TPU kernels only lower on a TPU backend.  A
+  backend that fails to initialize (e.g. the chip is held by another
+  process) raises here: answering "not a TPU" would send the whole model
+  to the jnp references and report a slow, green run.
 - ``APEX_TPU_KERNELS`` env var — ``"0"`` disables Pallas everywhere
   (pure-jnp fallbacks, still jitted/fused by XLA), ``"interpret"`` runs
   Pallas kernels in interpreter mode so CPU tests exercise the kernel code
-  path itself.
-- per-op shape predicates live next to each kernel.
+  path itself.  On a TPU backend ``"interpret"`` is an error, never a
+  quiet interpreter run.
+- per-op shape predicates live next to each kernel; with kernels enabled,
+  each call site reports which side it took through :func:`record_dispatch`.
 """
 
 from __future__ import annotations
@@ -20,20 +25,26 @@ import os
 
 import jax
 
+from apex_tpu._logging import emit_event
+
 _ENV = "APEX_TPU_KERNELS"
 
 
 @functools.lru_cache(maxsize=None)
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - backend init failure
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def use_interpret() -> bool:
     """Run Pallas kernels in interpret mode (CPU testing of kernel code)."""
-    return os.environ.get(_ENV, "").lower() == "interpret"
+    if os.environ.get(_ENV, "").lower() != "interpret":
+        return False
+    if on_tpu():
+        raise RuntimeError(
+            f"{_ENV}=interpret on a TPU backend: the Pallas interpreter "
+            f"is the CPU test handle; unset {_ENV} to compile the "
+            f"kernels with Mosaic")
+    return True
 
 
 def kernels_enabled() -> bool:
@@ -42,8 +53,25 @@ def kernels_enabled() -> bool:
     if mode == "0":
         return False
     if mode == "interpret":
-        return True
+        return use_interpret()
     return on_tpu()
+
+
+def record_dispatch(op: str, shape_ok: bool, **shape) -> bool:
+    """``kernels_enabled() and shape_ok``, reported.
+
+    With kernels enabled, a call site whose shape predicate fails runs
+    its jnp reference instead: numerically the same, an order of
+    magnitude slower, and otherwise invisible.  Each such decision is
+    emitted once per trace as a ``kernel_dispatch`` event (``path`` is
+    ``"pallas"`` or ``"reference"``) so a caller — ``chip_smoke.py`` —
+    can state which path every call site took.  With kernels disabled
+    there is no decision to report and nothing is emitted."""
+    if not kernels_enabled():
+        return False
+    emit_event("kernel_dispatch", op=op,
+               path="pallas" if shape_ok else "reference", **shape)
+    return bool(shape_ok)
 
 
 def lane_aligned(*dims: int, lane: int = 128) -> bool:

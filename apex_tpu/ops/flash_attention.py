@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops._dispatch import kernels_enabled, use_interpret
+from apex_tpu.ops._dispatch import record_dispatch, use_interpret
 
 _NEG_INF = -1e30
 # Large default tiles: at head dims of 64-128 a (128, d) step is too little
@@ -310,6 +310,7 @@ def _pallas_fwd(q, k, v, qseg, kseg, seed, causal, scale, block_q, block_k,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=use_interpret(),
+        name="flash_attention_fwd",
     )(seed, q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
       v.reshape(b * h, sk, d), qseg3, kseg3)
     return (o.reshape(b, h, sq, d), lse[:, :, 0].reshape(b, h, sq))
@@ -460,6 +461,7 @@ def _pallas_bwd(q, k, v, o, lse, do, qseg, kseg, seed, causal, scale,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=use_interpret(),
+        name="flash_attention_dq",
     )(seed, q3, k3, v3, do3, lse3, delta3, qseg3, kseg3)
 
     sqspec2, skspec2 = _seg_specs(b, h, bq, bk, has_seg)
@@ -493,6 +495,7 @@ def _pallas_bwd(q, k, v, o, lse, do, qseg, kseg, seed, causal, scale,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=use_interpret(),
+        name="flash_attention_dkv",
     )(seed, q3, k3, v3, do3, lse3, delta3, qseg3, kseg3)
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
             dv.reshape(b, h, sk, d))
@@ -529,13 +532,14 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _kernel_ok(q, k, block_q, block_k) -> bool:
-    if not kernels_enabled():
-        return False
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bq, bk = min(block_q, sq), min(block_k, sk)
-    return (d % 64 == 0 and sq % bq == 0 and sk % bk == 0
-            and bq % 8 == 0 and bk % 8 == 0)
+    return record_dispatch(
+        "flash_attention",
+        d % 64 == 0 and sq % bq == 0 and sk % bk == 0
+        and bq % 8 == 0 and bk % 8 == 0,
+        sq=sq, sk=sk, d=d)
 
 
 def flash_attention(q, k, v, *, causal: bool = False,

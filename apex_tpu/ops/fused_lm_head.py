@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops._dispatch import kernels_enabled, use_interpret
+from apex_tpu.ops._dispatch import record_dispatch, use_interpret
 
 __all__ = ["fused_lm_head_loss", "lm_head_loss_reference"]
 
@@ -162,6 +162,7 @@ def _pallas_fused_fwd(h2, e, labels, tb, vb):
                         pltpu.VMEM((tb, 128), jnp.float32),
                         pltpu.VMEM((tb, 1), jnp.float32)],
         interpret=use_interpret(),
+        name="fused_lm_head_fwd",
     )(h2, ep, _lane_tile(labels, jnp.int32))
     return loss[:, 0], lse[:, 0]
 
@@ -270,6 +271,7 @@ def _pallas_bwd(h2, e, labels, lse, g, tb, vb):
         out_shape=jax.ShapeDtypeStruct((t, hid), h2.dtype),
         scratch_shapes=[pltpu.VMEM((tb, hid), jnp.float32)],
         interpret=use_interpret(),
+        name="fused_lm_head_dh",
     )(h2, ep, lab3, lse3, g3)
 
     de = pl.pallas_call(
@@ -286,6 +288,7 @@ def _pallas_bwd(h2, e, labels, lse, g, tb, vb):
         out_shape=jax.ShapeDtypeStruct((vp, hid), e.dtype),
         scratch_shapes=[pltpu.VMEM((vb, hid), jnp.float32)],
         interpret=use_interpret(),
+        name="fused_lm_head_de",
     )(h2, ep, lab3, lse3, g3)
     return dh, de[:e.shape[0]]
 
@@ -311,7 +314,9 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
 def _kernel_ok(t, hid, block_t) -> bool:
-    return (kernels_enabled() and t % block_t == 0 and hid % 128 == 0)
+    return record_dispatch("fused_lm_head",
+                           t % block_t == 0 and hid % 128 == 0,
+                           tokens=t, hid=hid)
 
 
 def fused_lm_head_loss(hidden, embedding, labels, *, block_t: int = 512,

@@ -24,9 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops._dispatch import kernels_enabled, lane_aligned, use_interpret
-
-_INTERPRET = use_interpret
+from apex_tpu.ops._dispatch import lane_aligned, record_dispatch, use_interpret
 
 # Rows per grid step; amortizes the per-step overhead while keeping the
 # (block_rows, H) tile + fp32 temps within VMEM for H up to ~16k.
@@ -194,7 +192,8 @@ def _pallas_forward(x2d, weight, bias, eps, rms_only):
             jax.ShapeDtypeStruct((grid, _BLOCK_ROWS), jnp.float32),
             jax.ShapeDtypeStruct((grid, _BLOCK_ROWS), jnp.float32),
         ],
-        interpret=_INTERPRET(),
+        interpret=use_interpret(),
+        name="rms_norm_fwd" if rms_only else "layer_norm_fwd",
     )(x2d, w, b)
     mean, rstd = mean.reshape(np_), rstd.reshape(np_)
     if pad:
@@ -245,7 +244,8 @@ def _pallas_backward(dy2d, xin2d, mean, rstd, weight, bias, rms_only, mem_eff):
             jax.ShapeDtypeStruct((1, h), jnp.float32),
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
-        interpret=_INTERPRET(),
+        interpret=use_interpret(),
+        name="rms_norm_bwd" if rms_only else "layer_norm_bwd",
     )(dy2d, xin2d, mean2, rstd2, w, b)
     if pad:
         dx = dx[:n]
@@ -263,9 +263,10 @@ _MAX_H = 4096
 _MAX_ROWS = 256 * 1024
 
 
-def _kernel_ok(n: int, h: int) -> bool:
-    return (kernels_enabled() and lane_aligned(h)
-            and h <= _MAX_H and n <= _MAX_ROWS)
+def _kernel_ok(op: str, n: int, h: int) -> bool:
+    return record_dispatch(
+        op, lane_aligned(h) and h <= _MAX_H and n <= _MAX_ROWS,
+        rows=n, h=h)
 
 
 # ---------------------------------------------------------------------------
@@ -282,7 +283,7 @@ def _norm_fwd(x, weight, bias, eps, rms_only, memory_efficient):
     shape = x.shape
     h = shape[-1]
     x2d = x.reshape(-1, h)
-    if _kernel_ok(x2d.shape[0], h):
+    if _kernel_ok("norm_fwd", x2d.shape[0], h):
         y2d, mean, rstd = _pallas_forward(x2d, weight, bias, eps, rms_only)
     else:
         y2d, mean, rstd = _jnp_forward(x2d, weight, bias, eps, rms_only)
@@ -296,7 +297,7 @@ def _norm_bwd(eps, rms_only, memory_efficient, res, dy):
     shape = dy.shape
     h = shape[-1]
     dy2d = dy.reshape(-1, h)
-    if _kernel_ok(dy2d.shape[0], h):
+    if _kernel_ok("norm_bwd", dy2d.shape[0], h):
         dx2d, dw, db = _pallas_backward(dy2d, saved, mean, rstd, weight, bias,
                                         rms_only, memory_efficient)
     else:

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops._dispatch import kernels_enabled, use_interpret
+from apex_tpu.ops._dispatch import record_dispatch, use_interpret
 
 _CHUNK = 64 * 1024  # elements per grid step; 4 fp32 buffers/step ≈ 1 MiB VMEM
 
@@ -95,7 +95,7 @@ def packed_adam_update(flat_grad, flat_param, flat_m, flat_v, *,
         jnp.asarray(bias_correction2, jnp.float32),
         jnp.asarray(0.0 if noop_flag is None else noop_flag, jnp.float32),
     ])
-    if not kernels_enabled() or n % 1024:
+    if not record_dispatch("packed_adam", n % 1024 == 0, n=n):
         # jnp fallback with identical math
         return _jnp_adam(flat_grad, flat_param, flat_m, flat_v, scalars, adam_w_mode)
     # View the 1024-aligned flat buffer as (rows, 128) so blocks satisfy the
@@ -119,6 +119,7 @@ def packed_adam_update(flat_grad, flat_param, flat_m, flat_v, *,
             jax.ShapeDtypeStruct((rows, 128), jnp.float32),
         ],
         interpret=use_interpret(),
+        name="packed_adam",
     )(as2d(flat_grad), as2d(flat_param), as2d(flat_m), as2d(flat_v), scalars)
     return p_new.reshape(n), m_new.reshape(n), v_new.reshape(n)
 
@@ -264,7 +265,7 @@ def packed_lamb_update(flat_grad, flat_param, flat_m, flat_v, seg_ids, *,
         jnp.asarray(global_clip, jnp.float32),
     ])
     p32 = flat_param.astype(jnp.float32)
-    if kernels_enabled() and n % 1024 == 0:
+    if record_dispatch("packed_lamb_phase1", n % 1024 == 0, n=n):
         rows = n // 128
         chunk_rows = min(_CHUNK // 128, rows)
         while rows % chunk_rows:
@@ -279,6 +280,7 @@ def packed_lamb_update(flat_grad, flat_param, flat_m, flat_v, seg_ids, *,
             out_specs=[block, block, block],
             out_shape=[jax.ShapeDtypeStruct((rows, 128), jnp.float32)] * 3,
             interpret=use_interpret(),
+            name="packed_lamb_phase1",
         )(as2d(flat_grad), as2d(flat_param), as2d(flat_m), as2d(flat_v),
           scalars)
         m_new, v_new, update = (m_new.reshape(n), v_new.reshape(n),
@@ -371,7 +373,7 @@ def packed_adagrad_update(flat_grad, flat_param, flat_h, *, lr, eps,
         jnp.asarray(weight_decay, jnp.float32),
         jnp.asarray(0.0 if noop_flag is None else noop_flag, jnp.float32),
     ])
-    if not kernels_enabled() or n % 1024:
+    if not record_dispatch("packed_adagrad", n % 1024 == 0, n=n):
         g = flat_grad.astype(jnp.float32)
         p = flat_param.astype(jnp.float32)
         if not adagrad_w_mode:
@@ -401,5 +403,6 @@ def packed_adagrad_update(flat_grad, flat_param, flat_h, *, lr, eps,
             jax.ShapeDtypeStruct((rows, 128), jnp.float32),
         ],
         interpret=use_interpret(),
+        name="packed_adagrad",
     )(as2d(flat_grad), as2d(flat_param), as2d(flat_h), scalars)
     return p_new.reshape(n), h_new.reshape(n)

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops._dispatch import kernels_enabled, use_interpret
+from apex_tpu.ops._dispatch import record_dispatch, use_interpret
 
 __all__ = ["pair_bias_flash_attention", "pair_bias_reference"]
 
@@ -260,6 +260,7 @@ def _pallas_fwd(q, k, v, bias, mask, scale, bq, bk):
                         pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=use_interpret(),
+        name="pair_bias_attention_fwd",
     )(q3, k3, v3, b3, m3)
     return o.reshape(R, h, s, d), lse[:, :, 0].reshape(R, h, s)
 
@@ -287,7 +288,7 @@ def _pallas_bwd(q, k, v, bias, mask, o, lse, do, scale, bq, bk):
         (lambda g, i, j: (0, 0, 0))
     mshape = (1, bk, 128) if has_mask else (1, 1, 128)
 
-    def call(kernel, grid, out_specs, out_shape, scratch, swap=False):
+    def call(kernel, name, grid, out_specs, out_shape, scratch, swap=False):
         # swap=True: grid is (g, k block, q block) — index maps flip i/j
         def fix(f):
             return (lambda g, j, i: f(g, i, j)) if swap else f
@@ -309,13 +310,16 @@ def _pallas_bwd(q, k, v, bias, mask, o, lse, do, scale, bq, bk):
             out_shape=out_shape,
             scratch_shapes=scratch,
             interpret=use_interpret(),
+            name=name,
         )(q3, k3, v3, b3, m3, do3, lse3, delta3)
 
-    dq = call(_dq_kernel, (R * h, s // bq, s // bk),
+    dq = call(_dq_kernel, "pair_bias_attention_dq",
+              (R * h, s // bq, s // bk),
               pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
               jax.ShapeDtypeStruct((R * h, s, d), q.dtype),
               [pltpu.VMEM((bq, d), jnp.float32)])
-    dk, dv = call(_dkv_kernel, (R * h, s // bk, s // bq),
+    dk, dv = call(_dkv_kernel, "pair_bias_attention_dkv",
+                  (R * h, s // bk, s // bq),
                   [pl.BlockSpec((1, bk, d), lambda g, j, i: (g, j, 0)),
                    pl.BlockSpec((1, bk, d), lambda g, j, i: (g, j, 0))],
                   [jax.ShapeDtypeStruct((R * h, s, d), k.dtype),
@@ -348,6 +352,7 @@ def _pallas_bwd(q, k, v, bias, mask, o, lse, do, scale, bq, bk):
         out_shape=jax.ShapeDtypeStruct((b * h, s, s), bias.dtype),
         scratch_shapes=[pltpu.VMEM((bq, bk), jnp.float32)],
         interpret=use_interpret(),
+        name="pair_bias_attention_dbias",
     )(q3, k3, v3, b3, m3, do3, lse3, delta3)
 
     return (dq.reshape(R, h, s, d), dk.reshape(R, h, s, d),
@@ -394,8 +399,9 @@ def pair_bias_flash_attention(q, k, v, bias, mask=None,
     b = bias.shape[0]
     scale = 1.0 if scale is None else float(scale)
     bq, bk = min(block_q, s), min(block_k, s)
-    ok = (kernels_enabled() and R % b == 0 and d % 8 == 0
-          and s % bq == 0 and s % bk == 0 and s % 128 == 0)
-    if ok:
+    if record_dispatch(
+            "pair_bias_attention",
+            R % b == 0 and d % 8 == 0 and s % bq == 0 and s % bk == 0
+            and s % 128 == 0, rows=R, s=s, d=d):
         return _flash(q, k, v, bias, mask, scale, bq, bk)
     return pair_bias_reference(q, k, v, bias, mask, scale)

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops._dispatch import kernels_enabled, lane_aligned, use_interpret
+from apex_tpu.ops._dispatch import lane_aligned, record_dispatch, use_interpret
 
 _MASK_VALUE = -10000.0  # matches scaled_masked_softmax.h additive fill
 _BLOCK_ROWS = 128
@@ -120,6 +120,7 @@ def _pallas_forward(x, scale, mask, causal):
         out_specs=pl.BlockSpec((1, rows, sk), lambda g, i: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, sk), x.dtype),
         interpret=use_interpret(),
+        name="scaled_softmax_fwd",
     )(x3, mask3)
     return y.reshape(b, h, sq, sk)
 
@@ -138,6 +139,7 @@ def _pallas_backward(y, dy, scale):
         out_specs=pl.BlockSpec((1, rows, sk), lambda g, i: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, sk), dy.dtype),
         interpret=use_interpret(),
+        name="scaled_softmax_bwd",
     )(y.reshape(b * h, sq, sk), dy.reshape(b * h, sq, sk))
     return dx.reshape(b, h, sq, sk)
 
@@ -149,12 +151,14 @@ def _pallas_backward(y, dy, scale):
 _MAX_SK = 4096
 
 
-def _kernel_ok(x) -> bool:
-    if not kernels_enabled() or x.ndim != 4:
-        return False
+def _kernel_ok(op: str, x) -> bool:
+    if x.ndim != 4:
+        return record_dispatch(op, False, ndim=x.ndim)
     sq, sk = x.shape[-2], x.shape[-1]
-    return (lane_aligned(sk) and sk <= _MAX_SK
-            and (sq % min(_BLOCK_ROWS, sq) == 0) and sq >= 8)
+    return record_dispatch(
+        op, lane_aligned(sk) and sk <= _MAX_SK
+        and (sq % min(_BLOCK_ROWS, sq) == 0) and sq >= 8,
+        sq=sq, sk=sk)
 
 
 # ---------------------------------------------------------------------------
@@ -168,7 +172,7 @@ def _softmax(x, mask, scale, causal):
 
 
 def _softmax_fwd(x, mask, scale, causal):
-    if _kernel_ok(x):
+    if _kernel_ok("softmax_fwd", x):
         y = _pallas_forward(x, scale, mask, causal)
     else:
         y = _jnp_softmax(x, scale, mask=mask, causal=causal)
@@ -178,7 +182,7 @@ def _softmax_fwd(x, mask, scale, causal):
 def _softmax_bwd(scale, causal, y, dy):
     # dx = scale * y * (dy - sum(y*dy)); masked rows have y == 0 so their
     # gradient is exactly 0, matching the CUDA backward.
-    if _kernel_ok(y):
+    if _kernel_ok("softmax_bwd", y):
         dx = _pallas_backward(y, dy, scale)
     else:
         y32 = y.astype(jnp.float32)

@@ -2,17 +2,16 @@
 
 Host-side I/O at pod scale — checkpoint writes to network filesystems,
 data fetches through a flaky storage frontend — fails *transiently* far
-more often than it fails *permanently* (PAPERS.md TPU-pod papers; the
-same observation drove bench.py's ``_TRANSIENT_MARKERS`` harness after
-round 3's capture died on one ``remote_compile`` blip).  This module is
-the one retry policy for all of them, with three properties the ad-hoc
+more often than it fails *permanently* (PAPERS.md TPU-pod papers).  This
+module is the one retry policy for all of them, with three properties the
+ad-hoc
 ``try/sleep/except`` it replaces never had:
 
 - **Classified**: only exceptions the policy names (by type, or by a
   status-code-anchored message marker) are retried.  Deterministic
   failures — a ``CheckpointError`` from corrupt bytes, a shape bug —
   propagate on the first attempt; retrying them only burns the deadline
-  re-proving them (the bench.py round-4 lesson).
+  re-proving them.
 - **Deterministic jitter**: backoff delay is ``base * backoff**attempt``
   plus a jitter fraction derived from ``(seed, what, attempt)`` via
   CRC32 — the same call site produces the same delay schedule on every
@@ -78,9 +77,8 @@ class RetryPolicy:
     ``transient_types`` classifies by exception type (``OSError`` covers
     the host-I/O family: ``ConnectionError``, ``TimeoutError``, disk
     errors).  ``transient_markers`` classifies by status-code-anchored
-    message substring for runtime errors that arrive as generic types
-    (the bench.py tunnel-error set).  Everything else is deterministic
-    and propagates immediately.
+    message substring for runtime errors that arrive as generic types.
+    Everything else is deterministic and propagates immediately.
 
     The delay for attempt ``n`` (1-based) is
     ``min(base_delay_s * backoff**(n-1), max_delay_s)`` stretched by a
@@ -98,7 +96,7 @@ class RetryPolicy:
     transient_types: Tuple[Type[BaseException], ...] = (
         OSError, TransientError)
     transient_markers: Tuple[str, ...] = (
-        "UNAVAILABLE:", "DEADLINE_EXCEEDED", "remote_compile",
+        "UNAVAILABLE:", "DEADLINE_EXCEEDED",
         "Socket closed", "Connection reset", "Stream removed")
 
     def __post_init__(self):

@@ -77,10 +77,7 @@ def _tp_size(axis_name: str) -> int:
     exists — binding, not mesh presence, decides)."""
     if maybe_axis_index(axis_name) is None:
         return 1
-    # psum of a literal is evaluated statically (the idiom
-    # parallel.distributed._bound_axis_size uses); jax 0.4.x has no
-    # jax.lax.axis_size
-    return int(jax.lax.psum(1, axis_name))
+    return int(jax.lax.axis_size(axis_name))
 
 
 def _shard_init(init_fn: Callable, axis_name: str) -> Callable:

@@ -1,16 +1,9 @@
-"""JAX version-compatibility shims, probed once at import.
+"""The single import site for the jax APIs this codebase has seen move.
 
-Two renames keep biting every shard_map call site on this codebase's
-jax 0.4.x floor:
-
-- ``shard_map`` moved from ``jax.experimental.shard_map`` to the top
-  level in jax >= 0.6;
-- its replication-check kwarg was renamed ``check_rep`` (0.4.x) ->
-  ``check_vma``.
-
-This module is the ONE place that knows both (the probe previously
-lived copy-pasted in ``resilience.consistency``, ``__graft_entry__``
-and two test files — a future jax rename now lands here only):
+Installed, and the only target: jax / jaxlib 0.9.0.  ``shard_map`` lives
+at the top level there and its replication check is ``check_vma``; the
+jit cache probe is ``_cache_size()``.  Every call site imports these from
+here, so the next rename is a one-line change in this module:
 
     from apex_tpu.utils.compat import NO_REP_CHECK, shard_map
     f = shard_map(fn, mesh=mesh, in_specs=..., out_specs=...,
@@ -19,12 +12,7 @@ and two test files — a future jax rename now lands here only):
 
 from __future__ import annotations
 
-import inspect
-
-try:  # jax >= 0.6 exports it at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x keeps it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def compile_count(fn) -> int:
@@ -32,32 +20,20 @@ def compile_count(fn) -> int:
 
     The serving contract ("the decode step compiles exactly ONCE",
     "prefill compiles are bounded by the bucket table") is asserted in
-    tier-1 through jit cache statistics, but the probe is private API
-    that has already been renamed once across jax versions
-    (``_cache_size()`` today, ``cache_size()`` upstream).  This helper
-    is the ONE place that knows the spelling — every compile-count
-    assertion (``DecodeEngine.decode_compiles()`` /
+    tier-1 through jit cache statistics, and the probe is private API.
+    This helper is the ONE place that knows its spelling — every
+    compile-count assertion (``DecodeEngine.decode_compiles()`` /
     ``prefill_compiles()``, bench regression guards, tests) goes
-    through it, so the next rename is a one-line fix here instead of a
-    scavenger hunt.
+    through it.
     """
-    for probe in ("_cache_size", "cache_size"):
-        attr = getattr(fn, probe, None)
-        if callable(attr):
-            return int(attr())
-    raise AttributeError(
-        f"{fn!r} exposes no jit cache-size probe (tried _cache_size/"
-        f"cache_size) — is it a jax.jit-wrapped function on a supported "
-        f"jax version?")
+    return int(fn._cache_size())
+
 
 # Disabling the replication checker is the repo-wide default for
 # shard_map: the collective helpers mix per-leaf specs and produce
-# outputs made replicated by explicit psum/all_gather, which older
-# rep-checkers reject conservatively.
-NO_REP_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else {"check_rep": False})
+# outputs made replicated by explicit psum/all_gather, which the
+# checker rejects conservatively.
+NO_REP_CHECK = {"check_vma": False}
 
 #: Mesh axis name of the serving tensor-parallel mesh.  Deliberately
 #: the same spelling as ``parallel_state.TENSOR_PARALLEL_AXIS`` so the
@@ -91,8 +67,8 @@ def serving_mesh(size: int):
     """The 1-D tensor-parallel serving mesh over the first ``size``
     visible devices, axis-named :data:`SERVING_TP_AXIS`.
 
-    The ONE place the jax-0.4.37 ``Mesh(np.array(devices), ("tp",))``
-    dance is spelled (engine construction, weights-onto-mesh restore,
+    The ONE place the ``Mesh(np.array(devices), ("tp",))`` dance is
+    spelled (engine construction, weights-onto-mesh restore,
     tests and bench all call this), so a future Mesh-API rename lands
     here only.  Raises :class:`RuntimeError` with the
     ``--xla_force_host_platform_device_count`` recipe when the host

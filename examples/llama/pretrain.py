@@ -10,6 +10,15 @@ the loss with psums under tp.  Synthetic next-token data (zero egress).
 
     python examples/llama/pretrain.py [--tp 2] [--layers 4] [--steps 10]
 
+Parameters and optimizer state are created *inside* a jitted init whose
+``out_shardings`` are the tp layouts, so every device holds only its shard
+from the first allocation (an eager ``model.init`` would materialize the
+whole fp32 model and its Adam state on device 0 first — 13 GB at
+Llama-1B width), and the step donates both.  ``--bf16`` is the
+``bench.py`` llama-1b recipe: bf16 matmul weights and bf16 Adam moments.
+Per-device ``memory_stats()`` are printed after init and after the last
+step where the backend reports them.
+
 ``--pp N`` switches to the full 3-D dp × pp × tp layout (BASELINE.md
 row 5: "Llama-2 7B, TP x PP"): the decoder is sliced into pipeline stages
 (:mod:`apex_tpu.models.llama_pipeline`) and driven by the true-1F1B
@@ -28,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from apex_tpu.utils.compat import NO_REP_CHECK, shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from apex_tpu.models import LlamaConfig, LlamaForCausalLM
 from apex_tpu.optimizers import FusedAdam
@@ -59,7 +68,28 @@ def opt_specs(pspecs):
     return (AdamState(P(), pspecs, pspecs), P())
 
 
-def main():
+def device_memory(devices) -> dict:
+    """``{device id: (bytes_in_use, peak_bytes_in_use)}`` for the devices
+    whose backend reports memory (the CPU backend does not)."""
+    out = {}
+    for d in devices:
+        stats = d.memory_stats()
+        if stats:
+            out[d.id] = (stats.get("bytes_in_use"),
+                         stats.get("peak_bytes_in_use"))
+    return out
+
+
+def _print_memory(when: str, devices) -> None:
+    mem = device_memory(devices)
+    if not mem:
+        print(f"memory {when}: not reported by this backend")
+    for dev_id, (in_use, peak) in sorted(mem.items()):
+        print(f"memory {when}: device {dev_id} in_use "
+              f"{in_use / 2**30:.2f} GiB peak {peak / 2**30:.2f} GiB")
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--layers", type=int, default=4)
     ap.add_argument("--hidden", type=int, default=128)
@@ -83,15 +113,29 @@ def main():
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    ap.add_argument("--bf16", action="store_true",
+                    help="bf16 matmul weights + bf16 Adam moments (2-D "
+                    "path; the bench.py llama-1b recipe)")
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    args = parse_args(argv)
     if args.pp > 1:
         if args.batch is not None:
             raise SystemExit(
                 "--batch applies to the 2-D path only; with --pp the "
                 "global batch is --micro-batch * dp * --n-micro")
+        if args.bf16:
+            raise SystemExit("--bf16 applies to the 2-D path only")
         return main_3d(args)
+    _, first, last = train_2d(args)
+    return last
 
+
+def train_2d(args):
+    """The dp x tp run; returns ``(params, first_loss, last_loss)`` with
+    ``params`` the trained global arrays, tp-sharded over the mesh."""
     if args.batch is None:
         args.batch = 8
     devices = jax.devices()
@@ -110,7 +154,8 @@ def main():
         num_attention_heads=args.heads, num_key_value_heads=args.kv_heads,
         max_position_embeddings=args.seq)
     model = LlamaForCausalLM(cfg)
-    opt = FusedAdam(lr=args.lr)
+    opt = FusedAdam(lr=args.lr, state_dtype=(jnp.bfloat16 if args.bf16
+                                             else jnp.float32))
     rng = np.random.default_rng(args.seed)
 
     # one fixed batch: fresh uniform-random batches have nothing learnable
@@ -118,9 +163,15 @@ def main():
     batch0 = jnp.asarray(
         rng.integers(0, args.vocab, (args.batch, args.seq)), jnp.int32)
 
-    params = model.init(jax.random.PRNGKey(args.seed), batch0)
-    opt_state = opt.init(params)
-    pspecs = param_specs(params)
+    def init_fn(ids):
+        params = model.init(jax.random.PRNGKey(args.seed), ids)
+        if args.bf16:
+            params = jax.tree.map(
+                lambda p: p.astype(jnp.bfloat16) if p.ndim >= 2 else p,
+                params)
+        return params, opt.init(params)
+
+    pspecs = param_specs(jax.eval_shape(init_fn, batch0)[0])
     ospecs = opt_specs(pspecs)
 
     def train_step(params, opt_state, ids):
@@ -136,11 +187,18 @@ def main():
         return new_params, new_state, loss
 
     with mesh:
+        # sharded from the first allocation: the init program's outputs
+        # land tp-split, so no device ever holds the whole model
+        params, opt_state = jax.jit(
+            init_fn, out_shardings=jax.tree.map(
+                lambda spec: NamedSharding(mesh, spec), (pspecs, ospecs),
+                is_leaf=lambda x: isinstance(x, P)))(batch0)
+        _print_memory("after init", devices)
         step = jax.jit(shard_map(
             train_step, mesh=mesh,
             in_specs=(pspecs, ospecs, P("dp")),
             out_specs=(pspecs, ospecs, P()),
-            **NO_REP_CHECK))
+            **NO_REP_CHECK), donate_argnums=(0, 1))
         first = last = None
         for it in range(args.steps):
             params, opt_state, loss = step(params, opt_state, batch0)
@@ -149,11 +207,12 @@ def main():
             last = loss
             if it % 2 == 0 or it == args.steps - 1:
                 print(f"step {it:3d}  loss {loss:.4f}  dp={dp} tp={args.tp}")
+        _print_memory("after last step", devices)
 
     assert np.isfinite(last) and last < first, (first, last)
     print(f"llama pretrain OK: dp={dp} tp={args.tp}, "
           f"loss {first:.4f} -> {last:.4f}")
-    return last
+    return params, first, last
 
 
 def main_3d(args):

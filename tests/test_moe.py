@@ -113,9 +113,7 @@ def test_expert_parallel_matches_local():
 
     def fn(x_shard, full_params):
         # each rank keeps its token shard and its expert slice
-        # static axis size (jax 0.4.x has no jax.lax.axis_size); psum of
-        # a literal 1 folds to the axis size at trace time
-        ep = int(jax.lax.psum(1, "ep"))
+        ep = int(jax.lax.axis_size("ep"))
         r = jax.lax.axis_index("ep")
         local_e = 4 // ep
         slice_p = {

@@ -8,13 +8,8 @@ from apex_tpu.utils import nvtx
 
 
 def _hlo_with_labels(lowered):
-    """Scope labels live in the lowering's debug info on jax >= 0.5
-    (``as_text(debug_info=True)``); jax 0.4.x has no such kwarg and
-    only surfaces them in the compiled HLO's metadata."""
-    try:
-        return lowered.as_text(debug_info=True)
-    except TypeError:  # jax 0.4.x
-        return lowered.compile().as_text()
+    """Scope labels live in the lowering's debug info."""
+    return lowered.as_text(debug_info=True)
 
 
 def test_range_context_and_stack():

@@ -165,7 +165,7 @@ class TestRetry:
         def fn():
             calls.append(1)
             if len(calls) < 2:
-                raise RuntimeError("UNAVAILABLE: tunnel reset")
+                raise RuntimeError("UNAVAILABLE: connection reset")
             return 1
 
         assert rz.retry_transient(fn, sleep=lambda s: None) == 1

@@ -98,6 +98,10 @@ ALLOWLIST = {
     # echo with no measurement; the quant metrics (agreement, logit
     # error, bytes/token) ride serving_quant_eval, which IS handled
     "serving_quant_enabled",
+    # trace-time narration of which side of a kernel's shape predicate
+    # a call site took (ops/_dispatch.record_dispatch) — chip_smoke.py
+    # reads it from a private sink; countable via apex_events_total
+    "kernel_dispatch",
 }
 
 

@@ -110,6 +110,7 @@ PAGES = {
     "utils": ("Utilities", [
         "apex_tpu.utils.nvtx", "apex_tpu.utils.packing",
         "apex_tpu.utils.serialization", "apex_tpu.utils.compat",
+        "apex_tpu.utils.compile_cache",
         "apex_tpu.feature_registry", "apex_tpu._logging",
     ]),
 }

@@ -3,7 +3,7 @@
 BASELINE.json's primary metric names **ResNet-50 imgs/sec/chip** next to
 the GPT rows; the r1-r3 record only ever measured GPT.  This tool runs
 the other three target-table configurations on the real chip with the
-same honest protocol as bench.py (scalar readback forces the chain,
+same protocol as bench.py (scalar readback forces the chain,
 per-step cost is the marginal (t(2N)-t(N))/N):
 
 - ``resnet50``  — BASELINE row 1: O2-style bf16 + SyncBatchNorm(1 chip) +
@@ -15,7 +15,8 @@ per-step cost is the marginal (t(2N)-t(N))/N):
 
 FLOPs come from XLA's own cost analysis of the compiled training step
 (``compiled.cost_analysis()['flops']``) — no hand-derived constants —
-so ``mfu_hw`` is hardware-FLOPs utilization of the 197 TFLOP/s bf16 peak.
+so ``mfu_hw`` is hardware-FLOPs utilization of the device's bf16 peak
+(``bench._PEAK_TFLOPS``, keyed by ``device_kind``; unknown device = error).
 
 Usage: python tools/model_bench.py [resnet50 vit-l16 bert-large]
 """
@@ -36,8 +37,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for _p in (os.path.join(REPO, "examples", "imagenet"), REPO):
     if _p not in sys.path:  # idempotent: bench.py imports this module too
         sys.path.insert(0, _p)
-
-_PEAK_TFLOPS = 197.0  # v5e bf16
 
 
 def _marginal_time(step, state, steps_n):
@@ -63,8 +62,13 @@ QUIET = False  # bench.py sets True when embedding results in its own lines
 
 
 def _report(name, batch, step_s, flops_per_step, unit_per_step, unit):
+    import bench  # the one peak table; an unknown device_kind raises
+
     per_sec = unit_per_step / step_s
     tflops = flops_per_step / step_s / 1e12
+    dev = jax.devices()[0]
+    mfu_hw = round(tflops / bench._peak_tflops(dev), 4)
+    assert 0.0 < mfu_hw <= 1.0, f"measured hw-MFU {mfu_hw} is not physical"
     out = {
         "metric": f"{name}_{unit}_per_sec_per_chip",
         "value": round(per_sec, 1),
@@ -72,7 +76,7 @@ def _report(name, batch, step_s, flops_per_step, unit_per_step, unit):
         "step_time_ms": round(step_s * 1e3, 2),
         "batch": batch,
         "model_tflops_per_sec": round(tflops, 2),
-        "mfu_hw": round(tflops / _PEAK_TFLOPS, 4),
+        "mfu_hw": mfu_hw,
         "flops_source": "xla_cost_analysis",
     }
     if not QUIET:

@@ -15,8 +15,10 @@ actually measure honestly:
   config with ``bench.run_config``'s marginal-timing protocol and tabulate
   tokens/s + MFU.
 
-Usage:
-  python tools/scaling_sweep.py --mode tp
+Usage (``--mode tp`` needs the CPU handle: ``bench.tp_dryrun`` raises when
+fewer than ``tp`` devices are visible, it no longer re-executes itself):
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      python tools/scaling_sweep.py --mode tp
   python tools/scaling_sweep.py --mode batch --model medium \
       --batches 2,4,8 --seqs 512,1024
   python tools/scaling_sweep.py --mode both --json sweep.json

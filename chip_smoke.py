@@ -16,7 +16,9 @@ synthetic tokens) it runs
               chip, through ``tpu_custom_call``s of all three families);
 - *hand-off*: ``resilience.checkpoint.save_checkpoint`` of the trained
               params, then ``serving.load_serving_params`` — the repo's
-              own route from trainer to server;
+              own route from trainer to server; one 2.2 GB checkpoint
+              where the machine lets a file grow that large, consecutive
+              smaller ones where it does not (``phase_handoff``);
 - *serve*:    ``DecodeEngine`` + ``ContinuousBatchingScheduler`` driven by
               ``LoadGenerator`` over 6 greedy requests (one chunks, a freed
               slot is re-admitted), first-token logits against the plain
@@ -45,6 +47,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import errno
 import json
 import os
 import re
@@ -389,7 +392,63 @@ def phase_train4(sm: Smoke) -> dict:
                                   jax.tree.leaves(params)) / 1e6, 1)}
 
 
+def _file_bound(out_dir: str) -> dict:
+    """What one file under ``out_dir`` may hold.  ``RLIMIT_FSIZE`` is the
+    process's own cap — a write past it is ``EFBIG``, which is how the
+    one-file 2.2 GB hand-off first failed on a checking machine (PR 21) —
+    and a checkpoint also has to leave room on the disk for the compile
+    cache, hence half the free space."""
+    import resource
+
+    soft, _ = resource.getrlimit(resource.RLIMIT_FSIZE)
+    os.makedirs(out_dir, exist_ok=True)
+    free = shutil.disk_usage(out_dir).free
+    rlimit = None if soft == resource.RLIM_INFINITY else int(soft)
+    return {"rlimit_fsize": rlimit, "disk_free": free,
+            "bound": free // 2 if rlimit is None else min(rlimit, free // 2)}
+
+
+def _next_group(sizes, start, bound) -> list:
+    """The longest run of leaves from ``start`` whose bytes together stay
+    within ``bound`` (a checkpoint is ONE ``data.bin`` of exactly its
+    leaves' bytes); empty when leaf ``start`` alone exceeds it."""
+    idx, total = [], 0
+    for i in range(start, len(sizes)):
+        if total + sizes[i] > bound:
+            break
+        idx.append(i)
+        total += sizes[i]
+    return idx
+
+
+def _subtree(paths, leaves, idx) -> dict:
+    """The nested-dict tree holding ``leaves[i] for i in idx`` at their
+    own paths: with every index it is the params tree itself."""
+    out = {}
+    for i in idx:
+        node = out
+        for k in paths[i][:-1]:
+            node = node.setdefault(k.key, {})
+        node[paths[i][-1].key] = leaves[i]
+    return out
+
+
+# the machine's answer to "this file does not fit", as against a broken write
+_NO_ROOM = (errno.EFBIG, errno.ENOSPC, errno.EDQUOT)
+
+
 def phase_handoff(sm: Smoke) -> dict:
+    """Trained params -> ``save_checkpoint`` -> ``load_serving_params``.
+
+    Where the machine lets one file hold them, that is one checkpoint of
+    ``{"params": params}``, as a trainer writes it.  Where it does not
+    (``_file_bound``, or the write itself refused for want of room: the
+    bound then halves), the same tree goes over in consecutive checkpoints
+    of as many whole leaves as fit, each restored and removed before the
+    next is written; a leaf that no file here can hold is handed over in
+    memory and named in the phase line.  Nothing is dropped without a
+    word, and a run that could checkpoint nothing fails.
+    """
     import jax
     import jax.numpy as jnp
 
@@ -399,38 +458,84 @@ def phase_handoff(sm: Smoke) -> dict:
     root = os.path.join(sm.out_dir, "ckpt")
     shutil.rmtree(root, ignore_errors=True)
     trained = sm.params
-    like = {"params": jax.tree.map(
-        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), trained)}
+    flat, treedef = jax.tree_util.tree_flatten_with_path(trained)
+    paths = [p for p, _ in flat]
+    leaves = [x for _, x in flat]
+    keys = [jax.tree_util.keystr(p) for p in paths]
+    sizes = [x.nbytes for x in leaves]
     shardings = None
     if sm.args.chips > 1:
         from apex_tpu.utils.compat import serving_mesh
-        shardings = sv.tp_param_shardings(
-            like["params"], serving_mesh(sm.args.chips))
+        shardings = jax.tree.leaves(sv.tp_param_shardings(
+            jax.tree.map(lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype),
+                         trained), serving_mesh(sm.args.chips)))
+    limit = _file_bound(sm.out_dir)
+    bound = limit["bound"]
+
+    served, in_memory, refused, file_bytes = {}, [], [], []
+    save_s = load_s = 0.0
+    start = 0
     try:
-        t0 = time.perf_counter()
-        path = ckpt.save_checkpoint(root, sm.trained_steps,
-                                    {"params": trained}, keep=1)
-        save_s = time.perf_counter() - t0
-        nbytes = os.path.getsize(os.path.join(path, "data.bin"))
-        t0 = time.perf_counter()
-        served, step = sv.load_serving_params(
-            root, like, params_key="params", shardings=shardings)
-        jax.block_until_ready(served)
-        load_s = time.perf_counter() - t0
+        while start < len(leaves):
+            idx = _next_group(sizes, start, bound)
+            if not idx:
+                in_memory.append(start)
+                start += 1
+                continue
+            part = os.path.join(root, f"part_{len(file_bytes):02d}")
+            sub = _subtree(paths, leaves, idx)
+            t0 = time.perf_counter()
+            try:
+                path = ckpt.save_checkpoint(part, sm.trained_steps,
+                                            {"params": sub}, keep=1)
+            except OSError as e:
+                if e.errno not in _NO_ROOM:
+                    raise
+                nbytes = sum(sizes[i] for i in idx)
+                refused.append({"bytes": nbytes, "error": str(e)})
+                bound = nbytes // 2
+                continue
+            save_s += time.perf_counter() - t0
+            file_bytes.append(os.path.getsize(os.path.join(path, "data.bin")))
+            like = {"params": jax.tree.map(
+                lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), sub)}
+            t0 = time.perf_counter()
+            got, step = sv.load_serving_params(
+                part, like, params_key="params",
+                shardings=(None if shardings is None
+                           else _subtree(paths, shardings, idx)))
+            jax.block_until_ready(got)
+            load_s += time.perf_counter() - t0
+            if step != sm.trained_steps:
+                raise AssertionError(f"restored step {step}, saved "
+                                     f"{sm.trained_steps}")
+            for p, x in jax.tree_util.tree_flatten_with_path(got)[0]:
+                served[jax.tree_util.keystr(p)] = x
+            # 2.2 GB at full width: never left behind, never copied back
+            shutil.rmtree(part, ignore_errors=True)
+            start = idx[-1] + 1
     finally:
-        # 2.2 GB at full width: never left behind, never copied back
         shutil.rmtree(root, ignore_errors=True)
-    if step != sm.trained_steps:
-        raise AssertionError(f"restored step {step}, saved "
-                             f"{sm.trained_steps}")
+    if not file_bytes:
+        raise RuntimeError(f"no leaf of the params fits a file here: "
+                           f"{limit}, refused {refused}")
+    for i in in_memory:
+        served[keys[i]] = (leaves[i] if shardings is None
+                           else jax.device_put(leaves[i], shardings[i]))
+    served = jax.tree_util.tree_unflatten(treedef, [served[k] for k in keys])
     same = jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)),
                         trained, served)
     if not all(jax.tree.leaves(same)):
         raise AssertionError("restored params differ from the trained ones")
     sm.params = served
-    return {"bytes": nbytes, "save_seconds": round(save_s, 2),
+    return {"bytes": sum(file_bytes), "checkpoints": len(file_bytes),
+            "largest_file_bytes": max(file_bytes), "file_bound": limit,
+            "refused_writes": refused,
+            "in_memory_leaves": [keys[i] for i in in_memory],
+            "in_memory_bytes": sum(sizes[i] for i in in_memory),
+            "save_seconds": round(save_s, 2),
             "load_seconds": round(load_s, 2),
-            "leaves": len(jax.tree.leaves(served)),
+            "leaves": len(leaves),
             "restored_onto": ("one device" if shardings is None else
                               f"tp={sm.args.chips} serving mesh")}
 
@@ -602,7 +707,7 @@ def main(argv=None) -> None:
                     "proof runs the card as it is)")
     ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
                                                   "chip_smoke"),
-                    help="directory for result.json and the checkpoint "
+                    help="directory for result.json and the checkpoint(s) "
                     "of the hand-off phase (removed after the restore)")
     args = ap.parse_args(argv)
 

@@ -9,8 +9,10 @@ turns a broken or misconfigured device into a quiet reference run.
 
 import json
 import os
+import resource
 import subprocess
 import sys
+import types
 from pathlib import Path
 
 import pytest
@@ -19,6 +21,8 @@ from apex_tpu import _logging
 from apex_tpu.ops import _dispatch
 
 REPO = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO))
+import chip_smoke  # noqa: E402
 
 
 def _smoke(tmp_path, *flags):
@@ -52,6 +56,9 @@ def test_rehearsal_runs_every_phase_and_is_stamped(tmp_path):
     assert by["train"]["losses"][-1] < by["train"]["losses"][0]
     assert by["serve"]["decode_compiles"] == 1
     assert by["device"]["compile_cache_dir"] == str(tmp_path / "cache")
+    # no file-size limit here: the params went over as ONE checkpoint
+    assert by["hand-off"]["checkpoints"] == 1
+    assert by["hand-off"]["in_memory_leaves"] == []
     # the last stdout line is the result, stamped as a CPU rehearsal
     assert json.loads(out.stdout.splitlines()[-1]) == {
         "ok": True, "rehearsal": True,
@@ -65,6 +72,66 @@ def test_without_a_chip_the_smoke_fails_and_prints_no_result(tmp_path):
     assert out.returncode != 0
     assert "needs 'tpu'" in out.stderr
     assert not [ln for ln in out.stdout.splitlines() if '"ok"' in ln]
+
+
+def _hand_off(tmp_path, soft_limit):
+    """``phase_handoff`` over a small tree with the process's file-size
+    limit lowered for the duration (python ignores SIGXFSZ: a write past
+    the limit raises EFBIG, as on the machine that refused PR 21)."""
+    import jax.numpy as jnp
+
+    kib = 1024 // 2                       # bf16 elements per KiB
+    params = {"params": {
+        "embed": jnp.arange(256 * kib, dtype=jnp.float32).astype(
+            jnp.bfloat16).reshape(256, kib),            # 256 KiB
+        **{f"layers_{i}": {"kernel": jnp.full((32, kib), i, jnp.bfloat16)}
+           for i in range(6)}}}                         # 32 KiB each
+    sm = chip_smoke.Smoke(types.SimpleNamespace(chips=1), {},
+                          str(tmp_path / "out"))
+    sm.params, sm.trained_steps = params, 3
+    old = resource.getrlimit(resource.RLIMIT_FSIZE)
+    resource.setrlimit(resource.RLIMIT_FSIZE, (soft_limit, old[1]))
+    try:
+        obs = chip_smoke.phase_handoff(sm)
+    finally:
+        resource.setrlimit(resource.RLIMIT_FSIZE, old)
+    assert sm.params is not params
+    assert not os.path.exists(tmp_path / "out" / "ckpt")
+    return obs
+
+
+def test_hand_off_obeys_the_file_size_limit(tmp_path):
+    """448 KiB of params under a 100 KiB file limit: the kernels go over
+    three to a checkpoint, the 256 KiB leaf in memory, and the phase line
+    says so (``phase_handoff`` itself checks the tree arrived equal)."""
+    obs = _hand_off(tmp_path, 100 * 1024)
+    assert obs["file_bound"]["rlimit_fsize"] == obs["file_bound"]["bound"]
+    assert obs["checkpoints"] == 2
+    assert obs["largest_file_bytes"] == 96 * 1024
+    assert obs["bytes"] == 192 * 1024
+    assert obs["in_memory_leaves"] == ["['params']['embed']"]
+    assert obs["in_memory_bytes"] == 256 * 1024
+    assert obs["refused_writes"] == []
+
+
+def test_hand_off_halves_its_bound_when_a_write_is_refused(
+        tmp_path, monkeypatch):
+    """A limit the script cannot read beforehand (a filesystem's own) shows
+    as EFBIG from the write: the bound halves until the files fit."""
+    monkeypatch.setattr(
+        chip_smoke, "_file_bound",
+        lambda out: {"rlimit_fsize": None, "disk_free": 2**40,
+                     "bound": 2**39})
+    obs = _hand_off(tmp_path, 100 * 1024)
+    assert [r["bytes"] for r in obs["refused_writes"]] == [
+        448 * 1024, 192 * 1024]
+    assert obs["checkpoints"] == 2
+    assert obs["in_memory_leaves"] == ["['params']['embed']"]
+
+
+def test_hand_off_that_can_checkpoint_nothing_fails(tmp_path):
+    with pytest.raises(RuntimeError, match="no leaf of the params fits"):
+        _hand_off(tmp_path, 1024)
 
 
 def test_on_tpu_propagates_a_backend_init_error(monkeypatch):

@@ -97,9 +97,13 @@ def get_logger(name: str) -> logging.Logger:
 
 def _log_sink(event: dict) -> None:
     """The default sink: one sorted-key JSON line on ``apex_tpu.events``
-    (the exact pre-sink-registry behavior, byte for byte)."""
-    logging.getLogger("apex_tpu.events").info(
-        "%s", json.dumps(event, sort_keys=True, default=str))
+    (the exact pre-sink-registry behavior, byte for byte).  The line is
+    built only when the logger would write it: a serving step emits
+    several events, and ``json.dumps`` of each was paid with nothing
+    listening at INFO."""
+    logger = logging.getLogger("apex_tpu.events")
+    if logger.isEnabledFor(logging.INFO):
+        logger.info("%s", json.dumps(event, sort_keys=True, default=str))
 
 
 # ordered fan-out list; the log sink is first so the canonical line is

@@ -3,11 +3,20 @@
 The metrics registry answers "how often / how long on average"; spans
 answer "what was this *particular* slow step doing".  Design:
 
-- :func:`span` is a context manager.  With **no recorder installed it
-  is a near-no-op** — one module-global read, no contextvar traffic, no
-  allocation (the hot-path contract ``bench.py``'s ``obs`` block
-  measures).  With a recorder, each span records a Chrome trace-event
-  ``"X"`` (complete) event: ``ts``/``dur`` in monotonic microseconds
+- :func:`span` is a context manager.  With **no recorder installed and
+  no ``jax.profiler`` session active it is a near-no-op** — one
+  module-global read and one ``TraceAnnotation.is_enabled()`` call, no
+  contextvar traffic, no allocation (the hot-path contract
+  ``bench.py``'s ``obs`` block measures).  While a ``jax.profiler``
+  session is active, each span is also a
+  ``jax.profiler.TraceAnnotation``: it lands on the ``/host:CPU`` plane
+  of the same ``.xplane.pb`` as the device's lines, on the profiler's
+  clock, with its attributes as the event's stats — so a device idle
+  gap can be laid against what the host was doing (to within the
+  profiler's own alignment of the two planes: about a millisecond on a
+  v5e, PERF.md §6).  With a recorder, each span records
+  a Chrome trace-event ``"X"`` (complete) event: ``ts``/``dur`` in
+  monotonic microseconds
   from :func:`time.perf_counter` (never the wall clock — spans must
   not stretch under NTP steps), ``pid``/``tid``, and ``args`` carrying
   the span's attributes, id, and parent id.
@@ -27,7 +36,9 @@ answer "what was this *particular* slow step doing".  Design:
 
 For stalls that need *device-side* truth, :func:`start_jax_profiler` /
 :func:`stop_jax_profiler` wrap ``jax.profiler`` start/stop (opt-in,
-failure-tolerant), and :func:`profile_on_stall` adapts them to the
+failure-tolerant; every :func:`span` open while it runs is in the
+profile, beside the device's lines), and :func:`profile_on_stall`
+adapts them to the
 :class:`~apex_tpu.resilience.supervisor.StepWatchdog` ``on_stall`` hook
 so the first stall of a run captures a device profile on demand.
 """
@@ -41,6 +52,8 @@ import os
 import threading
 import time
 from typing import Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from apex_tpu._logging import get_logger
 
@@ -75,18 +88,25 @@ class Span:
     Mutable only while live; the exporter snapshot is taken at exit.
     """
 
-    __slots__ = ("name", "span_id", "parent_id", "attrs", "events")
+    __slots__ = ("name", "span_id", "parent_id", "attrs", "events",
+                 "_annotation")
 
     def __init__(self, name: str, span_id: int, parent_id: Optional[int],
-                 attrs: dict):
+                 attrs: dict, annotation: Optional[TraceAnnotation] = None):
         self.name = name
         self.span_id = span_id
         self.parent_id = parent_id
         self.attrs = attrs
         self.events: List[dict] = []
+        self._annotation = annotation
 
     def set_attribute(self, key: str, value) -> None:
+        """Set an attribute while the span is live — a count known only
+        at exit (chunks dispatched, requests finished) goes here.  Under
+        a profiler session it becomes one more stat of the annotation."""
         self.attrs[key] = value
+        if self._annotation is not None:
+            self._annotation.set_metadata(**{key: value})
 
     def add_event(self, name: str, **attrs) -> None:
         """Stamp a point-in-time event onto this span (the bridge calls
@@ -208,21 +228,35 @@ def span(name: str, **attrs) -> Iterator[Optional[Span]]:
     """``with span("train_step", step=i) as s:`` — time a region.
 
     Yields the live :class:`Span` (mutate attributes, add events), or
-    ``None`` when no recorder is installed — the no-recorder path does
-    no contextvar writes and no allocation, so leaving instrumentation
-    in hot loops is free by default.
+    ``None`` when no recorder is installed and no ``jax.profiler``
+    session is active — that path does no contextvar writes and no
+    allocation, so leaving instrumentation in hot loops is free by
+    default.  Under a profiler session the span is written to the
+    profile as a ``TraceAnnotation`` (attributes — plain ints and short
+    strings — become its stats); with a recorder it is recorded as a
+    Chrome trace event; with both, both.
     """
     recorder = _RECORDER
-    if recorder is None:
+    profiling = TraceAnnotation.is_enabled()
+    if recorder is None and not profiling:
         yield None
+        return
+    annotation = TraceAnnotation(name, **attrs) if profiling else None
+    if recorder is None:
+        # profiler only: no parent linkage to keep and current_span()
+        # stays None (the event bridge stamps recorder spans only)
+        with annotation:
+            yield Span(name, 0, None, attrs, annotation)
         return
     parent = _CURRENT.get()
     live = Span(name, next(_SPAN_IDS),
-                parent.span_id if parent is not None else None, dict(attrs))
+                parent.span_id if parent is not None else None, dict(attrs),
+                annotation)
     token = _CURRENT.set(live)
     t0 = time.perf_counter()
     try:
-        yield live
+        with annotation or contextlib.nullcontext():
+            yield live
     finally:
         dur_us = (time.perf_counter() - t0) * 1e6
         _CURRENT.reset(token)

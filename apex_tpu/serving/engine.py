@@ -66,6 +66,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec
 
 from apex_tpu._logging import emit_event, get_logger
+from apex_tpu.obs import trace as obs_trace
 from apex_tpu.serving.kv_cache import (
     KVCache,
     commit_slot_length,
@@ -1074,25 +1075,27 @@ class DecodeEngine:
         self._check_slot(slot)
         n = len(tokens)
         bucket = self.bucket_for(n)      # raises on n < 1 / n too long
-        offset = int(self._lengths_host[slot])
-        if offset + n > self.max_len:
-            raise ValueError(
-                f"chunk of {n} tokens at offset {offset} overruns cache "
-                f"max_len {self.max_len}")
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :n] = np.asarray(tokens, np.int32)
-        # paged: allocate/CoW the REAL rows' blocks before the write
-        # lands (bucket-padding rows past the frontier route to the
-        # null table entry and are dropped by the scatter)
-        self._ensure_paged([(slot, offset, offset + n)])
-        # np scalars, not jnp: a jnp.int32() wrapper costs a device_put
-        # (~35us) EACH on the dispatching host thread — three of them
-        # tripled this call's host cost (see PERF_NOTES; same move as
-        # read_region)
-        logits, self._cache = self._prefill(
-            self.params, self._cache, ids,
-            np.int32(slot), np.int32(offset), np.int32(n))
-        self._lengths_host[slot] = offset + n
+        with obs_trace.span("engine.prefill_chunk", slot=int(slot),
+                            bucket=bucket, tokens=n):
+            offset = int(self._lengths_host[slot])
+            if offset + n > self.max_len:
+                raise ValueError(
+                    f"chunk of {n} tokens at offset {offset} overruns "
+                    f"cache max_len {self.max_len}")
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :n] = np.asarray(tokens, np.int32)
+            # paged: allocate/CoW the REAL rows' blocks before the write
+            # lands (bucket-padding rows past the frontier route to the
+            # null table entry and are dropped by the scatter)
+            self._ensure_paged([(slot, offset, offset + n)])
+            # np scalars, not jnp: a jnp.int32() wrapper costs a
+            # device_put (~35us) EACH on the dispatching host thread —
+            # three of them tripled this call's host cost (see
+            # PERF_NOTES; same move as read_region)
+            logits, self._cache = self._prefill(
+                self.params, self._cache, ids,
+                np.int32(slot), np.int32(offset), np.int32(n))
+            self._lengths_host[slot] = offset + n
         return logits
 
     def prefill(self, slot: int, tokens: Sequence[int], *,
@@ -1328,52 +1331,59 @@ class DecodeEngine:
         ``active``.  Raises when an active slot is already at
         ``max_len`` (the append would silently clobber the last cached
         token otherwise)."""
-        act = np.asarray(active, bool)
-        full = act & (self._lengths_host >= self.max_len)
-        if full.any():
-            raise ValueError(
-                f"slots {np.flatnonzero(full).tolist()} are at cache "
-                f"capacity ({self.max_len}); release or raise max_len")
-        empty = act & (self._lengths_host == 0)
-        if empty.any():
-            raise ValueError(
-                f"slots {np.flatnonzero(empty).tolist()} are active but "
-                f"never prefilled — a decode step would expose a garbage "
-                f"token as their whole context")
-        if self._pager is not None:
-            # one batched allocation pass for every active lane, ONE
-            # table flush at most (none at all on the (block_size-1)
-            # of block_size steps that cross no block boundary)
-            self._ensure_paged(
-                [(int(s), int(self._lengths_host[s]),
-                  int(self._lengths_host[s]) + 1)
-                 for s in np.flatnonzero(act)])
-        if self._tp_cfg is None:
-            logits, self._cache = self._decode(
-                self.params, self._cache,
-                np.asarray(tokens, np.int32), act)
-        else:
-            # time the step wall-to-wall and publish it as
-            # serving_tp_step: an honest UPPER BOUND on the per-step
-            # collective cost (dispatch + compute + the per-layer psum
-            # pair; exact collective attribution needs a profiler).
-            # The block_until_ready adds ~nothing — the caller samples
-            # from these logits immediately, syncing anyway.  tp=None
-            # emits nothing: the default-off event stream is identical.
-            t0 = time.perf_counter()
-            logits, self._cache = self._decode(
-                self.params, self._cache,
-                np.asarray(tokens, np.int32), act)
-            jax.block_until_ready(logits)
-            # a fleet scheduler stamps its replica name onto the engine
-            # (anonymous engines splat nothing — byte-identical stream)
-            replica = getattr(self, "name", None)
-            emit_event("serving_tp_step", tp=self.tp_size,
-                       active=int(act.sum()),
-                       duration_s=time.perf_counter() - t0,
-                       **({"replica": replica}
-                          if isinstance(replica, str) else {}))
-        self._lengths_host[act] += 1
+        with obs_trace.span("engine.decode") as sp:
+            act = np.asarray(active, bool)
+            if sp is not None:
+                # the cached tokens this step's attention reads: what a
+                # roofline of the decode program counts as KV bytes
+                sp.set_attribute("lanes", int(act.sum()))
+                sp.set_attribute("kv_tokens",
+                                 int(self._lengths_host[act].sum()))
+            full = act & (self._lengths_host >= self.max_len)
+            if full.any():
+                raise ValueError(
+                    f"slots {np.flatnonzero(full).tolist()} are at cache "
+                    f"capacity ({self.max_len}); release or raise max_len")
+            empty = act & (self._lengths_host == 0)
+            if empty.any():
+                raise ValueError(
+                    f"slots {np.flatnonzero(empty).tolist()} are active "
+                    f"but never prefilled — a decode step would expose a "
+                    f"garbage token as their whole context")
+            if self._pager is not None:
+                # one batched allocation pass for every active lane, ONE
+                # table flush at most (none at all on the (block_size-1)
+                # of block_size steps that cross no block boundary)
+                self._ensure_paged(
+                    [(int(s), int(self._lengths_host[s]),
+                      int(self._lengths_host[s]) + 1)
+                     for s in np.flatnonzero(act)])
+            if self._tp_cfg is None:
+                logits, self._cache = self._decode(
+                    self.params, self._cache,
+                    np.asarray(tokens, np.int32), act)
+            else:
+                # time the step wall-to-wall and publish it as
+                # serving_tp_step: an honest UPPER BOUND on the per-step
+                # collective cost (dispatch + compute + the per-layer psum
+                # pair; exact collective attribution needs a profiler).
+                # The block_until_ready adds ~nothing — the caller samples
+                # from these logits immediately, syncing anyway.  tp=None
+                # emits nothing: the default-off event stream is identical.
+                t0 = time.perf_counter()
+                logits, self._cache = self._decode(
+                    self.params, self._cache,
+                    np.asarray(tokens, np.int32), act)
+                jax.block_until_ready(logits)
+                # a fleet scheduler stamps its replica name onto the engine
+                # (anonymous engines splat nothing — byte-identical stream)
+                replica = getattr(self, "name", None)
+                emit_event("serving_tp_step", tp=self.tp_size,
+                           active=int(act.sum()),
+                           duration_s=time.perf_counter() - t0,
+                           **({"replica": replica}
+                              if isinstance(replica, str) else {}))
+            self._lengths_host[act] += 1
         return logits
 
     def verify_draft(self, slot: int, tokens: Sequence[int]
@@ -1411,35 +1421,38 @@ class DecodeEngine:
                 f"token, got {len(tokens)} token(s) — with no draft to "
                 f"verify, run the plain decode step")
         bucket = self.draft_bucket_for(k)    # raises past max_draft
-        offset = int(self._lengths_host[slot])
-        if offset == 0:
-            raise ValueError(
-                f"slot {slot} was never prefilled — a verify would "
-                f"expose garbage as its whole context")
-        if offset + k + 1 > self.max_len:
-            raise ValueError(
-                f"verify of {k + 1} tokens at offset {offset} overruns "
-                f"cache max_len {self.max_len}")
-        ids = np.zeros((1, bucket + 1), np.int32)
-        ids[0, :k + 1] = np.asarray(tokens, np.int32)
-        # paged: cover the pending token + the whole real draft; a
-        # rollback leaves the surplus blocks owned by the slot (refs
-        # untouched), so the re-decode over them re-allocates nothing
-        self._ensure_paged([(slot, offset, offset + k + 1)])
-        greedy, rows, accepted, self._cache = self._verify(
-            self.params, self._cache, ids, np.int32(slot),
-            np.int32(offset), np.int32(k + 1))
-        a = int(accepted)
-        self._lengths_host[slot] = offset + a + 1
-        return a, np.asarray(greedy), rows
+        with obs_trace.span("engine.verify_draft", slot=int(slot),
+                            drafted=k):
+            offset = int(self._lengths_host[slot])
+            if offset == 0:
+                raise ValueError(
+                    f"slot {slot} was never prefilled — a verify would "
+                    f"expose garbage as its whole context")
+            if offset + k + 1 > self.max_len:
+                raise ValueError(
+                    f"verify of {k + 1} tokens at offset {offset} overruns "
+                    f"cache max_len {self.max_len}")
+            ids = np.zeros((1, bucket + 1), np.int32)
+            ids[0, :k + 1] = np.asarray(tokens, np.int32)
+            # paged: cover the pending token + the whole real draft; a
+            # rollback leaves the surplus blocks owned by the slot (refs
+            # untouched), so the re-decode over them re-allocates nothing
+            self._ensure_paged([(slot, offset, offset + k + 1)])
+            greedy, rows, accepted, self._cache = self._verify(
+                self.params, self._cache, ids, np.int32(slot),
+                np.int32(offset), np.int32(k + 1))
+            a = int(accepted)
+            self._lengths_host[slot] = offset + a + 1
+            return a, np.asarray(greedy), rows
 
     # ---- sampling --------------------------------------------------------
     @staticmethod
     def sample(logits, base_keys, indices, temperatures,
                top_ks) -> jax.Array:
         """Vectorized deterministic sampling (see :func:`sample_tokens`)."""
-        return sample_tokens(
-            jnp.asarray(logits), jnp.asarray(base_keys),
-            jnp.asarray(indices, jnp.int32),
-            jnp.asarray(temperatures, jnp.float32),
-            jnp.asarray(top_ks, jnp.int32))
+        with obs_trace.span("engine.sample"):
+            return sample_tokens(
+                jnp.asarray(logits), jnp.asarray(base_keys),
+                jnp.asarray(indices, jnp.int32),
+                jnp.asarray(temperatures, jnp.float32),
+                jnp.asarray(top_ks, jnp.int32))

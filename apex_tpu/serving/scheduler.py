@@ -115,6 +115,7 @@ import numpy as np
 
 from apex_tpu._logging import emit_event, get_logger
 from apex_tpu.obs import bridge as obs_bridge
+from apex_tpu.obs import trace as obs_trace
 from apex_tpu.serving.draft import SpeculationConfig, adapt_k, propose
 from apex_tpu.serving.engine import DecodeEngine, request_key
 from apex_tpu.serving.paged_kv_cache import blocks_per_slot
@@ -436,64 +437,65 @@ class ContinuousBatchingScheduler:
         """Enqueue; raises :class:`QueueFull` at ``max_queue`` and
         ``ValueError`` for requests the engine can never serve."""
         rid = request.rid
-        # O(1): the live-rid set mirrors queue + active + suspended +
-        # unclaimed results exactly (updated at submit / finish /
-        # pop_result) — the old three linear scans made every submit
-        # O(n) and a loadgen run O(n^2)
-        if rid in self._live_rids:
-            raise ValueError(
-                f"duplicate rid {rid!r}: already "
-                f"{'finished' if rid in self._results else 'in flight'} "
-                f"— two streams under one rid would overwrite each "
-                f"other's results")
-        n = len(request.prompt)
-        if request.max_new_tokens < 1:
-            raise ValueError(
-                f"{request.rid}: max_new_tokens must be >= 1 "
-                f"(got {request.max_new_tokens})")
-        if n < 1:
-            raise ValueError(f"{request.rid}: empty prompt")
-        if request.deadline_s is not None and request.deadline_s <= 0:
-            raise ValueError(
-                f"{request.rid}: deadline_s must be > 0 (or None), got "
-                f"{request.deadline_s} — an already-expired deadline "
-                f"is a caller bug, not a sheddable request")
-        if not request.tenant:
-            raise ValueError(
-                f"{request.rid}: tenant must be a non-empty string")
-        # prompts longer than prefill_len are fine (chunked cached
-        # prefill serves them); the only hard ceiling is cache capacity.
-        # The FINAL sampled token is never appended (the request finishes
-        # right after sampling it), so peak cache use is one less than
-        # prompt + output budget — a stream may fill the cache exactly
-        if n + request.max_new_tokens - 1 > self.engine.max_len:
-            raise ValueError(
-                f"{request.rid}: prompt {n} + max_new_tokens "
-                f"{request.max_new_tokens} needs "
-                f"{n + request.max_new_tokens - 1} cached positions, "
-                f"over cache max_len {self.engine.max_len}")
-        if self._paged:
-            # the paged analog of the max_len guard: a stream whose
-            # worst-case (zero-sharing) footprint exceeds the whole
-            # pool could stall every other stream before dying at
-            # BlockPoolExhausted — reject it at the door instead
-            bs = self.engine.block_size
-            need = blocks_per_slot(n + request.max_new_tokens - 1, bs)
-            usable = self.engine.block_pool.num_blocks - 1
-            if need > usable:
+        with obs_trace.span("serving.submit", rid=rid):
+            # O(1): the live-rid set mirrors queue + active + suspended +
+            # unclaimed results exactly (updated at submit / finish /
+            # pop_result) — the old three linear scans made every submit
+            # O(n) and a loadgen run O(n^2)
+            if rid in self._live_rids:
                 raise ValueError(
-                    f"{request.rid}: worst-case footprint of {need} "
-                    f"blocks (block_size {bs}) exceeds the whole pool "
-                    f"({usable} allocatable blocks) — raise num_blocks "
-                    f"or shrink the request")
-        if len(self._queue) >= self.max_queue:
-            raise QueueFull(f"queue at capacity ({self.max_queue})")
-        self._queue.append((request, self._clock()))
-        self._live_rids.add(rid)
-        if self.policy is not None:
-            self._tenants_seen.add(request.tenant)
-        self._emit("serving_request_queued", rid=request.rid,
-                   prompt_tokens=n, queue_depth=len(self._queue))
+                    f"duplicate rid {rid!r}: already "
+                    f"{'finished' if rid in self._results else 'in flight'} "
+                    f"— two streams under one rid would overwrite each "
+                    f"other's results")
+            n = len(request.prompt)
+            if request.max_new_tokens < 1:
+                raise ValueError(
+                    f"{request.rid}: max_new_tokens must be >= 1 "
+                    f"(got {request.max_new_tokens})")
+            if n < 1:
+                raise ValueError(f"{request.rid}: empty prompt")
+            if request.deadline_s is not None and request.deadline_s <= 0:
+                raise ValueError(
+                    f"{request.rid}: deadline_s must be > 0 (or None), got "
+                    f"{request.deadline_s} — an already-expired deadline "
+                    f"is a caller bug, not a sheddable request")
+            if not request.tenant:
+                raise ValueError(
+                    f"{request.rid}: tenant must be a non-empty string")
+            # prompts longer than prefill_len are fine (chunked cached
+            # prefill serves them); the only hard ceiling is cache capacity.
+            # The FINAL sampled token is never appended (the request finishes
+            # right after sampling it), so peak cache use is one less than
+            # prompt + output budget — a stream may fill the cache exactly
+            if n + request.max_new_tokens - 1 > self.engine.max_len:
+                raise ValueError(
+                    f"{request.rid}: prompt {n} + max_new_tokens "
+                    f"{request.max_new_tokens} needs "
+                    f"{n + request.max_new_tokens - 1} cached positions, "
+                    f"over cache max_len {self.engine.max_len}")
+            if self._paged:
+                # the paged analog of the max_len guard: a stream whose
+                # worst-case (zero-sharing) footprint exceeds the whole
+                # pool could stall every other stream before dying at
+                # BlockPoolExhausted — reject it at the door instead
+                bs = self.engine.block_size
+                need = blocks_per_slot(n + request.max_new_tokens - 1, bs)
+                usable = self.engine.block_pool.num_blocks - 1
+                if need > usable:
+                    raise ValueError(
+                        f"{request.rid}: worst-case footprint of {need} "
+                        f"blocks (block_size {bs}) exceeds the whole pool "
+                        f"({usable} allocatable blocks) — raise num_blocks "
+                        f"or shrink the request")
+            if len(self._queue) >= self.max_queue:
+                raise QueueFull(f"queue at capacity ({self.max_queue})")
+            self._queue.append((request, self._clock()))
+            self._live_rids.add(rid)
+            if self.policy is not None:
+                self._tenants_seen.add(request.tenant)
+            self._emit("serving_request_queued", rid=request.rid,
+                       prompt_tokens=n, queue_depth=len(self._queue))
 
     # ---- introspection ---------------------------------------------------
     @property
@@ -1326,50 +1328,59 @@ class ContinuousBatchingScheduler:
         that finished already at prefill completion (one-token
         requests, instant EOS)."""
         finished: List[str] = []
-        budget = self.prefill_budget
-        # FIFO by admission order; under a policy, priority classes
-        # drain first (a high-priority admission's first token must
-        # not wait behind an earlier low-priority long prompt)
-        key = (
-            (lambda s: s.seq) if self.policy is None
-            else (lambda s: (-s.request.priority, s.seq)))
-        for st in sorted((s for s in self._active.values()
-                          if s.phase is RequestPhase.PREFILL),
-                         key=key):
-            while budget > 0 and st.prompt_remaining:
-                chunk = min(st.prompt_remaining,
-                            self.engine.prefill_len, budget)
-                offset = st.prompt_pos      # the chunk's START position
-                t0 = self._clock()
-                logits = self.engine.prefill_chunk(
-                    st.slot, st.request.prompt[offset:offset + chunk])
-                dt = self._clock() - t0
-                st.prompt_pos = offset + chunk
-                budget -= chunk
-                self._emit("serving_prefill_chunk", rid=st.request.rid,
-                           bucket=self.engine.bucket_for(chunk),
-                           chunk_tokens=chunk, offset_tokens=offset,
-                           duration_s=round(dt, 6))
-                if self._prefix is not None:
-                    self._offer_blocks(st)
-                if not st.prompt_remaining:
-                    tok = int(self.engine.sample(
-                        logits[None], st.base_key[None], np.int32([0]),
-                        np.float32([st.request.temperature]),
-                        np.int32([st.request.top_k]))[0])
-                    st.t_first = self._clock()
-                    st.tokens.append(tok)
-                    st.phase = RequestPhase.DECODE
+        with obs_trace.span("serving.prefill") as sp:
+            budget = self.prefill_budget
+            chunks = 0
+            # FIFO by admission order; under a policy, priority classes
+            # drain first (a high-priority admission's first token must
+            # not wait behind an earlier low-priority long prompt)
+            key = (
+                (lambda s: s.seq) if self.policy is None
+                else (lambda s: (-s.request.priority, s.seq)))
+            for st in sorted((s for s in self._active.values()
+                              if s.phase is RequestPhase.PREFILL),
+                             key=key):
+                while budget > 0 and st.prompt_remaining:
+                    chunk = min(st.prompt_remaining,
+                                self.engine.prefill_len, budget)
+                    offset = st.prompt_pos      # the chunk's START position
+                    t0 = self._clock()
+                    logits = self.engine.prefill_chunk(
+                        st.slot, st.request.prompt[offset:offset + chunk])
+                    dt = self._clock() - t0
+                    st.prompt_pos = offset + chunk
+                    budget -= chunk
+                    chunks += 1
+                    self._emit("serving_prefill_chunk", rid=st.request.rid,
+                               bucket=self.engine.bucket_for(chunk),
+                               chunk_tokens=chunk, offset_tokens=offset,
+                               duration_s=round(dt, 6))
                     if self._prefix is not None:
-                        # the prompt is fully cached: the chain it was
-                        # matching/extending no longer needs protection
-                        self._release_pins(st)
-                    self._emit("serving_first_token", rid=st.request.rid,
-                               ttft_s=round(st.t_first - st.t_submit, 6))
-                    if self._finish_if_done(st):
-                        finished.append(st.request.rid)
-            if budget <= 0:
-                break
+                        self._offer_blocks(st)
+                    if not st.prompt_remaining:
+                        sampled = self.engine.sample(
+                            logits[None], st.base_key[None], np.int32([0]),
+                            np.float32([st.request.temperature]),
+                            np.int32([st.request.top_k]))
+                        with obs_trace.span("serving.readback",
+                                            what="first_token",
+                                            rid=st.request.rid):
+                            tok = int(sampled[0])
+                        st.t_first = self._clock()
+                        st.tokens.append(tok)
+                        st.phase = RequestPhase.DECODE
+                        if self._prefix is not None:
+                            # the prompt is fully cached: the chain it was
+                            # matching/extending no longer needs protection
+                            self._release_pins(st)
+                        self._emit("serving_first_token", rid=st.request.rid,
+                                   ttft_s=round(st.t_first - st.t_submit, 6))
+                        if self._finish_if_done(st):
+                            finished.append(st.request.rid)
+                if budget <= 0:
+                    break
+            if sp is not None:
+                sp.set_attribute("chunks", chunks)
         return finished
 
     def _finish_if_done(self, st: _Active) -> bool:
@@ -1495,49 +1506,72 @@ class ContinuousBatchingScheduler:
         for every decoding slot.  Returns rids that reached a terminal
         state at this boundary (finished or shed)."""
         finished: List[str] = []
-        if self.policy is not None and self.policy.deadline_shedding:
-            finished.extend(self._shed_expired())
-        self._admit()
-        finished.extend(self._prefill_work())
-        decoding = {slot: st for slot, st in self._active.items()
-                    if st.phase is RequestPhase.DECODE}
-        if decoding and self.speculation is not None:
-            # speculative verifies run between the prefill budget and
-            # the shared decode step; slots they advanced are excluded
-            # from this step's decode (they already emitted), everyone
-            # else — sampled requests, no-match streams, mid-prefill
-            # lanes — proceeds exactly as before
-            spec_finished, consumed = self._spec_work(decoding)
-            finished.extend(spec_finished)
-            decoding = {slot: st for slot, st in decoding.items()
-                        if slot not in consumed}
-        if decoding:
-            slots = self.engine.slots
-            tokens = np.zeros((slots,), np.int32)
-            active = np.zeros((slots,), bool)
-            base_keys = np.zeros((slots, 2), np.uint32)
-            indices = np.zeros((slots,), np.int32)
-            temps = np.zeros((slots,), np.float32)
-            top_ks = np.zeros((slots,), np.int32)
-            for slot, st in decoding.items():
-                tokens[slot] = st.tokens[-1]
-                active[slot] = True
-                base_keys[slot] = st.base_key
-                indices[slot] = len(st.tokens)
-                temps[slot] = st.request.temperature
-                top_ks[slot] = st.request.top_k
-            # per-step device work: ONE decode dispatch + ONE sampler
-            # dispatch (keys fold inside the sampler) + one readback;
-            # mid-prefill slots ride as inactive lanes (their lengths
-            # never advance, and the next chunk overwrites the lane's
-            # masked garbage write)
-            logits = self.engine.decode(tokens, active)
-            sampled = np.asarray(self.engine.sample(
-                logits, base_keys, indices, temps, top_ks))
-            for slot, st in list(decoding.items()):
-                st.tokens.append(int(sampled[slot]))
-                if self._finish_if_done(st):
-                    finished.append(st.request.rid)
+        with obs_trace.span("serving.step", step=self._step_index + 1,
+                            active=len(self._active),
+                            queued=len(self._queue)):
+            with obs_trace.span("serving.admit"):
+                if self.policy is not None and self.policy.deadline_shedding:
+                    finished.extend(self._shed_expired())
+                self._admit()
+            finished.extend(self._prefill_work())
+            decoding = {slot: st for slot, st in self._active.items()
+                        if st.phase is RequestPhase.DECODE}
+            if decoding and self.speculation is not None:
+                # speculative verifies run between the prefill budget and
+                # the shared decode step; slots they advanced are excluded
+                # from this step's decode (they already emitted), everyone
+                # else — sampled requests, no-match streams, mid-prefill
+                # lanes — proceeds exactly as before
+                with obs_trace.span("serving.spec"):
+                    spec_finished, consumed = self._spec_work(decoding)
+                finished.extend(spec_finished)
+                decoding = {slot: st for slot, st in decoding.items()
+                            if slot not in consumed}
+            if decoding:
+                with obs_trace.span("serving.decode", lanes=len(decoding)):
+                    slots = self.engine.slots
+                    tokens = np.zeros((slots,), np.int32)
+                    active = np.zeros((slots,), bool)
+                    base_keys = np.zeros((slots, 2), np.uint32)
+                    indices = np.zeros((slots,), np.int32)
+                    temps = np.zeros((slots,), np.float32)
+                    top_ks = np.zeros((slots,), np.int32)
+                    for slot, st in decoding.items():
+                        tokens[slot] = st.tokens[-1]
+                        active[slot] = True
+                        base_keys[slot] = st.base_key
+                        indices[slot] = len(st.tokens)
+                        temps[slot] = st.request.temperature
+                        top_ks[slot] = st.request.top_k
+                    # per-step device work: ONE decode dispatch + ONE sampler
+                    # dispatch (keys fold inside the sampler) + one readback;
+                    # mid-prefill slots ride as inactive lanes (their lengths
+                    # never advance, and the next chunk overwrites the lane's
+                    # masked garbage write)
+                    logits = self.engine.decode(tokens, active)
+                    sampled = self.engine.sample(
+                        logits, base_keys, indices, temps, top_ks)
+                # the one place a decoding step WAITS on the device: the span
+                # wraps the host read and nothing else, so device idle under
+                # it is "device done, host not yet resumed" and idle under
+                # any other span is the host working
+                with obs_trace.span("serving.readback", what="decode"):
+                    sampled = np.asarray(sampled)
+                with obs_trace.span("serving.finish") as sp:
+                    n_done = len(finished)
+                    for slot, st in list(decoding.items()):
+                        st.tokens.append(int(sampled[slot]))
+                        if self._finish_if_done(st):
+                            finished.append(st.request.rid)
+                    if sp is not None:
+                        sp.set_attribute("finished", len(finished) - n_done)
+            with obs_trace.span("serving.publish"):
+                self._publish_step()
+        return finished
+
+    def _publish_step(self) -> None:
+        """Close the step: count it, refresh the gauges, and every
+        ``log_interval`` steps emit ``serving_step``."""
         self._step_index += 1
         # current-state gauges refresh EVERY step (a gauge tied to
         # log_interval would be stale for interval-1 steps); occupancy
@@ -1620,7 +1654,6 @@ class ContinuousBatchingScheduler:
                        # single-chip; getattr so engine doubles in
                        # tests keep working)
                        tp=int(getattr(self.engine, "tp_size", 1)))
-        return finished
 
     def _derived_step_bound(self) -> int:
         """A generous progress bound for :meth:`run`: every step of a

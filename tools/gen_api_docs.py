@@ -1293,7 +1293,7 @@ two rounds of a benchmark — aggregate bucket-to-bucket.
 | `apex_serving_ttft_seconds{replica}` | histogram | `serving_first_token` events |
 | `apex_serving_queue_wait_seconds{replica}` | histogram | `serving_request_admitted` events (submit → slot admission; the queueing component of TTFT) |
 | `apex_serving_goodput_ratio` | gauge | `serving.loadgen` (requests meeting their deadline / offered, for the most recent deadline-carrying open-loop run) |
-| `apex_serving_prefill_duration_seconds{bucket}` | histogram | `serving_prefill_chunk` events (label = bucket size; bounded by the engine's bucket table) |
+| `apex_serving_prefill_duration_seconds{bucket}` | histogram | `serving_prefill_chunk` events: host wall time of the chunk's **enqueue** — `engine.prefill_chunk` returns an un-awaited array, so on a chip this is tens of µs, not the chunk's device time (read that from a profile: `jit__prefill` on `XLA Modules`) (label = bucket size; bounded by the engine's bucket table) |
 | `apex_serving_decode_per_token_seconds{replica}` | histogram | `serving_request_finished` events |
 | `apex_serving_tokens_per_second{replica}` | gauge | last finished request |
 | `apex_serving_queue_depth{replica}` | gauge | scheduler, every step |
@@ -1388,8 +1388,14 @@ series).
 ## Span semantics
 
 `with span("train_step", step=i) as s:` times a region on the
-**monotonic** clock.  With no recorder installed the span is a
-near-no-op (one global read — the always-on default).  Under
+**monotonic** clock.  With no recorder installed and no `jax.profiler`
+session active the span is a near-no-op (one global read and one
+`TraceAnnotation.is_enabled()` call — the always-on default; it yields
+`None`).  While a `jax.profiler` session is active each span is also a
+`jax.profiler.TraceAnnotation`: it is written to the `/host:CPU` plane
+of the same `.xplane.pb` as the device's lines, on the profiler's clock
+(it aligns the two planes to about a millisecond on a v5e), with its
+attributes (plain ints and short strings) as the event's stats.  Under
 `install_recorder()` / `with recording() as rec:` each span records a
 Chrome trace-event `"X"` entry (`ts`/`dur` in µs, `pid`/`tid`, `args`
 carrying attributes + `span_id`/`parent_id`); parent linkage rides
@@ -1403,6 +1409,43 @@ trace of a slow step shows the retries/skips that fired inside it.
 `stop_jax_profiler()` wrap `jax.profiler`, and
 `profile_on_stall(logdir)` adapts them to `StepWatchdog(on_stall=...)`
 so the first stall of a run captures a device profile on demand.
+
+### The serving step's spans
+
+`ContinuousBatchingScheduler` and `DecodeEngine` open these spans on
+the dispatching thread (no option turns them on: they cost ~1.5 µs each
+while nothing records).  Nesting is by interval; `rid` is the
+identifier a request's spans share.  `serving.readback` wraps the
+blocking device read and nothing else, so it is the one span under
+which the host *waits*; everything else under `serving.step` is the
+host *working*.
+
+| span | where | attributes |
+|---|---|---|
+| `serving.submit` | all of `ContinuousBatchingScheduler.submit` | `rid` |
+| `serving.step` | all of `step()` | `step`, `active`, `queued` |
+| `serving.admit` | deadline shedding + admission (policy path included) | — |
+| `serving.prefill` | the prefill budget's chunks | `chunks` (set at exit) |
+| `serving.spec` | speculative verifies, only when speculation is on | — |
+| `serving.decode` | building the step's inputs through the return of `engine.sample` | `lanes` |
+| `serving.readback` | each blocking device read: the step's sampled tokens, and a prompt's first token | `what` = `decode` / `first_token`; `rid` for the latter |
+| `serving.finish` | token append + finish checks of the decoding lanes | `finished` (set at exit) |
+| `serving.publish` | step counter, gauges, the `serving_step` event | — |
+| `engine.prefill_chunk` | `DecodeEngine.prefill_chunk`: input build and enqueue | `slot`, `bucket`, `tokens` |
+| `engine.decode` | all of `DecodeEngine.decode`: checks, paging, enqueue | `lanes`, `kv_tokens` (cached tokens the active lanes attend, before the append) |
+| `engine.sample` | `DecodeEngine.sample` | — |
+| `engine.verify_draft` | `DecodeEngine.verify_draft` (its two reads included) | `slot`, `drafted` |
+
+**Profile a slow serving step.**  Start a profiler session around a few
+steps — `obs.start_jax_profiler(logdir)` … `obs.stop_jax_profiler()`, or
+`jax.profiler.trace(logdir)` — and open the result in TensorBoard /
+Perfetto or read it with `jax.profiler.ProfileData`: the spans above
+appear on the host's Python thread beside the device's `XLA Modules`
+and `XLA Ops` lines, so a gap on the device can be laid against the
+span the host was in (`benchmark/lib/program_spans.py` is such a
+reader).  Without a profiler, `obs.install_recorder()` (or
+`with obs.recording() as rec:`) records the same spans as a Chrome
+trace, host clock only: `rec.export("step.json")`.
 
 ## The event bridge
 

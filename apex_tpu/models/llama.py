@@ -168,38 +168,63 @@ def _rope_freqs(s: int, dim: int, theta: float, offset=0) -> jax.Array:
     return jnp.concatenate([f, f], axis=-1)[:, None, None, :]  # [s,1,1,d]
 
 
-# cached-attention query blocks are padded to at least this many rows:
-# XLA-CPU lowers an M=1 score "matmul" as a gemv whose per-element rounding
-# differs from the gemm the uncached forward's [s, s] scores go through;
-# M>=8 keeps both paths in the gemm regime so the dot products round
-# identically (pinned by tests/test_serving.py bit-parity)
+# cached-attention query blocks are padded to at least this many rows
+# per query head: XLA-CPU lowers an M=1 score "matmul" as a gemv whose
+# per-element rounding differs from the gemm the uncached forward's
+# [s, s] scores go through; M>=8 keeps both paths in the gemm regime so
+# the float32 dot products round identically (pinned by
+# tests/test_serving.py bit-parity).  On the chip the pad makes a KV
+# head's query block rep * 8 rows: an MXU tile at GQA 4:1
 _DECODE_QPAD = 8
 
 
-def _cached_attention(qt, kt, vt, bounds):
-    """Length-masked attention read over a full KV-cache buffer.
+def _cached_attention(qt, kc, vc, bounds):
+    """Length-masked attention read over a full KV-cache buffer, as the
+    cache stores it.
 
-    ``qt``: ``[b, h, m, hd]`` query rows; ``kt``/``vt``: ``[b, h,
-    max_len, hd]`` (the cache, GQA-expanded); ``bounds``: ``[b, m]``
-    int32 — row ``i`` of batch element ``b`` attends cache positions
-    ``idx <= bounds[b, i]``; everything past its bound is masked
-    garbage.  Two callers: single-token decode (``m == 1``, one bound
-    per slot) and chunked prefill (``m == chunk``, ``bounds[0, i] =
-    offset + i`` — the chunk's causal block over the previously cached
-    context).
+    ``qt``: ``[b, h, m, hd]`` query rows; ``kc``/``vc``: ``[b, max_len,
+    kv_heads, hd]`` — the cache's own layout and head count, in the
+    dtype the cache hands back; ``bounds``: ``[b, m]`` int32 — row ``i``
+    of batch element ``b`` attends cache positions ``idx <=
+    bounds[b, i]``; everything past its bound is masked garbage.  Two
+    callers: single-token decode (``m == 1``, one bound per slot) and
+    chunked prefill / speculative verification (``m == chunk``,
+    ``bounds[0, i] = offset + i`` — the chunk's causal block over the
+    previously cached context).
 
-    The op sequence mirrors ``ops.flash_attention.mha_reference`` (scale
-    folded into fp32 q before the dot, ``-1e30`` mask, max/exp/sum/divide,
-    fp32 PV, cast back) so that against an uncached forward **run at the
-    same static ``max_len`` extent** every reduction sees identical
-    operand extents — masked tails are exact zeros — and the result is
-    bit-identical, per step, forever (the no-recompile serving contract
-    and the parity acceptance test in one property).
+    **Grouped, not repeated.**  Query head ``j`` reads KV head ``j //
+    rep`` (``rep = h // kv_heads``, the ``jnp.repeat`` share pattern of
+    the uncached branch), so consecutive query heads group: ``q`` is
+    viewed as ``[b, kv_heads, rep * m, hd]`` and both contractions run
+    batched over ``(b, kv_heads)`` directly on the stored layout.  K/V
+    are never repeated, transposed or upcast: no program-visible buffer
+    has the size of an expanded cache view (``rep == 1`` is plain MHA
+    through the same lines).
+
+    **Arithmetic.**  Operands stay in the cache's dtype, accumulation is
+    float32 (``preferred_element_type``); mask, max, exp, sum and divide
+    are float32; the probabilities are cast to V's dtype for the second
+    contraction — operation for operation what
+    ``ops.flash_attention`` does on the training path, except that the
+    scale is folded into ``q`` before the first dot (as
+    ``mha_reference`` does).  For a float32 cache that is the very op
+    sequence of ``mha_reference``, so against an uncached forward **run
+    at the same static ``max_len`` extent** every reduction sees
+    identical operand extents — masked tails are exact zeros — and the
+    result is bit-identical, per step, forever (the no-recompile serving
+    contract and the parity acceptance test in one property) — on a
+    backend whose gemm rounds a row alike at every row count.  Each
+    score row is the same dot product grouped or repeated, but XLA-CPU's
+    rounding follows the rows per batch, so at some shapes the grouped
+    read is one or two float32 ulps from the repeated one
+    (``tests/test_serving.py``; ROADMAP D1).  For a bf16 cache it is the
+    precision the model is trained under: bf16 products, float32 sums.
     """
     from apex_tpu.ops.flash_attention import _NEG_INF
 
     b, h, m, hd = qt.shape
-    max_len = kt.shape[2]
+    max_len, nkv = kc.shape[1], kc.shape[2]
+    rep = h // nkv
     scale = 1.0 / hd ** 0.5
     mp = max(m, _DECODE_QPAD)
     if m < mp:
@@ -213,9 +238,16 @@ def _cached_attention(qt, kt, vt, bounds):
         bounds = jnp.concatenate(
             [bounds, jnp.broadcast_to(bounds[:, -1:], (b, mp - m))],
             axis=1)
-    s = jax.lax.dot_general(
-        qt.astype(jnp.float32) * scale, kt.astype(jnp.float32),
-        (((3,), (3,)), ((0, 1), (0, 1))))          # [b, h, mp, max]
+    # pin the view the contractions read to the layout it is stored in:
+    # left free, XLA:TPU gives the WHOLE cache a kv-head-major layout for
+    # these two dots and copies it in and out of every decode step
+    # (measured: 20 ms a step of 16 layers against 7.5 with the barrier)
+    kc, vc = jax.lax.optimization_barrier((kc, vc))
+    qg = (qt.astype(jnp.float32) * scale).astype(kc.dtype)
+    qg = qg.reshape(b, nkv, rep * mp, hd)
+    s = jnp.einsum("bgrd,blgd->bgrl", qg, kc,
+                   preferred_element_type=jnp.float32)
+    s = s.reshape(b, h, mp, max_len)
     # masked scores sit at the flash kernels' exact _NEG_INF: exp of the
     # masked residual underflows to exactly 0.0 in f32, which is what
     # makes these fixed-extent reductions bit-exact vs a same-extent
@@ -226,17 +258,19 @@ def _cached_attention(qt, kt, vt, bounds):
     mx = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - mx)
     l = jnp.sum(e, axis=-1, keepdims=True)
-    p = e / l
-    out = jax.lax.dot_general(p, vt.astype(jnp.float32),
-                              (((3,), (2,)), ((0, 1), (0, 1))))
+    p = (e / l).astype(vc.dtype).reshape(b, nkv, rep * mp, max_len)
+    out = jnp.einsum("bgrl,blgd->bgrd", p, vc,
+                     preferred_element_type=jnp.float32)
+    out = out.reshape(b, h, mp, hd)
     return out[:, :, :m].astype(qt.dtype)           # [b, h, m, hd]
 
 
-def _decode_attention(qt, kt, vt, position):
-    """Single-token cached read: ``qt [b, h, 1, hd]``, one visibility
-    bound per slot (``idx <= position[b]``).  See
-    :func:`_cached_attention` for the masking/bit-exactness contract."""
-    return _cached_attention(qt, kt, vt,
+def _decode_attention(qt, kc, vc, position):
+    """Single-token cached read: ``qt [b, h, 1, hd]`` over ``kc``/``vc``
+    ``[b, max_len, kv_heads, hd]``, one visibility bound per slot
+    (``idx <= position[b]``).  See :func:`_cached_attention` for the
+    grouped stored-dtype read and its masking/exactness contract."""
+    return _cached_attention(qt, kc, vc,
                              jnp.asarray(position, jnp.int32)[:, None])
 
 
@@ -268,11 +302,15 @@ class LlamaMLP(nn.Module):
 
 
 class LlamaAttention(nn.Module):
-    """Grouped-query flash attention with rotary embeddings.
+    """Grouped-query attention with rotary embeddings.
 
-    kv heads are broadcast to the query-head count before the kernel (the
-    GQA share pattern); with tp, both q heads and kv heads shard over the
-    axis, so ``kv_heads % tp == 0`` is required."""
+    Query head ``j`` reads KV head ``j // (heads // kv_heads)`` (the GQA
+    share pattern).  The uncached (training) branch repeats the KV heads
+    to the query-head count before the flash kernel; the two cached
+    (serving) branches do not — :func:`_cached_attention` groups the
+    query heads over their KV head and reads the cache as it is stored.
+    With tp, both q heads and kv heads shard over the axis, so
+    ``kv_heads % tp == 0`` is required (the group size is unchanged)."""
 
     config: LlamaConfig
     sequence_parallel_enabled: bool = False
@@ -296,9 +334,13 @@ class LlamaAttention(nn.Module):
           and the chunk's causal block attends the full ``max_len``
           cache under per-row bounds (``idx <= offset + row``) — so a
           chunk reads every previously cached token through the same
-          masked, fixed-extent path decode uses, and chunk logits are
-          bit-identical to the shape-stable uncached forward (context
-          padded to ``max_len``) no matter how the prompt is split.
+          masked, fixed-extent, grouped read decode uses
+          (:func:`_cached_attention`: the slot's ``[max_len, kv_heads,
+          hd]`` rows as stored, operands in the cache's dtype, float32
+          accumulation and softmax), and chunk logits are the same bits
+          no matter how the prompt is split — for a float32 cache the
+          bits of the shape-stable uncached forward (context padded to
+          ``max_len``), for a bf16 cache the flash kernel's arithmetic.
           This mode also carries **speculative verification**
           (``DecodeEngine.verify_draft``): the per-ROW logits it
           returns are each bit-identical to the single-token decode
@@ -310,7 +352,9 @@ class LlamaAttention(nn.Module):
           per-slot depths; rope is applied at the true position, the new
           K/V are appended at ``position``, and attention reads the full
           ``max_len`` cache under a length mask — one static shape for
-          every decode step (no recompiles after warmup).
+          every decode step (no recompiles after warmup).  The read is
+          the layer's ``[slots, max_len, kv_heads, hd]`` buffer itself:
+          no head-repeated, transposed or float32 copy of it is built.
 
         Returns ``out`` (training) or ``(out, kv_cache)`` (serving).
         """
@@ -363,7 +407,10 @@ class LlamaAttention(nn.Module):
             # logits (the dense-vs-paged parity contract).  The KV-int8
             # twins ride the same branches: the cache primitives are
             # polymorphic (quant caches dequantize inside the read), so
-            # attention itself never spells a scale
+            # attention itself never spells a scale.  Every view reaches
+            # _cached_attention with the cache's own head count and
+            # layout ([.., max_len, nkv, hd]): the GQA grouping happens
+            # on the query side
             paged = isinstance(kv_cache,
                                (pkv.PagedKVCache, pkv.QuantPagedKVCache))
             if decode:
@@ -389,14 +436,8 @@ class LlamaAttention(nn.Module):
                     kc, vc = kvc.decode_read(kv_cache, layer_idx)
                     kc = kc.astype(q.dtype)
                     vc = vc.astype(q.dtype)
-                if nkv != nq:
-                    rep = nq // nkv
-                    kc = jnp.repeat(kc, rep, axis=2)
-                    vc = jnp.repeat(vc, rep, axis=2)
                 qt = q.transpose(1, 2, 0, 3)        # [b, nq, 1, hd]
-                kt = kc.transpose(0, 2, 1, 3)       # [b, nq, max, hd]
-                vt = vc.transpose(0, 2, 1, 3)
-                ctx = _decode_attention(qt, kt, vt, position)
+                ctx = _decode_attention(qt, kc, vc, position)
             else:
                 # chunked prefill: write the chunk's K/V at the offset,
                 # then attend over the whole masked cache — the chunk's
@@ -423,16 +464,10 @@ class LlamaAttention(nn.Module):
                     kc, vc = kvc.slot_read(kv_cache, layer_idx, slot)
                     kc = kc.astype(q.dtype)         # [max, nkv, hd]
                     vc = vc.astype(q.dtype)
-                if nkv != nq:
-                    rep = nq // nkv
-                    kc = jnp.repeat(kc, rep, axis=1)
-                    vc = jnp.repeat(vc, rep, axis=1)
                 qt = q.transpose(1, 2, 0, 3)        # [1, nq, s, hd]
-                kt = kc.transpose(1, 0, 2)[None]    # [1, nq, max, hd]
-                vt = vc.transpose(1, 0, 2)[None]
                 bounds = (offset
                           + jnp.arange(s, dtype=jnp.int32))[None]  # [1, s]
-                ctx = _cached_attention(qt, kt, vt, bounds)
+                ctx = _cached_attention(qt, kc[None], vc[None], bounds)
         if kv_cache is None:
             # GQA: each kv head serves nq/nkv query heads
             if nkv != nq:

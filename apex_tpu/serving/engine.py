@@ -34,19 +34,25 @@ the full ``max_len`` axis — ``O(bucket * max_len)`` — while the
 bucket-scaled projections/MLP/head dominate at transformer widths; see
 ``docs/api/serving.md`` for the honest accounting.)
 
-Numerics contract (the acceptance bar): prefill *and* greedy
-incremental decode through the cache are **bit-identical** — same f32
-logits — to the *shape-stable* uncached full-context forward (context
-padded to ``max_len``, the recompile-free form a TPU server would
-actually run) at every length and under every chunk split, and produce
-the identical greedy argmax stream as the unpadded forward, including
-GQA configs.  Ingredients: rope applied at the true position through
-``_rope_freqs``'s offset paths, attention reads masked with the flash
-kernels' exact ``-1e30`` (masked ``exp`` underflows to 0.0, so
+Numerics contract (the acceptance bar): in a float32 engine prefill
+*and* greedy incremental decode through the cache are **bit-identical**
+— same f32 logits — to the *shape-stable* uncached full-context forward
+(context padded to ``max_len``, the recompile-free form a TPU server
+would actually run) at every length and under every chunk split, and
+produce the identical greedy argmax stream as the unpadded forward,
+including GQA configs.  Ingredients: rope applied at the true position
+through ``_rope_freqs``'s offset paths, attention reads masked with the
+flash kernels' exact ``-1e30`` (masked ``exp`` underflows to 0.0, so
 same-extent reductions round identically; see
 ``models.llama._cached_attention``), and logits through the same
 ``parallel_lm_logits`` head matmul as the plain forward (the fused LM
-*head-loss* kernel is training-only — serving has no labels).
+*head-loss* kernel is training-only — serving has no labels).  The
+cached read takes the cache as it is stored — query heads grouped over
+their KV head, K/V neither repeated nor upcast, operands in the cache's
+dtype with float32 accumulation and softmax — so a bf16 engine runs the
+flash kernel's arithmetic (bf16 products, float32 sums), and decode,
+chunked prefill and speculative verification share that one read and
+so one arithmetic in either precision.
 
 Sampling is a pure function of ``(logits, key, temperature, top_k)``
 with explicit PRNG keys — no ambient state, so a replayed request

@@ -1,0 +1,133 @@
+"""The serving programs compiled for a described TPU v5e, without the chip.
+
+The cached read's speed on the chip rests on a layout decision of
+XLA:TPU that no CPU test can see: ``_cached_attention`` fences the cache
+view with ``jax.lax.optimization_barrier`` so that the two grouped
+contractions read it in the layout it is stored in.  Left free, the
+compiler gives the WHOLE cache a kv-head-major layout for those dots and
+copies it in and out of every decode step (PERF.md §6, PR 26: 30.9 ms a
+16-layer step against 19.1).  That side effect of the barrier is
+unspecified, and the jaxpr-level structural test in ``test_serving.py``
+sits above layout assignment; this file compiles the engine's own
+programs with the TPU compiler that is installed here (nothing runs, no
+time is measured) and reads the compiled text: a ``copy`` or
+``transpose`` of the cache's dtype as large as one layer's slab is the
+regression.
+
+All such compiles live in this one file and describe the topology inside
+a fixture: only one process at a time may load the TPU's library.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from apex_tpu import serving as sv
+from apex_tpu.models import LlamaConfig, LlamaForCausalLM
+from apex_tpu.serving.kv_cache import init_cache
+
+# the serving cell's attention geometry (benchmark/configs/
+# mistral-7b-l16.json and traffic/chat-closed.json): GQA 32:8, head 128,
+# 16 slots of 2048 rows, 512-row chunks, bf16.  Depth, MLP width and
+# vocabulary are cut: they hold no cache
+CFG = LlamaConfig(vocab_size=256, hidden_size=4096, intermediate_size=256,
+                  num_hidden_layers=2, num_attention_heads=32,
+                  num_key_value_heads=8, max_position_embeddings=4096)
+SLOTS, MAX_LEN, CHUNK = 16, 2048, 512
+SLAB = SLOTS * MAX_LEN * CFG.kv_heads * (CFG.hidden_size
+                                         // CFG.num_attention_heads)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """The engine whose jitted programs are compiled: real widths, zero
+    weights (only shapes matter), and a small cache of its own — the
+    cell-sized cache is handed to ``lower`` as shapes."""
+    model = LlamaForCausalLM(CFG)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    params = jax.tree.map(
+        lambda l: jnp.zeros(l.shape,
+                            jnp.bfloat16 if l.ndim >= 2 else l.dtype),
+        shapes)
+    return sv.DecodeEngine(model, params, slots=2, max_len=CHUNK,
+                           prefill_len=CHUNK, cache_dtype=jnp.bfloat16)
+
+
+def _compiled_text(engine, one_chip, program):
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = on_chip(jax.eval_shape(
+        lambda: init_cache(CFG, slots=SLOTS, max_len=MAX_LEN,
+                           dtype=jnp.bfloat16)))
+    params = on_chip(engine.params)
+    if program == "decode":
+        lowered = engine._decode.lower(
+            params, cache, arg((SLOTS,), jnp.int32), arg((SLOTS,), bool))
+    else:
+        lowered = engine._prefill.lower(
+            params, cache, arg((1, CHUNK), jnp.int32), arg((), jnp.int32),
+            arg((), jnp.int32), arg((), jnp.int32))
+    return lowered.compile().as_text()
+
+
+def _slab_sized_layout_copies(text, dtype="bf16"):
+    """Instructions of the compiled program's entry computation that
+    write a buffer of the cache's dtype, at least one layer's slab large,
+    as a ``copy`` or ``transpose`` (alone or as a fusion XLA names after
+    one).  A ``copy`` INSIDE a convolution fusion is the dot reading its
+    operand turned on the fly and writes nothing; a ``slice`` of the
+    cache or a prefetch into another memory space is no layout copy."""
+    found = []
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if not m or m.group(2) != dtype:
+            continue
+        name, _, dims, op = m.groups()
+        if not (op in ("copy", "transpose") or op == "fusion"
+                and name.startswith(("copy", "transpose"))):
+            continue
+        size = 1
+        for d in dims.split(","):
+            size *= int(d) if d else 1
+        if size >= SLAB:
+            found.append(f"{op} %{name} {dtype}[{dims}]")
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_compiled_program_copies_no_cache_slab(engine, one_chip, program):
+    text = _compiled_text(engine, one_chip, program)
+    # the check reads the TPU compiler's program, not the CPU's
+    assert "bf16[%d,%d,%d,%d,%d]" % (
+        CFG.num_hidden_layers, SLOTS, MAX_LEN, CFG.kv_heads,
+        CFG.hidden_size // CFG.num_attention_heads) in text
+    assert ":T(8,128)" in text
+    copies = _slab_sized_layout_copies(text)
+    assert not copies, (
+        f"{program}: the compiled program copies the cache into another "
+        f"layout ({len(copies)} slab-sized copies, e.g. {copies[:3]}): "
+        "the cached read no longer takes it as it is stored")

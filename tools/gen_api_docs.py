@@ -453,8 +453,19 @@ changing any shape).  Attention always
 reads the full `max_len` axis under a per-row visibility bound whose
 masked scores sit at the flash kernels' exact `-1e30`:
 `exp(masked - max)` underflows to exactly `0.0`, so the fixed-extent
-softmax is *bit-identical* to a same-extent uncached forward — masking
-is correctness, not approximation.  Bytes past `lengths` (chunk
+softmax of a float32 cache is *bit-identical* to a same-extent uncached
+forward — masking is correctness, not approximation.  The read takes
+the cache **as it is stored** (`models.llama._cached_attention`): query
+heads are grouped over their KV head (`[slots, kv_heads, rep * rows,
+head_dim]` against the `[slots, max_len, kv_heads, head_dim]` buffer,
+both contractions batched over `(slot, kv_head)`), K/V are never
+repeated to the query-head count nor upcast, the operands keep the
+cache's dtype and only the accumulation, the mask and the softmax are
+float32 — for a bf16 cache the flash kernel's arithmetic, the
+precision the model is trained under.  No buffer of the size of an
+expanded or float32 cache view exists in any program
+(`tests/test_serving.py` walks the decode and prefill programs for
+one).  Bytes past `lengths` (chunk
 padding, evicted streams) are garbage by contract and unreadable by
 construction.
 
@@ -565,7 +576,10 @@ Cost model, stated honestly: a chunk's attention reads the **full
 `max_len` cache axis** (that fixed extent *is* the bit-exactness and
 no-recompile mechanism, shared with decode), so per-chunk attention is
 `O(bucket x max_len)` where the old single-program prefill paid
-`O(prefill_len^2)` causal.  The projections/MLP/LM-head — the dominant
+`O(prefill_len^2)` causal.  What it moves: the slot's stored K/V rows
+once (`max_len x kv_heads x head_dim` in the cache's dtype — not
+repeated per query head, not upcast) and the `[heads, bucket, max_len]`
+float32 scores.  The projections/MLP/LM-head — the dominant
 cost at transformer widths — scale with the *bucket*, which is what
 bucketing shrinks.  At `max_len >> prefill_len` the attention term
 grows; a length-bucketed cache *read* window would recover it but
@@ -640,7 +654,9 @@ several tokens without changing a single emitted bit:
   enabled or disabled (tier-1 pins the equality).
 
 Honest accounting: a verify of width w costs ~w× the projections/MLP
-FLOPs of a decode step plus the same fixed-extent attention read, so
+FLOPs of a decode step plus the same fixed-extent attention read (the
+K/V bytes of the slot's `max_len` rows once, in the cache's dtype,
+whatever w is: the w rows join the grouped query block), so
 the win is `(accepted + 1)` tokens per dispatch *minus* that wider
 dispatch — large when traffic is repetitive (summarization, code edit,
 RAG with quoted context, self-repeating generations), ≈ 1.0x when the
@@ -698,8 +714,9 @@ counts).
   for that exact token prefix — snapshotted and written back
   bit-for-bit on the dense path, or *the very same physical block*
   read through the table gather on the paged path — and the resumed
-  chunk reads the whole masked cache through the same fixed-extent
-  attention as always.  Nothing in the pipeline rounds, re-orders, or
+  chunk reads the whole masked cache through the same fixed-extent,
+  grouped, stored-dtype attention read as always.  Nothing in the
+  pipeline rounds differently, re-orders, or
   approximates — so a hit changes *nothing*: logits, tokens, and
   greedy streams are bit-identical to the cold path (tier-1 pins the
   full trajectory, `tests/test_serving_prefix.py` dense,

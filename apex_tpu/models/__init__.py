@@ -10,6 +10,9 @@ optimizers).  This package holds the production-shaped model definitions:
   sharding, flash attention, fused LM-head loss.
 - :mod:`apex_tpu.models.vit` — Vision Transformer classifier (patch
   embedding, pre-LN encoder over the tp layers, fused LN kernels).
+- :mod:`apex_tpu.models.nemotron_h` — Nemotron-H hybrid decoder, serving
+  only: Mamba-2, latent routed experts and rope-free GQA by a pattern
+  string, one mixer a layer.
 """
 
 from apex_tpu.models.llama import LlamaConfig, LlamaForCausalLM
@@ -19,9 +22,10 @@ from apex_tpu.models.llama_pipeline import (
     init_llama_pipeline_params,
     make_llama_3d_train_step,
 )
+from apex_tpu.models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
 from apex_tpu.models.vit import ViTConfig, ViTForImageClassification
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaPipeConfig",
            "build_llama_pipeline", "init_llama_pipeline_params",
-           "make_llama_3d_train_step", "ViTConfig",
-           "ViTForImageClassification"]
+           "make_llama_3d_train_step", "NemotronHConfig",
+           "NemotronHForCausalLM", "ViTConfig", "ViTForImageClassification"]

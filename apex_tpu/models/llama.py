@@ -542,7 +542,8 @@ class LlamaForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, labels=None, deterministic: bool = True,
-                 *, kv_cache=None, position=None, slot=None):
+                 *, kv_cache=None, position=None, slot=None, length=None,
+                 active=None):
         """Forward pass; optionally in KV-cached serving mode.
 
         With ``kv_cache`` (a :class:`apex_tpu.serving.kv_cache.KVCache`)
@@ -555,8 +556,12 @@ class LlamaForCausalLM(nn.Module):
         [slots]`` runs one batched decode step (see
         :class:`apex_tpu.serving.engine.DecodeEngine`).  ``labels``
         is a training-only argument and rejected in serving mode.  The
-        default (``kv_cache=None``) path is unchanged.
+        default (``kv_cache=None``) path is unchanged.  ``length`` (a
+        chunk's real rows) and ``active`` (a decode step's live lanes)
+        are what ``DecodeEngine`` tells every model; K/V rows are hidden
+        after the fact by the slot lengths, so this one ignores both.
         """
+        del length, active
         cfg = self.config
         if kv_cache is not None and labels is not None:
             raise ValueError("kv_cache is a serving-mode argument; "

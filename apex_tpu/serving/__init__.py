@@ -10,7 +10,11 @@ head matmul), its amp policies, and its resilience checkpoints:
   lengths and pure shape-stable updates (drop-mode row scatter for
   prefill chunks, vmapped ``lax.dynamic_update_slice`` for decode
   appends): one static shape for every decode step, zero recompiles
-  after warmup.
+  after warmup.  A model whose layers are not all attention declares
+  what each keeps a slot (``KVRows``, ``RecurrentRows``,
+  ``CallCounters``) and is served from one :class:`HybridCache`: K/V
+  rows for the layers that have them, a :class:`RecurrentState` for the
+  rest.
 - :mod:`.paged_kv_cache` — the opt-in **paged** layout
   (``DecodeEngine(..., paged=PagedCacheConfig(...))``): a global pool
   of fixed-size K/V blocks (``[layers, num_blocks, block_size,
@@ -162,10 +166,16 @@ from apex_tpu.serving.engine import (
     tp_param_shardings,
 )
 from apex_tpu.serving.kv_cache import (
+    CallCounters,
+    HybridCache,
     KVCache,
+    KVRows,
     QuantKVCache,
+    RecurrentRows,
+    RecurrentState,
     append_token,
     init_cache,
+    init_hybrid_cache,
     init_quant_cache,
     prefill_into_slot,
     read_slot_region,
@@ -228,6 +238,12 @@ from apex_tpu.serving.weights import load_serving_params
 
 __all__ = [
     "KVCache",
+    "HybridCache",
+    "RecurrentState",
+    "KVRows",
+    "RecurrentRows",
+    "CallCounters",
+    "init_hybrid_cache",
     "append_token",
     "init_cache",
     "prefill_into_slot",

@@ -74,10 +74,13 @@ from jax.sharding import NamedSharding, PartitionSpec
 from apex_tpu._logging import emit_event, get_logger
 from apex_tpu.obs import trace as obs_trace
 from apex_tpu.serving.kv_cache import (
+    CallCounters,
     KVCache,
+    RecurrentRows,
     commit_slot_length,
     gather_slot_rows,
     init_cache,
+    init_hybrid_cache,
     init_quant_cache,
     release_slot,
     value_dtype,
@@ -326,6 +329,29 @@ class DecodeEngine:
         self.model = model
         self.params = params
         self.slots = int(slots)
+        # a model whose layers are not all attention declares what each
+        # layer keeps a slot (K/V rows, a recurrent state, counters) and
+        # the cache is built from that; a model that declares nothing is a
+        # stack of attention layers sized from its config, as ever.  What
+        # moves, shares or rolls back K/V rows knows nothing of a recurrent
+        # state, so each such mechanism is refused here by name
+        declare = getattr(model, "cache_layers", None)
+        self._layers = None if declare is None else tuple(declare())
+        self._recurrent = self._layers is not None and any(
+            isinstance(l, RecurrentRows) for l in self._layers)
+        if self._layers is not None:
+            for given, what in (
+                    (paged is not None, "paged= (a block table pages K/V "
+                     "rows; a recurrent state has no rows to page)"),
+                    (tp is not None, "tp= (the model's mixers have no "
+                     "tensor-parallel layout)"),
+                    (quant is not None and quant.kv, "QuantConfig(kv=True) "
+                     "(the int8 cache is sized from a config of attention "
+                     "layers; a float32 state is not quantized)")):
+                if given:
+                    raise ValueError(
+                        f"{type(model).__name__} declares per-layer state "
+                        f"(cache_layers()); it cannot be served with {what}")
         # opt-in tensor parallelism: validate the head/vocab split up
         # front (a bad divisor must fail at construction, not as an XLA
         # sharding error three calls later) and build the serving mesh.
@@ -397,6 +423,9 @@ class DecodeEngine:
         elif kv_int8:
             fresh = init_quant_cache(model.config, slots=slots,
                                      max_len=max_len)
+        elif self._layers is not None:
+            fresh = init_hybrid_cache(self._layers, slots=slots,
+                                      max_len=max_len, dtype=cache_dtype)
         else:
             fresh = init_cache(model.config, slots=slots, max_len=max_len,
                                dtype=cache_dtype)
@@ -487,9 +516,12 @@ class DecodeEngine:
             # already cached in the slot; length = REAL tokens in this
             # chunk.  Returns the logits at the chunk's last real
             # position (the next-token distribution after the final
-            # chunk) + the filled cache.
+            # chunk) + the filled cache.  The model is told the length
+            # too: K/V rows of the padding are hidden afterwards by the
+            # commit below, a recurrent state must not take them in at all
             logits, cache = model.apply(dq(params), ids, kv_cache=cache,
-                                        slot=slot, position=offset)
+                                        slot=slot, position=offset,
+                                        length=length)
             cache = commit_slot_length(cache, slot, offset + length)
             last = lax.dynamic_index_in_dim(logits[:, 0, :], length - 1,
                                             axis=0, keepdims=False)
@@ -512,8 +544,12 @@ class DecodeEngine:
                                      jnp.int32(-1))
             else:
                 position = cache.lengths
+            # the model is told the active lanes too: an idle lane's K/V
+            # write is hidden by its length, its recurrent state must not
+            # move
             logits, cache = model.apply(dq(params), tokens[:, None],
-                                        kv_cache=cache, position=position)
+                                        kv_cache=cache, position=position,
+                                        active=active)
             cache = dataclasses.replace(
                 cache,
                 lengths=cache.lengths + active.astype(jnp.int32))
@@ -753,6 +789,29 @@ class DecodeEngine:
         if not 0 <= slot < self.slots:
             raise ValueError(f"slot {slot} out of range [0, {self.slots})")
 
+    @property
+    def recurrent_state(self) -> bool:
+        """Whether some layer of the model keeps a recurrent state a slot
+        (then nothing that copies, shares or rolls back K/V rows serves)."""
+        return self._recurrent
+
+    def _refuse_recurrent(self, what: str) -> None:
+        if self._recurrent:
+            raise ValueError(
+                f"{what} on a model with recurrent state "
+                f"({type(self.model).__name__}): it moves K/V rows, and a "
+                f"slot's recurrent state is not among them")
+
+    def moe_stats(self) -> dict:
+        """What the counting layers (routed experts) added up over every
+        decode step so far: ``{name: int64 [counting layers]}``, empty for
+        a model that declares none.  ONE readback, when asked: the counts
+        ride the cache pytree and cost a decode step no transfer."""
+        names = next((l.names for l in self._layers or ()
+                      if isinstance(l, CallCounters)), ())
+        counts = np.asarray(self._cache.counters, np.int64) if names else ()
+        return {name: counts[:, i] for i, name in enumerate(names)}
+
     def release(self, slot: int) -> None:
         """Evict a slot (O(1)); its bytes stay masked until overwritten.
         Paged engines also drop the slot's block references — blocks
@@ -774,6 +833,14 @@ class DecodeEngine:
                  else jax.device_put(np.zeros((self.slots,), np.int32),
                                      self._host_target))
         self._cache = dataclasses.replace(self._cache, lengths=zeros)
+        if self._layers is not None:
+            # committed like every jit output, or the next call retraces
+            self._cache = dataclasses.replace(
+                self._cache, **jax.device_put(
+                    {"state": jax.tree.map(jnp.zeros_like,
+                                           self._cache.state),
+                     "counters": jnp.zeros_like(self._cache.counters)},
+                    self._device))
         self._lengths_host[:] = 0
         self._restored.clear()
         if self._pager is not None:
@@ -1000,6 +1067,7 @@ class DecodeEngine:
         parallel-sampling / n-best primitive)."""
         self._check_slot(src)
         self._check_slot(dst)
+        self._refuse_recurrent("fork_slot")
         if self._pager is None:
             raise ValueError("fork_slot on a dense engine — the dense "
                              "layout has no shareable blocks")
@@ -1176,6 +1244,7 @@ class DecodeEngine:
         blocks into one span read, so its compiles are bounded by
         ``ceil(prefill_len / block_size)`` distinct extents."""
         self._check_slot(slot)
+        self._refuse_recurrent("read_region / prefix capture")
         if self._pager is not None:
             raise ValueError(
                 "read_region on a paged engine — prefix capture is "
@@ -1214,6 +1283,7 @@ class DecodeEngine:
         (:meth:`slot_block_ids` + pool refcounts), never by copy.
         """
         self._check_slot(slot)
+        self._refuse_recurrent("capture_slot (preemption snapshot)")
         if self._pager is not None:
             raise ValueError(
                 "capture_slot on a paged engine — capture by reference "
@@ -1276,6 +1346,7 @@ class DecodeEngine:
         compute the next-token logits the stream needs.
         """
         self._check_slot(slot)
+        self._refuse_recurrent("restore_prefix")
         if self._pager is not None:
             raise ValueError(
                 "restore_prefix on a paged engine — hits alias shared "
@@ -1420,6 +1491,8 @@ class DecodeEngine:
         the same invariant a plain decode step leaves.
         """
         self._check_slot(slot)
+        self._refuse_recurrent("verify_draft (speculation rolls rejected "
+                               "rows back by a length)")
         k = len(tokens) - 1
         if k < 1:
             raise ValueError(

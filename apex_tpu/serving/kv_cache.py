@@ -54,7 +54,10 @@ __all__ = ["KVCache", "QuantKVCache", "init_cache", "init_quant_cache",
            "prefill_into_slot", "append_token", "commit_slot_length",
            "release_slot", "valid_token_mask", "read_slot_region",
            "write_slot_region", "decode_read", "slot_read", "value_dtype",
-           "gather_slot_rows"]
+           "gather_slot_rows", "KVRows", "RecurrentRows", "CallCounters",
+           "RecurrentState", "HybridCache", "init_hybrid_cache",
+           "slot_state", "write_slot_state", "write_lane_state",
+           "add_counts"]
 
 
 @functools.partial(jax.tree_util.register_dataclass,
@@ -421,3 +424,158 @@ def valid_token_mask(positions, max_len: int):
     """
     idx = jnp.arange(max_len, dtype=jnp.int32)[None, :]
     return idx <= jnp.asarray(positions, jnp.int32)[:, None]
+
+
+# ---- per-layer state of a model whose layers are not all attention --------
+#
+# A model that is not a stack of identical attention layers declares, layer
+# by layer, what a slot keeps between calls (``model.cache_layers()``: one
+# of the three declarations below, or None, a layer): K/V rows that grow
+# with the sequence, a recurrent state of fixed size, or counters the layer
+# adds to a call.  :func:`init_hybrid_cache` builds ONE pytree from the
+# declarations; the K/V primitives above work on it unchanged (its ``k`` /
+# ``v`` hold the K/V layers only, in declaration order), and the model owns
+# the map from its layer index to the index on each leading axis.
+
+
+@dataclasses.dataclass(frozen=True)
+class KVRows:
+    """A layer that keeps ``[max_len, kv_heads, head_dim]`` K and V rows a
+    slot."""
+
+    kv_heads: int
+    head_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class RecurrentRows:
+    """A layer that keeps a fixed-size state a slot: ``ssm`` (float32: it is
+    summed into at every token) and ``conv`` (the last inputs of a causal
+    convolution, in the weights' type).  Both are shapes without the slot
+    axis."""
+
+    ssm: tuple
+    conv: tuple
+
+
+@dataclasses.dataclass(frozen=True)
+class CallCounters:
+    """A layer that adds ``len(names)`` int32 counts a decode step, read
+    back by ``DecodeEngine.moe_stats`` when somebody asks."""
+
+    names: tuple
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("ssm", "conv"), meta_fields=())
+@dataclasses.dataclass(frozen=True)
+class RecurrentState:
+    """``ssm [layers, slots, *RecurrentRows.ssm]`` float32 and ``conv
+    [layers, slots, *RecurrentRows.conv]``: what the recurrent layers carry
+    from one call to the next, one row a slot.
+
+    Unlike a K/V row, a state cannot be hidden after the fact by a length:
+    every write decides at the write what is real.  A chunk that starts a
+    prompt reads zeros whatever the slot holds (:func:`slot_state`), so a
+    released slot needs no clearing; a decode step writes only the active
+    lanes (:func:`write_lane_state`), so an idle lane keeps its state bit
+    for bit."""
+
+    ssm: jax.Array
+    conv: jax.Array
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("k", "v", "lengths", "state", "counters"),
+                   meta_fields=())
+@dataclasses.dataclass(frozen=True)
+class HybridCache(KVCache):
+    """A :class:`KVCache` over the layers that declared :class:`KVRows`,
+    plus the :class:`RecurrentState` of the layers that declared
+    :class:`RecurrentRows` and ``counters [counting layers, names]`` int32.
+    ``lengths`` counts a slot's tokens for every kind of layer alike."""
+
+    state: RecurrentState
+    counters: jax.Array
+
+
+def _one_shape(layers, kind, what: str):
+    found = {l for l in layers if isinstance(l, kind)}
+    if len(found) > 1:
+        raise ValueError(
+            f"layers declare different {what}: {sorted(map(str, found))} - "
+            f"one stacked array holds one shape")
+    return (next(iter(found)) if found else None,
+            sum(isinstance(l, kind) for l in layers))
+
+
+def init_hybrid_cache(layers, *, slots: int, max_len: int,
+                      dtype=jnp.float32) -> HybridCache:
+    """Zero-filled cache for a model's per-layer declarations (``layers``:
+    a :class:`KVRows`, :class:`RecurrentRows`, :class:`CallCounters` or None
+    a layer)."""
+    kv, n_kv = _one_shape(layers, KVRows, "K/V rows")
+    rec, n_rec = _one_shape(layers, RecurrentRows, "recurrent states")
+    cnt, n_cnt = _one_shape(layers, CallCounters, "counters")
+    kv_shape = (n_kv, slots, max_len) + (
+        (kv.kv_heads, kv.head_dim) if kv else (0, 0))
+    ssm, conv = (rec.ssm, rec.conv) if rec else ((0,), (0,))
+    return HybridCache(
+        k=jnp.zeros(kv_shape, dtype), v=jnp.zeros(kv_shape, dtype),
+        lengths=jnp.zeros((slots,), jnp.int32),
+        state=RecurrentState(
+            ssm=jnp.zeros((n_rec, slots) + tuple(ssm), jnp.float32),
+            conv=jnp.zeros((n_rec, slots) + tuple(conv), dtype)),
+        counters=jnp.zeros((n_cnt, len(cnt.names) if cnt else 0), jnp.int32))
+
+
+def slot_state(cache: HybridCache, layer: int, slot, offset):
+    """One slot's ``(ssm, conv)`` for one recurrent layer as a chunk at
+    ``offset`` must see it: zeros at offset 0 - a slot's next request never
+    starts from the last one's state, and releasing a slot costs nothing -
+    and what the previous chunk left otherwise."""
+    s = jnp.asarray(slot, jnp.int32)
+    fresh = jnp.asarray(offset, jnp.int32) == 0
+
+    def one(stack):
+        # layer and slot in ONE slice: ``stack[layer]`` first makes XLA:TPU
+        # copy the layer's state of every slot (268 MB at the cell's 64
+        # slots, 0.82 ms a layer a chunk) to read one slot's 4 MB
+        # (PERF.md section 6, PR 27; tests/test_serving_aot.py)
+        rows = lax.dynamic_slice(
+            stack, (layer, s) + (0,) * (stack.ndim - 2),
+            (1, 1) + stack.shape[2:])[0, 0]
+        return jnp.where(fresh, jnp.zeros_like(rows), rows)
+
+    return one(cache.state.ssm), one(cache.state.conv)
+
+
+def write_slot_state(cache: HybridCache, layer: int, slot, ssm,
+                     conv) -> HybridCache:
+    """Store one slot's state after a prefill chunk."""
+    s = jnp.asarray(slot, jnp.int32)
+    st = cache.state
+    return dataclasses.replace(cache, state=RecurrentState(
+        ssm=st.ssm.at[layer, s].set(ssm.astype(st.ssm.dtype)),
+        conv=st.conv.at[layer, s].set(conv.astype(st.conv.dtype))))
+
+
+def write_lane_state(cache: HybridCache, layer: int, ssm, conv,
+                     active) -> HybridCache:
+    """Store every slot's state after a decode step; lanes not ``active``
+    keep what they had."""
+    st = cache.state
+
+    def keep(new, old):
+        on = active.reshape((-1,) + (1,) * (old.ndim - 1))
+        return jnp.where(on, new.astype(old.dtype), old)
+
+    return dataclasses.replace(cache, state=RecurrentState(
+        ssm=st.ssm.at[layer].set(keep(ssm, st.ssm[layer])),
+        conv=st.conv.at[layer].set(keep(conv, st.conv[layer]))))
+
+
+def add_counts(cache: HybridCache, layer: int, counts) -> HybridCache:
+    """Add one call's counts to a counting layer's row."""
+    return dataclasses.replace(
+        cache, counters=cache.counters.at[layer].add(counts))

@@ -31,7 +31,14 @@ import jax.numpy as jnp
 
 import flax.linen as nn
 
-__all__ = ["ExpertParallelMLP", "top1_dispatch"]
+from apex_tpu.ops._dispatch import (
+    lane_aligned,
+    record_dispatch,
+    use_interpret,
+)
+
+__all__ = ["ExpertParallelMLP", "top1_dispatch", "LatentMoE",
+           "topk_sigmoid_route", "grouped_matmul", "MOE_COUNTERS"]
 
 
 def top1_dispatch(logits32, capacity: int):
@@ -134,3 +141,163 @@ class ExpertParallelMLP(nn.Module):
 
         out = jnp.einsum("tec,ech->th", combine.astype(x.dtype), expert_out)
         return out, aux
+
+
+# what LatentMoE counts a call, in this order (int32): calls, tokens routed
+# (valid rows), token-expert pairs computed here, held experts with a pair,
+# largest number of pairs on one held expert
+MOE_COUNTERS = ("steps", "tokens", "pairs", "touched", "max_load")
+
+# gmm's tile: up to 1024 x 1024 of an expert's matrix a grid step, so that
+# a step's ~0.35 us is paid once per 2 MB read and not once per 32 KB, and 32
+# rows of pairs a step where a call has few (a decode step: ~3 pairs an
+# expert), 128 where it has many (a prefill chunk).  On one v5e at this
+# model's shapes (128 held experts, 1024 -> 2688 -> 1024, both products;
+# tools/grouped_matmul_bench.py, PERF.md section 6, PR 27): 64 tokens x 22
+# pairs 2.0 ms against jax.lax.ragged_dot's 4.9 and the default 128^3
+# tile's 13.7; 512 x 22 pairs 3.1 ms against 9.2 and 17.3
+_GMM_TILE_KN = 1024
+_GMM_TILE_M, _GMM_TILE_M_FEW, _GMM_FEW_ROWS = 128, 32, 2048
+
+
+def topk_sigmoid_route(x, kernel, bias, top_k: int, scale: float):
+    """Sigmoid-scored top-k routing with a selection bias (DeepSeek-V3 /
+    Nemotron-H): scores ``sigmoid(x W_r)`` in float32 over every published
+    expert, the ``top_k`` largest of ``score + bias`` chosen, weights the
+    chosen *scores* normalised to sum 1 and multiplied by ``scale``.
+    Returns ``(chosen [t, top_k] int32, weights [t, top_k] float32)``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), kernel.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), weights
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs[rows of group g] @ rhs[g]`` for the ``rhs.shape[0]`` groups
+    held; ``group_sizes`` has one more entry, the rows at the end that
+    belong to no group held here, whose output rows the caller masks.
+    ``lhs [m, k]`` sorted by group, ``rhs [g, k, n]``; float32 out.
+
+    On the chip, at tile-aligned shapes, the ``gmm`` kernel that ships with
+    jax (it visits only the tiles of non-empty groups, and reads a touched
+    expert's matrix once a row tile); everywhere else
+    ``jax.lax.ragged_dot``."""
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tm = _GMM_TILE_M_FEW if m <= _GMM_FEW_ROWS else _GMM_TILE_M
+    if record_dispatch("moe_gmm", lane_aligned(k, n) and m % tm == 0,
+                       m=m, k=k, n=n):
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        def tile(dim):
+            # the largest multiple of 128 up to _GMM_TILE_KN that divides dim
+            return max(t for t in range(128, min(_GMM_TILE_KN, dim) + 1, 128)
+                       if dim % t == 0)
+
+        return gmm(lhs, rhs, group_sizes,
+                   preferred_element_type=jnp.float32,
+                   tiling=(tm, tile(k), tile(n)), interpret=use_interpret())
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes[:rhs.shape[0]],
+                              preferred_element_type=jnp.float32)
+
+
+class LatentMoE(nn.Module):
+    """One chip's share of a routed-expert layer whose experts live in a
+    latent space (Nemotron-H ``E`` layers), plus the shared expert.
+
+    The router is as wide as the model's ``num_experts`` and keeps the
+    published ``top_k`` and weights; this layer holds the experts
+    ``[experts_held[0], experts_held[0] + experts_held[1])`` and computes
+    their part of the result for the token-expert pairs that land on them:
+    ``W_up (sum over chosen e held here of w_e W2_e relu(W1_e W_down x)^2)``.
+    Nothing is dropped: the pairs are sorted by expert and each projection
+    is one grouped matrix product over the experts held
+    (:func:`grouped_matmul`) with room for every pair.  What experts held
+    elsewhere would add is left out; in an expert-parallel deployment the
+    tokens are exchanged over the chips before and after this layer, and on
+    one chip it runs without that exchange.  The shared expert reads the
+    hidden vector and is whole here.
+
+    ``x [tokens, hidden]``; ``valid [tokens]`` bool marks the rows that are
+    real (a prefill bucket's padding and a decode step's inactive lanes are
+    not): their pairs are computed nowhere and counted nowhere.  Returns
+    ``(out [tokens, hidden], counts [len(MOE_COUNTERS)] int32)``.
+    """
+
+    num_experts: int
+    experts_held: Tuple[int, int]
+    top_k: int
+    hidden_size: int
+    latent_size: int
+    expert_width: int
+    shared_width: int
+    routed_scaling_factor: float = 1.0
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    @jax.named_scope("latent_moe")
+    def __call__(self, x, valid=None) -> Tuple[jax.Array, jax.Array]:
+        t, h = x.shape
+        lo, held = self.experts_held
+        if not 0 <= lo <= lo + held <= self.num_experts or held < 1:
+            raise ValueError(
+                f"experts_held {self.experts_held}: a range (start, count) "
+                f"inside the {self.num_experts} routed experts")
+        k = self.top_k
+        normal = nn.initializers.normal(0.02)
+
+        def dense(name, features):
+            return nn.Dense(features, use_bias=False, dtype=x.dtype,
+                            param_dtype=self.param_dtype, kernel_init=normal,
+                            name=name)
+
+        router_kernel = self.param("router_kernel", normal,
+                                   (h, self.num_experts), jnp.float32)
+        router_bias = self.param("router_bias", nn.initializers.zeros,
+                                 (self.num_experts,), jnp.float32)
+        w1 = self.param("experts_w1", normal,
+                        (held, self.latent_size, self.expert_width),
+                        self.param_dtype)
+        w2 = self.param("experts_w2", normal,
+                        (held, self.expert_width, self.latent_size),
+                        self.param_dtype)
+
+        chosen, weights = topk_sigmoid_route(
+            x, router_kernel, router_bias, k, self.routed_scaling_factor)
+        here = (chosen >= lo) & (chosen < lo + held)
+        if valid is not None:
+            here &= valid[:, None]
+        # pairs sorted by the expert held here they land on; the rest sort
+        # to the end as group ``held``, which no matrix is multiplied for
+        key = jnp.where(here, chosen - lo, held).reshape(t * k)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+        token_of = order // k
+
+        latent = dense("latent_down", self.latent_size)(x)
+        rows = latent[token_of]                              # [t k, latent]
+        hid = grouped_matmul(rows, w1.astype(x.dtype), sizes)
+        hid = jnp.square(jax.nn.relu(hid)).astype(x.dtype)
+        out = grouped_matmul(hid, w2.astype(x.dtype), sizes)
+        # a row past the held groups is whatever the product left there
+        out = jnp.where((key[order] < held)[:, None],
+                        out * weights.reshape(t * k)[order][:, None], 0.0)
+        # back to token order: pair (token, j) sits at sorted row inverse[.]
+        inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32))
+        routed = out[inverse].reshape(t, k, self.latent_size).sum(1)
+        routed = dense("latent_up", h)(routed.astype(x.dtype))
+
+        shared = jnp.square(jax.nn.relu(
+            dense("shared_up", self.shared_width)(x)))
+        shared = dense("shared_down", h)(shared)
+
+        load = sizes[:held]
+        tokens = t if valid is None else valid.sum()
+        counts = jnp.stack([jnp.int32(1), jnp.asarray(tokens, jnp.int32),
+                            load.sum(), (load > 0).sum().astype(jnp.int32),
+                            load.max()])
+        return routed + shared, counts

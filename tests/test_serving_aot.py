@@ -131,3 +131,83 @@ def test_compiled_program_copies_no_cache_slab(engine, one_chip, program):
         f"{program}: the compiled program copies the cache into another "
         f"layout ({len(copies)} slab-sized copies, e.g. {copies[:3]}): "
         "the cached read no longer takes it as it is stored")
+
+
+# -- a model with recurrent state (PR 27) -----------------------------------
+# the new cell's Mamba-2 geometry (benchmark/configs/
+# nemotron3-super-ep4-l11.json, traffic/chat-closed-64.json): 128 heads of
+# 64 x 128 float32 state, 64 slots.  No expert layer: it keeps no state
+
+HYBRID_SLOTS = 64
+
+
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    from apex_tpu.models.nemotron_h import (
+        NemotronHConfig,
+        NemotronHForCausalLM,
+    )
+
+    model = NemotronHForCausalLM(NemotronHConfig(
+        vocab_size=256, hidden_size=4096, hybrid_override_pattern="M*M",
+        num_attention_heads=32, num_key_value_heads=2, head_dim=128,
+        mamba_num_heads=128, mamba_head_dim=64, n_groups=8,
+        ssm_state_size=128, conv_kernel=4, chunk_size=128),
+        params_dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    params = jax.tree.map(lambda l: jnp.zeros(l.shape, l.dtype), shapes)
+    return sv.DecodeEngine(model, params, slots=2, max_len=64,
+                           prefill_len=64, cache_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("bucket", [16])
+def test_prefill_chunk_reads_one_slots_state_not_every_slots(
+        hybrid_engine, one_chip, bucket):
+    """A chunk belongs to one slot.  Reading that slot's state through
+    ``state.ssm[layer][slot]`` made the compiled program copy the layer's
+    state of all 64 slots first: a ``slice`` of 268 MB a recurrent layer,
+    4.1 of the 11-29 ms of every prefill call on the chip (PERF.md §6,
+    PR 27)."""
+    from apex_tpu.serving.kv_cache import init_hybrid_cache
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    model = hybrid_engine.model
+    cache = on_chip(jax.eval_shape(lambda: init_hybrid_cache(
+        model.cache_layers(), slots=HYBRID_SLOTS, max_len=MAX_LEN,
+        dtype=jnp.bfloat16)))
+    text = hybrid_engine._prefill.lower(
+        on_chip(hybrid_engine.params), cache, arg((1, bucket), jnp.int32),
+        arg((), jnp.int32), arg((), jnp.int32),
+        arg((), jnp.int32)).compile().as_text()
+    cfg = model.config
+    one_slot = cfg.mamba_num_heads * cfg.mamba_head_dim * cfg.ssm_state_size
+    assert "f32[2,%d,%d,%d,%d]" % (
+        HYBRID_SLOTS, cfg.mamba_num_heads, cfg.mamba_head_dim,
+        cfg.ssm_state_size) in text
+    found = []
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = f32\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        # the in-place write of the slot's new state is a fusion over the
+        # whole (donated) array and copies nothing
+        if not m or not (
+                m.group(3) in ("slice", "copy", "transpose")
+                or m.group(3) == "fusion" and m.group(1).startswith(
+                    ("slice", "copy", "transpose"))):
+            continue
+        size = 1
+        for d in m.group(2).split(","):
+            size *= int(d) if d else 1
+        if size >= HYBRID_SLOTS * one_slot // 2:
+            found.append(f"{m.group(3)} %{m.group(1)} f32[{m.group(2)}]")
+    assert not found, (
+        f"the prefill program copies the state of every slot "
+        f"({found[:3]}): a chunk reads and writes one slot's state")

@@ -68,6 +68,7 @@ PAGES = {
     "models": ("Model zoo", [
         "apex_tpu.models", "apex_tpu.models.llama",
         "apex_tpu.models.llama_pipeline", "apex_tpu.models.vit",
+        "apex_tpu.models.nemotron_h",
     ]),
     "contrib": ("Contrib extensions", [
         "apex_tpu.contrib.xentropy", "apex_tpu.contrib.focal_loss",

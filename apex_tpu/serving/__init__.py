@@ -7,9 +7,9 @@ head matmul), its amp policies, and its resilience checkpoints:
 
 - :mod:`.kv_cache` — preallocated slot-indexed decode cache
   (``[layers, slots, max_len, kv_heads, head_dim]``) with per-slot
-  lengths and pure shape-stable updates (drop-mode row scatter for
-  prefill chunks, vmapped ``lax.dynamic_update_slice`` for decode
-  appends): one static shape for every decode step, zero recompiles
+  lengths and pure shape-stable updates (drop-mode row scatters on
+  the whole buffer, a chunk's rows for prefill and one row a lane for
+  decode appends): one static shape for every decode step, zero recompiles
   after warmup.  A model whose layers are not all attention declares
   what each keeps a slot (``KVRows``, ``RecurrentRows``,
   ``CallCounters``) and is served from one :class:`HybridCache`: K/V

@@ -490,8 +490,8 @@ class DecodeEngine:
         self._restored: dict[int, int] = {}
         # host mirror of per-slot lengths: lets every call validate slot
         # bounds and cache capacity WITHOUT a device->host sync on the
-        # decode hot path (dynamic_update_slice clamps out-of-range
-        # indices silently — overflow must be an error, not corruption)
+        # decode hot path (the cache's scatters drop out-of-range rows
+        # silently — overflow must be an error, not a lost token)
         self._lengths_host = np.zeros((self.slots,), np.int64)
         # monotonic weight-buffer generation: bumped by swap_params so
         # host layers (the prefix cache's version tags, the reloader's

@@ -5,8 +5,8 @@ on Google Cloud TPU") comes from never letting XLA see a new shape after
 warmup: the cache is **preallocated** at ``[layers, slots, max_len,
 kv_heads, head_dim]``, every update is a shape-stable write into that
 fixed buffer (a drop-mode row scatter for prefill chunks — overhanging
-bucket padding must be dropped, never clamped backward — and a vmapped
-``lax.dynamic_update_slice`` for decode appends), and attention reads
+bucket padding must be dropped, never clamped backward — and one row
+per lane, scattered the same way, for decode appends), and attention reads
 the *whole* ``max_len`` axis with a per-slot length mask — so one
 compiled decode step serves every request mix, every sequence length,
 and every slot assignment with zero retraces.
@@ -221,37 +221,43 @@ def append_token(cache: KVCache, layer: int, k_tok, v_tok,
 
     ``k_tok`` / ``v_tok``: ``[slots, kv_heads, head_dim]``; ``positions``:
     ``[slots]`` int32 (normally ``cache.lengths`` — the next free index).
-    A vmapped ``dynamic_update_slice`` keeps the write shape-stable: the
+    One row scatter a buffer, on the WHOLE ``[layers, slots, max_len,
+    ...]`` array at ``(layer, lane, positions[lane])`` — the spelling of
+    :func:`prefill_into_slot` and ``paged_append``.  Shape-stable: the
     batched decode step compiles once no matter how slot positions drift
     apart under continuous batching.
+
+    A position outside ``[0, max_len)`` is DROPPED (``mode="drop"``; a
+    negative one too, which plain indexing would wrap to the slot's
+    end), never clamped back onto a cached row: a lane at ``length ==
+    max_len`` leaves its last real row alone.
+
+    Why the whole buffer and not the layer's slab (``cache.k[layer]``
+    updated and set back): XLA:TPU runs the scatter in place on the
+    donated cache, a few rows a layer, where the slab spelling copied
+    the layer's ``[slots, max_len, ...]`` slab out, looped over the
+    slots and wrote the slab back — 8.6 GB of traffic a step for 1 MB of
+    new rows in the Mistral cell (PERF.md §6, PR 28;
+    ``tests/test_serving_aot.py`` reads the compiled program for it).
     """
-    def write_one(buf, tok, pos):  # buf [max_len, kvh, hd]
-        return lax.dynamic_update_slice(
-            buf, tok.astype(buf.dtype)[None], (pos, 0, 0))
-
-    def write_scale(buf, tok, pos):  # buf [max_len, kvh]
-        return lax.dynamic_update_slice(buf, tok[None], (pos, 0))
-
     pos = jnp.asarray(positions, jnp.int32)
+    rows = jnp.where(pos < 0, cache.max_len, pos)
+    lanes = jnp.arange(pos.shape[0], dtype=jnp.int32)
+
+    def put(buf, tok):
+        return buf.at[layer, lanes, rows].set(tok.astype(buf.dtype),
+                                              mode="drop")
+
     if isinstance(cache, QuantKVCache):
+        # the scale rows ride the payload's indices: a dropped lane
+        # drops BOTH
         kq, ks = quantize_int8(k_tok, axis=-1)    # [slots, kvh, hd] -> ..
         vq, vs = quantize_int8(v_tok, axis=-1)    # .. + scale [slots, kvh]
         return dataclasses.replace(
-            cache,
-            k=cache.k.at[layer].set(
-                jax.vmap(write_one)(cache.k[layer], kq, pos)),
-            v=cache.v.at[layer].set(
-                jax.vmap(write_one)(cache.v[layer], vq, pos)),
-            k_scale=cache.k_scale.at[layer].set(
-                jax.vmap(write_scale)(cache.k_scale[layer], ks, pos)),
-            v_scale=cache.v_scale.at[layer].set(
-                jax.vmap(write_scale)(cache.v_scale[layer], vs, pos)))
-    return dataclasses.replace(
-        cache,
-        k=cache.k.at[layer].set(jax.vmap(write_one)(cache.k[layer], k_tok,
-                                                    pos)),
-        v=cache.v.at[layer].set(jax.vmap(write_one)(cache.v[layer], v_tok,
-                                                    pos)))
+            cache, k=put(cache.k, kq), v=put(cache.v, vq),
+            k_scale=put(cache.k_scale, ks), v_scale=put(cache.v_scale, vs))
+    return dataclasses.replace(cache, k=put(cache.k, k_tok),
+                               v=put(cache.v, v_tok))
 
 
 def read_slot_region(cache: KVCache, slot, start, stop) -> tuple:

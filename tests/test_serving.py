@@ -28,8 +28,11 @@ import numpy as np
 import pytest
 
 from apex_tpu import serving as sv
+from apex_tpu.amp.quant import quantize_int8
 from apex_tpu.models import LlamaConfig, LlamaForCausalLM
 from apex_tpu.serving.kv_cache import (
+    KVCache,
+    QuantKVCache,
     append_token,
     init_cache,
     prefill_into_slot,
@@ -243,6 +246,54 @@ def test_kv_cache_primitive_updates():
     mask = np.asarray(valid_token_mask(jnp.asarray([0, 2]), 5))
     assert mask.dtype == bool
     assert mask.astype(int).tolist() == [[1, 0, 0, 0, 0], [1, 1, 1, 0, 0]]
+
+
+@pytest.mark.parametrize("positions", [[0, 5, 15], [3, 16, 7], [-1, 2, 16]],
+                         ids=["in-range", "at-max_len", "negative"])
+@pytest.mark.parametrize("kind", ["fp", "int8"])
+def test_append_token_writes_its_rows_and_nothing_else(kind, positions):
+    """``append_token`` on a filled three-layer cache changes exactly the
+    rows ``(layer, lane, positions[lane])`` of every buffer (payload and,
+    for int8, scales); a lane whose position is ``max_len`` or negative
+    is dropped, not clamped onto its last or first cached row."""
+    layers, slots, max_len, layer = 3, 3, 16, 1
+    shape = (layers, slots, max_len, CFG.kv_heads,
+             CFG.hidden_size // CFG.num_attention_heads)
+    rng = np.random.default_rng(0)
+    lengths = jnp.asarray([4, 0, 9], jnp.int32)
+    k_tok, v_tok = (jnp.asarray(rng.standard_normal((slots,) + shape[3:]),
+                                jnp.float32) for _ in range(2))
+    if kind == "fp":
+        cache = KVCache(
+            k=jnp.asarray(rng.standard_normal(shape), jnp.float32),
+            v=jnp.asarray(rng.standard_normal(shape), jnp.float32),
+            lengths=lengths)
+        new = {"k": k_tok, "v": v_tok}
+    else:
+        cache = QuantKVCache(
+            k=jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+            v=jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+            k_scale=jnp.asarray(rng.uniform(0.5, 2.0, shape[:-1]),
+                                jnp.float32),
+            v_scale=jnp.asarray(rng.uniform(0.5, 2.0, shape[:-1]),
+                                jnp.float32),
+            lengths=lengths)
+        # under jit like the write: an eager scale differs in the last bit
+        (kq, ks), (vq, vs) = (
+            jax.jit(lambda t: quantize_int8(t, axis=-1))(t)
+            for t in (k_tok, v_tok))
+        new = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+
+    out = jax.jit(append_token, static_argnums=1)(
+        cache, layer, k_tok, v_tok, jnp.asarray(positions, jnp.int32))
+
+    for name, rows in new.items():
+        want = np.array(getattr(cache, name))
+        for lane, pos in enumerate(positions):
+            if 0 <= pos < max_len:
+                want[layer, lane, pos] = np.asarray(rows)[lane]
+        assert np.array_equal(np.asarray(getattr(out, name)), want), name
+    assert np.array_equal(np.asarray(out.lengths), np.asarray(lengths))
 
 
 # ---------------------------------------------------------------------------

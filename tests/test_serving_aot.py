@@ -12,12 +12,15 @@ sits above layout assignment; this file compiles the engine's own
 programs with the TPU compiler that is installed here (nothing runs, no
 time is measured) and reads the compiled text: a ``copy`` or
 ``transpose`` of the cache's dtype as large as one layer's slab is the
-regression.
+regression.  The same text says whether the decode step's append moves
+rows or slabs (PERF.md §6, PR 28).
 
 All such compiles live in this one file and describe the topology inside
 a fixture: only one process at a time may load the TPU's library.
 """
 
+import functools
+import math
 import os
 import re
 
@@ -70,6 +73,14 @@ def engine():
                            prefill_len=CHUNK, cache_dtype=jnp.bfloat16)
 
 
+@pytest.fixture(scope="module")
+def compiled_text(engine, one_chip):
+    """``compiled_text(program)``: each program is compiled once for the
+    module, whatever number of tests read its text."""
+    return functools.cache(
+        functools.partial(_compiled_text, engine, one_chip))
+
+
 def _compiled_text(engine, one_chip, program):
     def on_chip(tree):
         return jax.tree.map(
@@ -93,34 +104,36 @@ def _compiled_text(engine, one_chip, program):
     return lowered.compile().as_text()
 
 
-def _slab_sized_layout_copies(text, dtype="bf16"):
+def _entry_ops(text, dtype="bf16"):
+    """``(name, op, sizes)`` of each instruction of the compiled
+    program's entry computation: ``sizes`` are the element counts of its
+    results of ``dtype`` (several where it returns a tuple)."""
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, result, op = m.groups()
+        yield name, op, [
+            math.prod(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(r"\b%s\[([\d,]*)\]" % dtype, result)]
+
+
+def _slab_sized_layout_copies(text):
     """Instructions of the compiled program's entry computation that
     write a buffer of the cache's dtype, at least one layer's slab large,
     as a ``copy`` or ``transpose`` (alone or as a fusion XLA names after
     one).  A ``copy`` INSIDE a convolution fusion is the dot reading its
     operand turned on the fly and writes nothing; a ``slice`` of the
     cache or a prefetch into another memory space is no layout copy."""
-    found = []
-    for line in text[text.index("\nENTRY "):].splitlines():
-        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
-                     r"([\w\-]+)\(", line)
-        if not m or m.group(2) != dtype:
-            continue
-        name, _, dims, op = m.groups()
-        if not (op in ("copy", "transpose") or op == "fusion"
-                and name.startswith(("copy", "transpose"))):
-            continue
-        size = 1
-        for d in dims.split(","):
-            size *= int(d) if d else 1
-        if size >= SLAB:
-            found.append(f"{op} %{name} {dtype}[{dims}]")
-    return found
+    return [f"{op} %{name}" for name, op, sizes in _entry_ops(text)
+            if (op in ("copy", "transpose") or op == "fusion"
+                and name.startswith(("copy", "transpose")))
+            and any(size >= SLAB for size in sizes)]
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_compiled_program_copies_no_cache_slab(engine, one_chip, program):
-    text = _compiled_text(engine, one_chip, program)
+def test_compiled_program_copies_no_cache_slab(compiled_text, program):
+    text = compiled_text(program)
     # the check reads the TPU compiler's program, not the CPU's
     assert "bf16[%d,%d,%d,%d,%d]" % (
         CFG.num_hidden_layers, SLOTS, MAX_LEN, CFG.kv_heads,
@@ -131,6 +144,32 @@ def test_compiled_program_copies_no_cache_slab(engine, one_chip, program):
         f"{program}: the compiled program copies the cache into another "
         f"layout ({len(copies)} slab-sized copies, e.g. {copies[:3]}): "
         "the cached read no longer takes it as it is stored")
+
+
+def test_decode_append_moves_rows_not_slabs(compiled_text):
+    """``append_token`` is one scatter on the whole donated cache a layer
+    and a buffer.  Spelt as an update of the layer's slab
+    (``cache.k.at[layer].set(vmap(dynamic_update_slice)(cache.k[layer],
+    ...))``) the compiled step cut the 67 MB slab out, looped over the
+    slots and wrote it back, for K and for V, every layer: 8.6 GB a step
+    for 1 MB of new rows (PERF.md §6, PR 28).  What may remain is the
+    read's cut, one slab for K and one for V a layer (ROADMAP S1b)."""
+    ops = list(_entry_ops(compiled_text("decode")))
+    layers = CFG.num_hidden_layers
+    loops = [name for name, op, _ in ops if op == "while"]
+    assert not loops, f"the decode step loops over the slots: {loops}"
+    rewrites = [name for name, op, sizes in ops
+                if op == "fusion" and "dynamic-update-slice" in name
+                and any(size >= layers * SLAB for size in sizes)]
+    assert not rewrites, (
+        f"the decode step writes whole slabs back into the cache: "
+        f"{rewrites}")
+    cuts = [name for name, op, sizes in ops
+            if (op == "slice" or op == "fusion" and name.startswith("slice"))
+            and any(size >= SLAB for size in sizes)]
+    assert len(cuts) <= 2 * layers, (
+        f"{len(cuts)} slab-sized cuts of the cache where the read takes "
+        f"{2 * layers}: {cuts}")
 
 
 # -- a model with recurrent state (PR 27) -----------------------------------
@@ -192,22 +231,12 @@ def test_prefill_chunk_reads_one_slots_state_not_every_slots(
     assert "f32[2,%d,%d,%d,%d]" % (
         HYBRID_SLOTS, cfg.mamba_num_heads, cfg.mamba_head_dim,
         cfg.ssm_state_size) in text
-    found = []
-    for line in text[text.index("\nENTRY "):].splitlines():
-        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = f32\[([\d,]*)\]\S* "
-                     r"([\w\-]+)\(", line)
-        # the in-place write of the slot's new state is a fusion over the
-        # whole (donated) array and copies nothing
-        if not m or not (
-                m.group(3) in ("slice", "copy", "transpose")
-                or m.group(3) == "fusion" and m.group(1).startswith(
-                    ("slice", "copy", "transpose"))):
-            continue
-        size = 1
-        for d in m.group(2).split(","):
-            size *= int(d) if d else 1
-        if size >= HYBRID_SLOTS * one_slot // 2:
-            found.append(f"{m.group(3)} %{m.group(1)} f32[{m.group(2)}]")
+    # the in-place write of the slot's new state is a fusion over the
+    # whole (donated) array and copies nothing
+    found = [f"{op} %{name}" for name, op, sizes in _entry_ops(text, "f32")
+             if (op in ("slice", "copy", "transpose") or op == "fusion"
+                 and name.startswith(("slice", "copy", "transpose")))
+             and any(size >= HYBRID_SLOTS * one_slot // 2 for size in sizes)]
     assert not found, (
         f"the prefill program copies the state of every slot "
         f"({found[:3]}): a chunk reads and writes one slot's state")

@@ -448,9 +448,10 @@ One slot per in-flight request.  Prefill writes a (padded) prompt chunk
 at the slot's current depth with one per-row scatter (`mode="drop"`:
 bucket padding overhanging the cache end is dropped, never clamped
 backward onto cached tokens); each decode step appends one token per
-slot at that slot's own depth (a vmapped dynamic-update — per-slot
-positions drift apart freely under continuous batching without
-changing any shape).  Attention always
+slot at that slot's own depth (one more such scatter on the whole
+buffer, in place on the donated cache: per-slot positions drift apart
+freely under continuous batching without changing any shape, and a
+position outside `[0, max_len)` is dropped).  Attention always
 reads the full `max_len` axis under a per-row visibility bound whose
 masked scores sit at the flash kernels' exact `-1e30`:
 `exp(masked - max)` underflows to exactly `0.0`, so the fixed-extent

@@ -87,7 +87,7 @@ def _route_pair_bias(q, k, v, mask, bias):
     if q.ndim != 5 or bias is None or bias.ndim != 5:
         return None
     b, r, h, s, d = q.shape
-    # measured on v5e (tools/openfold_microbench.py): at Evoformer scale
+    # measured on v5e (PERF_NOTES.md, r2): at Evoformer scale
     # (s=256, d=32) the materialized XLA path runs at its bandwidth
     # roofline (4.5 ms) while the kernel's per-tile overhead dominates
     # (89 ms) — the kernel only wins once the s^2 scores are too big to

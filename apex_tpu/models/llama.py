@@ -168,112 +168,6 @@ def _rope_freqs(s: int, dim: int, theta: float, offset=0) -> jax.Array:
     return jnp.concatenate([f, f], axis=-1)[:, None, None, :]  # [s,1,1,d]
 
 
-# cached-attention query blocks are padded to at least this many rows
-# per query head: XLA-CPU lowers an M=1 score "matmul" as a gemv whose
-# per-element rounding differs from the gemm the uncached forward's
-# [s, s] scores go through; M>=8 keeps both paths in the gemm regime so
-# the float32 dot products round identically (pinned by
-# tests/test_serving.py bit-parity).  On the chip the pad makes a KV
-# head's query block rep * 8 rows: an MXU tile at GQA 4:1
-_DECODE_QPAD = 8
-
-
-def _cached_attention(qt, kc, vc, bounds):
-    """Length-masked attention read over a full KV-cache buffer, as the
-    cache stores it.
-
-    ``qt``: ``[b, h, m, hd]`` query rows; ``kc``/``vc``: ``[b, max_len,
-    kv_heads, hd]`` — the cache's own layout and head count, in the
-    dtype the cache hands back; ``bounds``: ``[b, m]`` int32 — row ``i``
-    of batch element ``b`` attends cache positions ``idx <=
-    bounds[b, i]``; everything past its bound is masked garbage.  Two
-    callers: single-token decode (``m == 1``, one bound per slot) and
-    chunked prefill / speculative verification (``m == chunk``,
-    ``bounds[0, i] = offset + i`` — the chunk's causal block over the
-    previously cached context).
-
-    **Grouped, not repeated.**  Query head ``j`` reads KV head ``j //
-    rep`` (``rep = h // kv_heads``, the ``jnp.repeat`` share pattern of
-    the uncached branch), so consecutive query heads group: ``q`` is
-    viewed as ``[b, kv_heads, rep * m, hd]`` and both contractions run
-    batched over ``(b, kv_heads)`` directly on the stored layout.  K/V
-    are never repeated, transposed or upcast: no program-visible buffer
-    has the size of an expanded cache view (``rep == 1`` is plain MHA
-    through the same lines).
-
-    **Arithmetic.**  Operands stay in the cache's dtype, accumulation is
-    float32 (``preferred_element_type``); mask, max, exp, sum and divide
-    are float32; the probabilities are cast to V's dtype for the second
-    contraction — operation for operation what
-    ``ops.flash_attention`` does on the training path, except that the
-    scale is folded into ``q`` before the first dot (as
-    ``mha_reference`` does).  For a float32 cache that is the very op
-    sequence of ``mha_reference``, so against an uncached forward **run
-    at the same static ``max_len`` extent** every reduction sees
-    identical operand extents — masked tails are exact zeros — and the
-    result is bit-identical, per step, forever (the no-recompile serving
-    contract and the parity acceptance test in one property) — on a
-    backend whose gemm rounds a row alike at every row count.  Each
-    score row is the same dot product grouped or repeated, but XLA-CPU's
-    rounding follows the rows per batch, so at some shapes the grouped
-    read is one or two float32 ulps from the repeated one
-    (``tests/test_serving.py``; ROADMAP D1).  For a bf16 cache it is the
-    precision the model is trained under: bf16 products, float32 sums.
-    """
-    from apex_tpu.ops.flash_attention import _NEG_INF
-
-    b, h, m, hd = qt.shape
-    max_len, nkv = kc.shape[1], kc.shape[2]
-    rep = h // nkv
-    scale = 1.0 / hd ** 0.5
-    mp = max(m, _DECODE_QPAD)
-    if m < mp:
-        # pad the query block with copies of its last row (same bound):
-        # the extra rows are sliced off below, and per-row results are
-        # M-extent-invariant in the gemm regime, so padding never moves
-        # a real row's bits
-        qt = jnp.concatenate(
-            [qt, jnp.broadcast_to(qt[:, :, -1:], (b, h, mp - m, hd))],
-            axis=2)
-        bounds = jnp.concatenate(
-            [bounds, jnp.broadcast_to(bounds[:, -1:], (b, mp - m))],
-            axis=1)
-    # pin the view the contractions read to the layout it is stored in:
-    # left free, XLA:TPU gives the WHOLE cache a kv-head-major layout for
-    # these two dots and copies it in and out of every decode step
-    # (measured: 20 ms a step of 16 layers against 7.5 with the barrier)
-    kc, vc = jax.lax.optimization_barrier((kc, vc))
-    qg = (qt.astype(jnp.float32) * scale).astype(kc.dtype)
-    qg = qg.reshape(b, nkv, rep * mp, hd)
-    s = jnp.einsum("bgrd,blgd->bgrl", qg, kc,
-                   preferred_element_type=jnp.float32)
-    s = s.reshape(b, h, mp, max_len)
-    # masked scores sit at the flash kernels' exact _NEG_INF: exp of the
-    # masked residual underflows to exactly 0.0 in f32, which is what
-    # makes these fixed-extent reductions bit-exact vs a same-extent
-    # uncached forward
-    idx = jnp.arange(max_len, dtype=jnp.int32)
-    valid = idx[None, None, :] <= bounds[:, :, None]   # [b, mp, max]
-    s = jnp.where(valid[:, None], s, _NEG_INF)
-    mx = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - mx)
-    l = jnp.sum(e, axis=-1, keepdims=True)
-    p = (e / l).astype(vc.dtype).reshape(b, nkv, rep * mp, max_len)
-    out = jnp.einsum("bgrl,blgd->bgrd", p, vc,
-                     preferred_element_type=jnp.float32)
-    out = out.reshape(b, h, mp, hd)
-    return out[:, :, :m].astype(qt.dtype)           # [b, h, m, hd]
-
-
-def _decode_attention(qt, kc, vc, position):
-    """Single-token cached read: ``qt [b, h, 1, hd]`` over ``kc``/``vc``
-    ``[b, max_len, kv_heads, hd]``, one visibility bound per slot
-    (``idx <= position[b]``).  See :func:`_cached_attention` for the
-    grouped stored-dtype read and its masking/exactness contract."""
-    return _cached_attention(qt, kc, vc,
-                             jnp.asarray(position, jnp.int32)[:, None])
-
-
 class LlamaMLP(nn.Module):
     """SwiGLU: down( silu(gate(x)) * up(x) )."""
 
@@ -307,8 +201,9 @@ class LlamaAttention(nn.Module):
     Query head ``j`` reads KV head ``j // (heads // kv_heads)`` (the GQA
     share pattern).  The uncached (training) branch repeats the KV heads
     to the query-head count before the flash kernel; the two cached
-    (serving) branches do not — :func:`_cached_attention` groups the
-    query heads over their KV head and reads the cache as it is stored.
+    (serving) branches do not — the cache's read
+    (:func:`apex_tpu.serving.kv_cache.cached_attention`) groups the query
+    heads over their KV head and reads the cache as it is stored.
     With tp, both q heads and kv heads shard over the axis, so
     ``kv_heads % tp == 0`` is required (the group size is unchanged)."""
 
@@ -324,7 +219,10 @@ class LlamaAttention(nn.Module):
         """Causal self-attention; optionally reading/writing a KV cache.
 
         Without ``kv_cache`` this is the training path, unchanged.  With
-        one (see :mod:`apex_tpu.serving.kv_cache`), two serving modes:
+        one, two serving modes, each one call into
+        :mod:`apex_tpu.serving.kv_cache` (``prefill_attend`` /
+        ``decode_attend``: write, view, cast, masked grouped read, for
+        whatever layout and storage format the cache has):
 
         - **chunked prefill** (``s > 1``): ``position`` is a scalar
           offset — the number of tokens already cached in ``slot``
@@ -335,7 +233,7 @@ class LlamaAttention(nn.Module):
           cache under per-row bounds (``idx <= offset + row``) — so a
           chunk reads every previously cached token through the same
           masked, fixed-extent, grouped read decode uses
-          (:func:`_cached_attention`: the slot's ``[max_len, kv_heads,
+          (``cached_attention``: the slot's ``[max_len, kv_heads,
           hd]`` rows as stored, operands in the cache's dtype, float32
           accumulation and softmax), and chunk logits are the same bits
           no matter how the prompt is split — for a float32 cache the
@@ -394,80 +292,6 @@ class LlamaAttention(nn.Module):
         q = fused_apply_rotary_pos_emb(q, freqs)
         k = fused_apply_rotary_pos_emb(k, freqs)
 
-        if kv_cache is not None:
-            from apex_tpu.serving import kv_cache as kvc
-            from apex_tpu.serving import paged_kv_cache as pkv
-
-            # the cache's pytree type is a trace-time constant, so this
-            # branch costs nothing at runtime: a paged cache writes
-            # through the slot's block table and reads the same
-            # [max_len]-extent view back out of the pool via a
-            # fixed-extent gather — identical values at every unmasked
-            # position, identical reduction extents, hence bit-identical
-            # logits (the dense-vs-paged parity contract).  The KV-int8
-            # twins ride the same branches: the cache primitives are
-            # polymorphic (quant caches dequantize inside the read), so
-            # attention itself never spells a scale.  Every view reaches
-            # _cached_attention with the cache's own head count and
-            # layout ([.., max_len, nkv, hd]): the GQA grouping happens
-            # on the query side
-            paged = isinstance(kv_cache,
-                               (pkv.PagedKVCache, pkv.QuantPagedKVCache))
-            if decode:
-                # append this token per slot, then attend over the whole
-                # masked cache (post-rope K, like the uncached path sees)
-                if paged:
-                    # inactive lanes arrive as position -1: a paged
-                    # table has no private masked scratch rows, so
-                    # their writes are dropped instead of routed
-                    kv_cache = pkv.paged_append(
-                        kv_cache, layer_idx, k[0], v[0],
-                        jnp.asarray(position))
-                    kc, vc = pkv.decode_view(kv_cache, layer_idx)
-                    kc = kc.astype(q.dtype)         # [b, max, nkv, hd]
-                    vc = vc.astype(q.dtype)
-                else:
-                    kv_cache = kvc.append_token(
-                        kv_cache, layer_idx, k[0], v[0],
-                        jnp.asarray(position))
-                    # decode_read is the fp buffer rows verbatim (same
-                    # trace as indexing .k directly) or the dequantized
-                    # KV-int8 view — [b, max, nkv, hd] either way
-                    kc, vc = kvc.decode_read(kv_cache, layer_idx)
-                    kc = kc.astype(q.dtype)
-                    vc = vc.astype(q.dtype)
-                qt = q.transpose(1, 2, 0, 3)        # [b, nq, 1, hd]
-                ctx = _decode_attention(qt, kc, vc, position)
-            else:
-                # chunked prefill: write the chunk's K/V at the offset,
-                # then attend over the whole masked cache — the chunk's
-                # own rows AND every previously cached token go through
-                # one fixed-extent read, so splitting a prompt into
-                # chunks never changes any bit
-                if b != 1:
-                    raise ValueError(
-                        f"prefill expects one slot per call (b=1), got "
-                        f"b={b}")
-                if paged:
-                    kv_cache = pkv.paged_prefill_write(
-                        kv_cache, layer_idx, slot, k[:, 0], v[:, 0],
-                        start=offset)
-                    kc, vc = pkv.prefill_view(kv_cache, layer_idx, slot)
-                    kc = kc.astype(q.dtype)         # [max, nkv, hd]
-                    vc = vc.astype(q.dtype)
-                else:
-                    kv_cache = kvc.prefill_into_slot(
-                        kv_cache, layer_idx, slot, k[:, 0], v[:, 0],
-                        start=offset)
-                    # slot_read: the same dynamic_index_in_dim gather as
-                    # before for an fp cache, dequantized for KV-int8
-                    kc, vc = kvc.slot_read(kv_cache, layer_idx, slot)
-                    kc = kc.astype(q.dtype)         # [max, nkv, hd]
-                    vc = vc.astype(q.dtype)
-                qt = q.transpose(1, 2, 0, 3)        # [1, nq, s, hd]
-                bounds = (offset
-                          + jnp.arange(s, dtype=jnp.int32))[None]  # [1, s]
-                ctx = _cached_attention(qt, kc[None], vc[None], bounds)
         if kv_cache is None:
             # GQA: each kv head serves nq/nkv query heads
             if nkv != nq:
@@ -479,6 +303,16 @@ class LlamaAttention(nn.Module):
             kt = k.transpose(1, 2, 0, 3)
             vt = v.transpose(1, 2, 0, 3)
             ctx = flash_attention(qt, kt, vt, causal=True)
+        else:
+            # imported here: training imports this module without serving
+            from apex_tpu.serving.kv_cache import decode_attend, prefill_attend
+
+            if decode:
+                ctx, kv_cache = decode_attend(kv_cache, layer_idx, q, k, v,
+                                              position)
+            else:
+                ctx, kv_cache = prefill_attend(kv_cache, layer_idx, slot, q,
+                                               k, v, offset)
         ctx = ctx.transpose(2, 0, 1, 3).reshape(s, b, nq * hd)
         out = RowParallelLinear(cfg.hidden_size, cfg.hidden_size,
                                 input_is_parallel=True,
@@ -540,14 +374,24 @@ class LlamaForCausalLM(nn.Module):
     params_dtype: Any = jnp.float32
     axis_name: str = TENSOR_PARALLEL_AXIS
 
+    def cache_layers(self) -> list:
+        """What each layer keeps a slot between calls, in layer order:
+        K/V rows, every layer alike."""
+        from apex_tpu.serving.kv_cache import KVRows
+
+        cfg = self.config
+        return [KVRows(cfg.kv_heads,
+                       cfg.hidden_size // cfg.num_attention_heads)
+                ] * cfg.num_hidden_layers
+
     @nn.compact
     def __call__(self, input_ids, labels=None, deterministic: bool = True,
                  *, kv_cache=None, position=None, slot=None, length=None,
                  active=None):
         """Forward pass; optionally in KV-cached serving mode.
 
-        With ``kv_cache`` (a :class:`apex_tpu.serving.kv_cache.KVCache`)
-        the call returns ``(logits, kv_cache)`` instead of logits/loss:
+        With ``kv_cache`` (what ``DecodeEngine`` builds from
+        :meth:`cache_layers`) the call returns ``(logits, kv_cache)`` instead of logits/loss:
         ``input_ids [1, s>1]`` + ``slot`` (+ scalar ``position`` = the
         chunk's start offset, 0/None for a fresh prompt) prefills one
         chunk of one slot — the serving engine slices the last real

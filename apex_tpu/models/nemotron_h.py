@@ -16,8 +16,9 @@ state) and the decode step's ``active`` lanes (an idle lane keeps its state
 bit for bit).  :meth:`NemotronHForCausalLM.cache_layers` declares what each
 layer keeps a slot; ``DecodeEngine`` builds the cache from that.
 
-- Attention reuses ``models.llama._cached_attention`` and the cache's
-  append / prefill primitives unchanged (here 16 query heads a KV head).
+- Attention is the cache's own step (``serving.kv_cache.decode_attend`` /
+  ``prefill_attend``, here 16 query heads a KV head), as in
+  ``models.llama``.
 - Mamba-2 prefill is the chunked scan (:func:`ssd_chunked`: inside a chunk
   masked matrix products, between chunks a short scan over chunk states)
   started from the slot's carried state; decode is the one-token update
@@ -36,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from apex_tpu.models.llama import _cached_attention, _decode_attention
 from apex_tpu.normalization import FusedRMSNorm
 from apex_tpu.transformer.moe import MOE_COUNTERS, LatentMoE
 from apex_tpu.transformer.parallel_state import TENSOR_PARALLEL_AXIS
@@ -247,7 +247,11 @@ class Mamba2Mixer(nn.Module):
             return jnp.split(out, [d_inner, d_inner + groups * n], axis=-1)
 
         if kv_cache is not None:
-            from apex_tpu.serving import kv_cache as kvc
+            from apex_tpu.serving.kv_cache import (
+                slot_state,
+                write_lane_state,
+                write_slot_state,
+            )
         if kv_cache is not None and s == 1:
             st = kv_cache.state
             padded = jnp.concatenate(
@@ -257,8 +261,8 @@ class Mamba2Mixer(nn.Module):
             y, ssm = ssd_step(x, dt[0], a, b.reshape(lanes, groups, n),
                               c.reshape(lanes, groups, n),
                               st.ssm[layer_idx])
-            kv_cache = kvc.write_lane_state(kv_cache, layer_idx, ssm,
-                                            padded[:, 1:], active)
+            kv_cache = write_lane_state(kv_cache, layer_idx, ssm,
+                                        padded[:, 1:], active)
             y = (y + d_skip[:, None] * x)[None]            # [1, lanes, H, P]
         else:
             if kv_cache is not None:
@@ -266,7 +270,7 @@ class Mamba2Mixer(nn.Module):
                     raise ValueError(f"prefill expects one slot per call "
                                      f"(b=1), got b={lanes}")
                 offset = 0 if position is None else position
-                ssm0, tail = kvc.slot_state(kv_cache, layer_idx, slot, offset)
+                ssm0, tail = slot_state(kv_cache, layer_idx, slot, offset)
                 ssm0, tail = ssm0[None], tail[None].astype(u.dtype)
                 real = (jnp.arange(s) < length)[:, None, None]
                 # a padded row is no step: dt = 0 keeps the state as it is
@@ -285,8 +289,8 @@ class Mamba2Mixer(nn.Module):
             if kv_cache is not None:
                 # the tail after the last REAL row: padding leaves no trace
                 tail = lax.dynamic_slice_in_dim(padded[0], length, k - 1, 0)
-                kv_cache = kvc.write_slot_state(kv_cache, layer_idx, slot,
-                                                ssm[0], tail)
+                kv_cache = write_slot_state(kv_cache, layer_idx, slot, ssm[0],
+                                            tail)
             y = (y + d_skip[:, None] * x).transpose(1, 0, 2, 3)
 
         # gated RMSNorm over groups of d_inner / n_groups, one weight vector
@@ -306,8 +310,8 @@ class Mamba2Mixer(nn.Module):
 class NemotronHAttention(nn.Module):
     """Causal grouped-query attention with no positional embedding (the
     Mamba layers carry order).  The cached branches are
-    ``models.llama.LlamaAttention``'s without the rope: the same append /
-    prefill primitives and the same grouped stored-dtype read."""
+    ``models.llama.LlamaAttention``'s without the rope: the same two calls
+    into the cache."""
 
     config: NemotronHConfig
     params_dtype: Any = jnp.float32
@@ -332,9 +336,9 @@ class NemotronHAttention(nn.Module):
         q = q.reshape(s, b, nq, hd)
         k = k.reshape(s, b, nkv, hd)
         v = v.reshape(s, b, nkv, hd)
-        qt = q.transpose(1, 2, 0, 3)                       # [b, nq, s, hd]
         if kv_cache is None:
             rep = nq // nkv
+            qt = q.transpose(1, 2, 0, 3)                   # [b, nq, s, hd]
             kt = jnp.repeat(k, rep, axis=2).transpose(1, 2, 0, 3)
             vt = jnp.repeat(v, rep, axis=2).transpose(1, 2, 0, 3)
             scores = jnp.einsum("bhqd,bhkd->bhqk", qt, kt,
@@ -344,26 +348,16 @@ class NemotronHAttention(nn.Module):
             probs = jax.nn.softmax(scores, axis=-1).astype(vt.dtype)
             ctx = jnp.einsum("bhqk,bhkd->bhqd", probs, vt)
         else:
-            from apex_tpu.serving import kv_cache as kvc
+            from apex_tpu.serving.kv_cache import decode_attend, prefill_attend
 
             if s == 1:
-                kv_cache = kvc.append_token(kv_cache, layer_idx, k[0], v[0],
-                                            jnp.asarray(position))
-                kc, vc = kvc.decode_read(kv_cache, layer_idx)
-                ctx = _decode_attention(qt, kc.astype(q.dtype),
-                                        vc.astype(q.dtype), position)
+                ctx, kv_cache = decode_attend(kv_cache, layer_idx, q, k, v,
+                                              position)
             else:
-                if b != 1:
-                    raise ValueError(f"prefill expects one slot per call "
-                                     f"(b=1), got b={b}")
                 offset = jnp.asarray(0 if position is None else position,
                                      jnp.int32)
-                kv_cache = kvc.prefill_into_slot(
-                    kv_cache, layer_idx, slot, k[:, 0], v[:, 0], start=offset)
-                kc, vc = kvc.slot_read(kv_cache, layer_idx, slot)
-                bounds = (offset + jnp.arange(s, dtype=jnp.int32))[None]
-                ctx = _cached_attention(qt, kc.astype(q.dtype)[None],
-                                        vc.astype(q.dtype)[None], bounds)
+                ctx, kv_cache = prefill_attend(kv_cache, layer_idx, slot, q,
+                                               k, v, offset)
         ctx = ctx.transpose(2, 0, 1, 3).reshape(s, b, nq * hd)
         out = RowParallelLinear(nq * hd, cfg.hidden_size,
                                 input_is_parallel=True, name="o_proj",
@@ -419,9 +413,9 @@ class NemotronHLayer(nn.Module):
                 h.reshape(s * lanes, -1), valid)
             out = out.reshape(s, lanes, -1)
             if decode:
-                from apex_tpu.serving import kv_cache as kvc
+                from apex_tpu.serving.kv_cache import add_counts
 
-                kv_cache = kvc.add_counts(kv_cache, layer_idx, counts)
+                kv_cache = add_counts(kv_cache, layer_idx, counts)
         return x + out.astype(x.dtype), kv_cache
 
 

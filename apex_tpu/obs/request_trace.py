@@ -376,6 +376,13 @@ class RequestTraceRecorder:
                     st.scheduler_ttft_s = self._num(event, "ttft_s")
             elif kind == "serving_spec_verify":
                 st = self._get(rid, create=False)
+                if st is None and self._done and self._done[-1].rid == rid:
+                    # the verify that FINISHED the request: the scheduler
+                    # knows ``emitted`` only after the token that ended
+                    # the stream, so this event follows the terminal one
+                    # and belongs to the record it just closed
+                    st = self._done[-1]
+                    now = st.t_finished
                 if st is not None:
                     for f in ("drafted", "accepted", "emitted"):
                         v = self._num(event, f)

@@ -15,7 +15,7 @@ matmuls.  Fused, the forward reads H (16 MB) and E (103 MB) once and
 emits per-token ``loss``/``lse`` (64 KB) — nothing O(T·V) survives the
 forward.
 
-Design (hybrid, measured — tools/head_bench.py on v5e):
+Design (hybrid, measured on v5e — PERF_NOTES.md):
 
 - fwd: Pallas kernel, grid ``(T/Tb, V/Vb)`` vocab innermost: logits tile
   = H_tile @ E_tileᵀ (fp32 MXU accumulation), online max/sum-exp across
@@ -228,7 +228,7 @@ def _de_kernel(h_ref, e_ref, lab_ref, lse_ref, g_ref, de_ref, de_scr,
 def _pallas_bwd(h2, e, labels, lse, g, tb, vb):
     """Backward as two Pallas kernels recomputing logits tiles from lse.
 
-    Measured on v5e (tools/head_bench.py + bench.py): isolated, this
+    Measured on v5e (PERF_NOTES.md; bench.py): isolated, this
     double recompute (~3.4 TF) is slower than XLA's materialized backward
     (24.6 vs 19.5 ms fwd+bwd) — but *in the training step* it wins
     (212.9 vs 213.6 ms/step), and beats a single shared XLA recompute

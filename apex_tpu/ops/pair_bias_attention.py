@@ -3,7 +3,7 @@
 Parity target: ``apex.contrib.openfold_triton.mha`` (mha.py:131-460) — the
 Triton fused attention with pair bias + mask that the reference built
 because framework-level fusion materializes the score matrix.  The same
-is true of XLA: ``tools/openfold_microbench.py`` measured the one-jit jnp
+is true of XLA: a microbenchmark (PERF_NOTES.md, r2) measured the one-jit jnp
 ``attention_core`` at the *materialized* bandwidth roofline (the
 [r, h, s, s] fp32 scores round-trip HBM).  This module is the Pallas
 kernel the r2 verdict asked for — with the honest caveat the same

@@ -10,11 +10,15 @@ head matmul), its amp policies, and its resilience checkpoints:
   lengths and pure shape-stable updates (drop-mode row scatters on
   the whole buffer, a chunk's rows for prefill and one row a lane for
   decode appends): one static shape for every decode step, zero recompiles
-  after warmup.  A model whose layers are not all attention declares
-  what each keeps a slot (``KVRows``, ``RecurrentRows``,
-  ``CallCounters``) and is served from one :class:`HybridCache`: K/V
-  rows for the layers that have them, a :class:`RecurrentState` for the
-  rest.
+  after warmup.  A model declares what each layer keeps a slot
+  (``cache_layers()``: ``KVRows``, ``RecurrentRows``, ``CallCounters``)
+  and :func:`init_cache` builds every cache from that, in the layout
+  (dense / paged) and storage format (float / int8) asked for; layers
+  that are not all attention are served from one :class:`HybridCache`:
+  K/V rows for the layers that have them, a :class:`RecurrentState` for
+  the rest.  The module is also the one seam a model's attention calls
+  through (``decode_attend`` / ``prefill_attend``: write, view, cast,
+  the grouped masked read), whatever the layout and format.
 - :mod:`.paged_kv_cache` — the opt-in **paged** layout
   (``DecodeEngine(..., paged=PagedCacheConfig(...))``): a global pool
   of fixed-size K/V blocks (``[layers, num_blocks, block_size,
@@ -175,8 +179,6 @@ from apex_tpu.serving.kv_cache import (
     RecurrentState,
     append_token,
     init_cache,
-    init_hybrid_cache,
-    init_quant_cache,
     prefill_into_slot,
     read_slot_region,
     release_slot,
@@ -190,8 +192,6 @@ from apex_tpu.serving.paged_kv_cache import (
     PagedCacheManager,
     PagedKVCache,
     QuantPagedKVCache,
-    init_paged_cache,
-    init_quant_paged_cache,
 )
 from apex_tpu.serving.quant import (
     QTensor,
@@ -243,7 +243,6 @@ __all__ = [
     "KVRows",
     "RecurrentRows",
     "CallCounters",
-    "init_hybrid_cache",
     "append_token",
     "init_cache",
     "prefill_into_slot",
@@ -257,9 +256,6 @@ __all__ = [
     "PagedKVCache",
     "QuantKVCache",
     "QuantPagedKVCache",
-    "init_paged_cache",
-    "init_quant_cache",
-    "init_quant_paged_cache",
     "value_dtype",
     "QTensor",
     "QuantConfig",

@@ -44,7 +44,7 @@ including GQA configs.  Ingredients: rope applied at the true position
 through ``_rope_freqs``'s offset paths, attention reads masked with the
 flash kernels' exact ``-1e30`` (masked ``exp`` underflows to 0.0, so
 same-extent reductions round identically; see
-``models.llama._cached_attention``), and logits through the same
+``serving.kv_cache.cached_attention``), and logits through the same
 ``parallel_lm_logits`` head matmul as the plain forward (the fused LM
 *head-loss* kernel is training-only — serving has no labels).  The
 cached read takes the cache as it is stored — query heads grouped over
@@ -76,12 +76,11 @@ from apex_tpu.obs import trace as obs_trace
 from apex_tpu.serving.kv_cache import (
     CallCounters,
     KVCache,
+    KVRows,
     RecurrentRows,
     commit_slot_length,
     gather_slot_rows,
     init_cache,
-    init_hybrid_cache,
-    init_quant_cache,
     release_slot,
     value_dtype,
     write_slot_region,
@@ -89,11 +88,7 @@ from apex_tpu.serving.kv_cache import (
 from apex_tpu.serving.paged_kv_cache import (
     PagedCacheConfig,
     PagedCacheManager,
-    PagedKVCache,
-    QuantPagedKVCache,
     blocks_per_slot,
-    init_paged_cache,
-    init_quant_paged_cache,
 )
 from apex_tpu.serving.quant import (
     QuantConfig,
@@ -329,29 +324,30 @@ class DecodeEngine:
         self.model = model
         self.params = params
         self.slots = int(slots)
-        # a model whose layers are not all attention declares what each
-        # layer keeps a slot (K/V rows, a recurrent state, counters) and
-        # the cache is built from that; a model that declares nothing is a
-        # stack of attention layers sized from its config, as ever.  What
-        # moves, shares or rolls back K/V rows knows nothing of a recurrent
-        # state, so each such mechanism is refused here by name
-        declare = getattr(model, "cache_layers", None)
-        self._layers = None if declare is None else tuple(declare())
-        self._recurrent = self._layers is not None and any(
-            isinstance(l, RecurrentRows) for l in self._layers)
-        if self._layers is not None:
+        # a model declares what each layer keeps a slot (K/V rows, a
+        # recurrent state, counters) and the cache is built from that.  What
+        # pages, shards or quantizes K/V rows knows nothing of the other two,
+        # so for a model that keeps them each such mechanism is refused here
+        # by name
+        self._layers = tuple(model.cache_layers())
+        self._recurrent = any(isinstance(l, RecurrentRows)
+                              for l in self._layers)
+        self._hybrid = any(l is not None and not isinstance(l, KVRows)
+                           for l in self._layers)
+        if self._hybrid:
             for given, what in (
                     (paged is not None, "paged= (a block table pages K/V "
                      "rows; a recurrent state has no rows to page)"),
                     (tp is not None, "tp= (the model's mixers have no "
                      "tensor-parallel layout)"),
                     (quant is not None and quant.kv, "QuantConfig(kv=True) "
-                     "(the int8 cache is sized from a config of attention "
-                     "layers; a float32 state is not quantized)")):
+                     "(the int8 format stores K/V rows; a float32 state is "
+                     "not quantized)")):
                 if given:
                     raise ValueError(
                         f"{type(model).__name__} declares per-layer state "
-                        f"(cache_layers()); it cannot be served with {what}")
+                        f"other than K/V rows (cache_layers()); it cannot be "
+                        f"served with {what}")
         # opt-in tensor parallelism: validate the head/vocab split up
         # front (a bad divisor must fail at construction, not as an XLA
         # sharding error three calls later) and build the serving mesh.
@@ -393,42 +389,20 @@ class DecodeEngine:
             if bs > max_len:
                 raise ValueError(
                     f"paged block_size {bs} exceeds max_len {max_len}")
-            nblk = paged.num_blocks
-            if nblk is None:
-                # dense-capacity parity: every slot can still fill to
-                # max_len with zero sharing (plus the null block)
-                nblk = slots * blocks_per_slot(max_len, bs) + 1
             self._pager = PagedCacheManager(
                 slots=slots, max_len=max_len, block_size=bs,
-                num_blocks=int(nblk))
+                num_blocks=paged.pool_blocks(slots, max_len))
         # commit the fresh cache to its device up front: the first
         # prefill otherwise sees UNCOMMITTED zeros while every later
         # call sees the jit output's committed placement — same trace,
         # but pjit specializes a SECOND executable for the changed
         # placement, and the "compiles bounded by the bucket table"
         # contract would be off by one (environment-dependently)
-        kv_int8 = quant is not None and quant.kv
+        fresh = init_cache(self._layers, slots=slots, max_len=max_len,
+                           dtype=cache_dtype, paged=paged,
+                           int8=quant is not None and quant.kv)
         if self._pager is not None:
-            fresh = (init_quant_paged_cache(
-                         model.config, slots=slots, max_len=max_len,
-                         block_size=self._pager.block_size,
-                         num_blocks=self._pager.num_blocks)
-                     if kv_int8 else
-                     init_paged_cache(
-                         model.config, slots=slots, max_len=max_len,
-                         block_size=self._pager.block_size,
-                         num_blocks=self._pager.num_blocks,
-                         dtype=cache_dtype))
             self._pager.consume_dirty()     # device holds this snapshot
-        elif kv_int8:
-            fresh = init_quant_cache(model.config, slots=slots,
-                                     max_len=max_len)
-        elif self._layers is not None:
-            fresh = init_hybrid_cache(self._layers, slots=slots,
-                                      max_len=max_len, dtype=cache_dtype)
-        else:
-            fresh = init_cache(model.config, slots=slots, max_len=max_len,
-                               dtype=cache_dtype)
         if tp is None:
             # _host_target is where host-side snapshots (table flushes,
             # length mirrors, restore chunks) get committed before a
@@ -531,19 +505,9 @@ class DecodeEngine:
             # tokens [slots] int32 (last sampled per slot); active [slots]
             # bool — inactive lanes still compute (shape stability) but
             # never advance their length, so their writes are unreadable.
-            # Dense lanes park inactive writes in their own masked rows;
-            # a paged table has no private scratch (a stale entry could
-            # route the row into another stream's live block), so
-            # inactive lanes carry the -1 sentinel and their writes are
-            # DROPPED by the paged append's drop-safe scatter.  The
-            # branch is on the cache's pytree type — a trace-time
-            # constant, so each engine still compiles exactly one
-            # decode program and the dense trace is untouched.
-            if isinstance(cache, (PagedKVCache, QuantPagedKVCache)):
-                position = jnp.where(active, cache.lengths,
-                                     jnp.int32(-1))
-            else:
-                position = cache.lengths
+            # Where an idle lane's write goes is the layout's to say
+            # (its own masked rows, or nowhere)
+            position = cache.decode_positions(active)
             # the model is told the active lanes too: an idle lane's K/V
             # write is hidden by its length, its recurrent state must not
             # move
@@ -608,26 +572,7 @@ class DecodeEngine:
             # needed it, so the writer lands on a private copy while
             # the sharers keep the original bytes — bit-isolation by
             # construction.
-            s = jnp.asarray(src, jnp.int32)
-            d = jnp.asarray(dst, jnp.int32)
-            k_blk = lax.dynamic_index_in_dim(cache.k, s, axis=1,
-                                             keepdims=False)
-            v_blk = lax.dynamic_index_in_dim(cache.v, s, axis=1,
-                                             keepdims=False)
-            new = dict(k=cache.k.at[:, d].set(k_blk),
-                       v=cache.v.at[:, d].set(v_blk))
-            if isinstance(cache, QuantPagedKVCache):
-                # a KV-int8 block's bytes are payload + scales: a CoW
-                # that copied one without the other would dequantize
-                # the writer's copy through the sharers' scales —
-                # trace-time dispatch, same single compiled program
-                new["k_scale"] = cache.k_scale.at[:, d].set(
-                    lax.dynamic_index_in_dim(cache.k_scale, s, axis=1,
-                                             keepdims=False))
-                new["v_scale"] = cache.v_scale.at[:, d].set(
-                    lax.dynamic_index_in_dim(cache.v_scale, s, axis=1,
-                                             keepdims=False))
-            return dataclasses.replace(cache, **new)
+            return cache.copy_block(src, dst)
 
         def _read(cache, slot, start, *, n):
             # the traced-start twin of kv_cache.read_slot_region (same
@@ -807,7 +752,7 @@ class DecodeEngine:
         decode step so far: ``{name: int64 [counting layers]}``, empty for
         a model that declares none.  ONE readback, when asked: the counts
         ride the cache pytree and cost a decode step no transfer."""
-        names = next((l.names for l in self._layers or ()
+        names = next((l.names for l in self._layers
                       if isinstance(l, CallCounters)), ())
         counts = np.asarray(self._cache.counters, np.int64) if names else ()
         return {name: counts[:, i] for i, name in enumerate(names)}
@@ -833,7 +778,7 @@ class DecodeEngine:
                  else jax.device_put(np.zeros((self.slots,), np.int32),
                                      self._host_target))
         self._cache = dataclasses.replace(self._cache, lengths=zeros)
-        if self._layers is not None:
+        if self._hybrid:
             # committed like every jit output, or the next call retraces
             self._cache = dataclasses.replace(
                 self._cache, **jax.device_put(

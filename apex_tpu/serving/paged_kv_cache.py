@@ -60,7 +60,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,13 +68,11 @@ from jax import lax
 import numpy as np
 
 from apex_tpu._logging import get_logger
-from apex_tpu.amp.quant import dequantize_int8, quantize_int8
+from apex_tpu.serving.kv_cache import FloatRows, Int8Rows
 
-__all__ = ["PagedCacheConfig", "PagedKVCache", "QuantPagedKVCache",
-           "BlockPoolExhausted", "PagedCacheManager", "init_paged_cache",
-           "init_quant_paged_cache", "paged_prefill_write",
-           "paged_append", "decode_view", "prefill_view",
-           "bytes_per_block"]
+__all__ = ["PagedCacheConfig", "PagedLayout", "PagedKVCache",
+           "QuantPagedKVCache", "BlockPoolExhausted", "PagedCacheManager",
+           "blocks_per_slot", "bytes_per_block"]
 
 logger = get_logger("serving.paged_kv_cache")
 
@@ -110,12 +108,160 @@ class PagedCacheConfig:
                 f"num_blocks must be >= 2 (the null block plus at least "
                 f"one allocatable), got {self.num_blocks}")
 
+    def pool_blocks(self, slots: int, max_len: int) -> int:
+        """``num_blocks``, or the dense-capacity parity it defaults to."""
+        if self.num_blocks is not None:
+            return int(self.num_blocks)
+        return slots * blocks_per_slot(max_len, self.block_size) + 1
+
+    def init_cache(self, layers: int, rows, *, slots: int, max_len: int,
+                   dtype, int8: bool):
+        """Zero-filled pool for ``layers`` layers of ``rows`` (a
+        :class:`~apex_tpu.serving.kv_cache.KVRows`) — what
+        :func:`apex_tpu.serving.kv_cache.init_cache` builds for this
+        layout.  Block 0 is the null block; all table entries start
+        there."""
+        cls = QuantPagedKVCache if int8 else PagedKVCache
+        shape = (layers, self.pool_blocks(slots, max_len), self.block_size,
+                 rows.kv_heads, rows.head_dim)
+        return cls(
+            **cls.zeros(shape, dtype),
+            tables=jnp.zeros(
+                (slots, blocks_per_slot(max_len, self.block_size)),
+                jnp.int32),
+            lengths=jnp.zeros((slots,), jnp.int32), max_len=int(max_len))
+
+
+def blocks_per_slot(max_len: int, block_size: int) -> int:
+    """Table width: blocks covering ``max_len`` rows (ceil division)."""
+    return -(-int(max_len) // int(block_size))
+
+
+# ---------------------------------------------------------------------------
+# the layout: drop-safe routing for writes, fixed-extent gathers for reads
+# ---------------------------------------------------------------------------
+
+
+class PagedLayout:
+    """``[layers, num_blocks, block_size, ...]`` pools read and written
+    through ``tables``: the index a logical row becomes and the view a
+    read takes (what :class:`~apex_tpu.serving.kv_cache.DenseLayout`
+    answers for slot rows), for any storage format."""
+
+    @property
+    def num_layers(self) -> int:
+        return self.k.shape[0]
+
+    @property
+    def num_blocks(self) -> int:
+        return self.k.shape[1]
+
+    @property
+    def block_size(self) -> int:
+        return self.k.shape[2]
+
+    @property
+    def num_slots(self) -> int:
+        return self.tables.shape[0]
+
+    @property
+    def blocks_per_slot(self) -> int:
+        return self.tables.shape[1]
+
+    def _table_row(self, slot):
+        return lax.dynamic_index_in_dim(
+            self.tables, jnp.asarray(slot, jnp.int32), axis=0,
+            keepdims=False)
+
+    def _route_rows(self, table_row, rows):
+        """Map logical slot rows -> ``(physical block id, offset in
+        block)``, with every undroppable-unsafe row redirected to
+        ``num_blocks`` (out of pool range, dropped by ``mode="drop"``):
+        rows ``< 0`` (inactive-lane sentinel), rows ``>= max_len``
+        (bucket-padding overhang past capacity), and rows whose table
+        entry is the null block (padding past the allocated frontier, or a
+        released slot's zeroed table).  Real rows always route to a live
+        allocated block — the host manager guarantees the table covers the
+        declared write span before the dispatch."""
+        bs = self.block_size
+        safe = jnp.clip(rows, 0, self.max_len - 1)
+        blk = jnp.clip(safe // bs, 0, self.blocks_per_slot - 1)
+        if table_row.ndim == 2:
+            # batched append: row i must read SLOT i's own table (the
+            # diagonal), not every slot's entry at offset blk[i] — a plain
+            # take here is an outer product that scatters each lane's token
+            # through every other slot's table
+            entry = jnp.take_along_axis(table_row, blk[:, None],
+                                        axis=-1)[:, 0]
+        else:
+            entry = jnp.take(table_row, blk, axis=-1)
+        ok = (rows >= 0) & (rows < self.max_len) & (entry > NULL_BLOCK)
+        phys = jnp.where(ok, entry, self.num_blocks)
+        return phys, safe % bs
+
+    def _gathered(self, arr, tables) -> jax.Array:
+        """``arr`` (one layer of a pool) gathered through ``tables`` and
+        re-laid as contiguous token rows, sliced to exactly ``max_len`` —
+        the fixed-extent read every attention caller shares.  The gather
+        shape is static (``tables``' shape), so one compiled program
+        serves every slot state."""
+        g = jnp.take(arr, tables, axis=0)     # [..., bps, bs, kvh(, hd)]
+        n = tables.ndim                       # the block-size axis of g
+        flat = g.reshape(g.shape[:n - 1] + (g.shape[n - 1] * g.shape[n],)
+                         + g.shape[n + 1:])
+        return flat[(slice(None),) * (n - 1) + (slice(None, self.max_len),)]
+
+    def chunk_index(self, layer, slot, rows):
+        """Where ``rows`` of one slot live: through the slot's own table
+        row.  Rows routing to the null block (bucket padding past the
+        allocated frontier) or past ``max_len`` are dropped — the paged
+        cache never writes padding into a block, so no stale table can
+        route one into a live neighbor."""
+        return (layer,) + self._route_rows(self._table_row(slot), rows)
+
+    def lane_index(self, layer, positions):
+        """Where row ``positions[lane]`` of every lane lives; ``-1`` (an
+        idle lane, see :meth:`decode_positions`) is dropped."""
+        return (layer,) + self._route_rows(self.tables, positions)
+
+    def lanes_view(self, layer):
+        """``view(pool)``: every slot's rows as ``[slots, max_len, ...]`` —
+        same shape, same masked-read contract, same reduction extents as
+        the dense ``buf[layer]``; unallocated rows are the null block's
+        exact finite zeros."""
+        return lambda pool: self._gathered(pool[layer], self.tables)
+
+    def slot_view(self, layer, slot):
+        """``view(pool)``: one slot's rows as ``[max_len, ...]``."""
+        table_row = self._table_row(slot)
+        return lambda pool: self._gathered(pool[layer], table_row)
+
+    def decode_positions(self, active):
+        """Where a decode step appends: a dense lane parks an idle write
+        in its own masked rows, but a table has no private scratch (a
+        stale entry could route the row into another stream's live
+        block), so idle lanes carry ``-1`` and their writes are
+        dropped."""
+        return jnp.where(active, self.lengths, jnp.int32(-1))
+
+    def copy_block(self, src, dst):
+        """Pool block ``src`` -> ``dst`` across every layer and every
+        stored pool (an int8 block's bytes are payload AND scales: a copy
+        of one without the other would dequantize the writer's copy
+        through the sharers' scales)."""
+        s = jnp.asarray(src, jnp.int32)
+        d = jnp.asarray(dst, jnp.int32)
+        return dataclasses.replace(self, **{
+            name: getattr(self, name).at[:, d].set(lax.dynamic_index_in_dim(
+                getattr(self, name), s, axis=1, keepdims=False))
+            for name in self.stored})
+
 
 @functools.partial(jax.tree_util.register_dataclass,
                    data_fields=("k", "v", "tables", "lengths"),
                    meta_fields=("max_len",))
 @dataclasses.dataclass(frozen=True)
-class PagedKVCache:
+class PagedKVCache(PagedLayout, FloatRows):
     """Block-pool decode cache.
 
     ``k`` / ``v``: ``[layers, num_blocks, block_size, kv_heads,
@@ -135,50 +281,22 @@ class PagedKVCache:
     lengths: jax.Array
     max_len: int
 
-    @property
-    def num_layers(self) -> int:
-        return self.k.shape[0]
-
-    @property
-    def num_blocks(self) -> int:
-        return self.k.shape[1]
-
-    @property
-    def block_size(self) -> int:
-        return self.k.shape[2]
-
-    @property
-    def num_slots(self) -> int:
-        return self.tables.shape[0]
-
-    @property
-    def blocks_per_slot(self) -> int:
-        return self.tables.shape[1]
-
-    @property
-    def dtype(self):
-        return self.k.dtype
-
 
 @functools.partial(jax.tree_util.register_dataclass,
                    data_fields=("k", "v", "k_scale", "v_scale", "tables",
                                 "lengths"),
                    meta_fields=("max_len",))
 @dataclasses.dataclass(frozen=True)
-class QuantPagedKVCache:
+class QuantPagedKVCache(PagedLayout, Int8Rows):
     """KV-int8 twin of :class:`PagedKVCache`: the same block pool and
-    table routing, the payload stored as symmetric int8 with one fp32
-    scale per pooled (row, head) — scales live in a parallel pool
-    ``[layers, num_blocks, block_size, kv_heads]`` indexed by the SAME
-    block ids, so aliasing, CoW, fork, and release move payload and
-    scales together by construction (a shared block shares its scales;
-    a CoW copy copies both).
-
-    Every drop-safe-scatter/null-block/fixed-extent-gather invariant of
-    the fp pool holds unchanged; reads dequantize through the gathered
-    scales.  ``kv_heads`` sits at axis 3 of both pools, so under tensor
-    parallelism the scale pool shards on the same
-    ``P(None, None, None, 'tp')`` spec as the payload.
+    table routing, rows stored as
+    :class:`~apex_tpu.serving.kv_cache.Int8Rows` — scales live in a
+    parallel pool ``[layers, num_blocks, block_size, kv_heads]`` indexed
+    by the SAME block ids, so aliasing, CoW, fork, and release move
+    payload and scales together by construction (a shared block shares
+    its scales; a CoW copy copies both).  Unallocated rows carry
+    q=0/scale=1 and so stay exact finite zeros, preserving the
+    masked-read ``0 * NaN``-safety invariant.
     """
 
     k: jax.Array
@@ -189,256 +307,19 @@ class QuantPagedKVCache:
     lengths: jax.Array
     max_len: int
 
-    @property
-    def num_layers(self) -> int:
-        return self.k.shape[0]
-
-    @property
-    def num_blocks(self) -> int:
-        return self.k.shape[1]
-
-    @property
-    def block_size(self) -> int:
-        return self.k.shape[2]
-
-    @property
-    def num_slots(self) -> int:
-        return self.tables.shape[0]
-
-    @property
-    def blocks_per_slot(self) -> int:
-        return self.tables.shape[1]
-
-    @property
-    def dtype(self):
-        """Payload dtype (int8); reads dequantize to fp32."""
-        return self.k.dtype
-
-
-def blocks_per_slot(max_len: int, block_size: int) -> int:
-    """Table width: blocks covering ``max_len`` rows (ceil division)."""
-    return -(-int(max_len) // int(block_size))
-
 
 def bytes_per_block(cache) -> int:
     """True resident bytes one pool block pins across every layer and
-    pool array.  For the fp pool that is the k+v payload; for the quant
+    stored pool.  For the fp pool that is the k+v payload; for the quant
     pool the fp32 scale pools ride the same block ids, so their bytes
     are part of the block (an accounting that read ``k.dtype.itemsize``
     alone would undercount an int8 pool by its scale overhead)."""
-    pools = [cache.k, cache.v]
-    if isinstance(cache, QuantPagedKVCache):
-        pools += [cache.k_scale, cache.v_scale]
     total = 0
-    for arr in pools:
+    for arr in (getattr(cache, name) for name in cache.stored):
         shape = arr.shape            # [L, num_blocks, block_size, ...]
         per = int(np.prod((shape[0],) + shape[2:]))
         total += jnp.dtype(arr.dtype).itemsize * per
     return int(total)
-
-
-def init_paged_cache(config: Any, *, slots: int, max_len: int,
-                     block_size: int, num_blocks: int,
-                     dtype=jnp.float32) -> PagedKVCache:
-    """Zero-filled pool for ``config`` (``LlamaConfig``-shaped).  Block
-    0 is the null block; all table entries start there."""
-    head_dim = config.hidden_size // config.num_attention_heads
-    shape = (config.num_hidden_layers, num_blocks, block_size,
-             config.kv_heads, head_dim)
-    bps = blocks_per_slot(max_len, block_size)
-    return PagedKVCache(
-        k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-        tables=jnp.zeros((slots, bps), jnp.int32),
-        lengths=jnp.zeros((slots,), jnp.int32), max_len=int(max_len))
-
-
-def init_quant_paged_cache(config: Any, *, slots: int, max_len: int,
-                           block_size: int,
-                           num_blocks: int) -> QuantPagedKVCache:
-    """Zero-filled KV-int8 block pool.  Scales start at 1.0 (the
-    zero-amax convention): the null block — and every unallocated block
-    — dequantizes to exact finite zeros, preserving the masked-read
-    ``0 * NaN``-safety invariant."""
-    head_dim = config.hidden_size // config.num_attention_heads
-    shape = (config.num_hidden_layers, num_blocks, block_size,
-             config.kv_heads, head_dim)
-    bps = blocks_per_slot(max_len, block_size)
-    return QuantPagedKVCache(
-        k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
-        k_scale=jnp.ones(shape[:-1], jnp.float32),
-        v_scale=jnp.ones(shape[:-1], jnp.float32),
-        tables=jnp.zeros((slots, bps), jnp.int32),
-        lengths=jnp.zeros((slots,), jnp.int32), max_len=int(max_len))
-
-
-# ---------------------------------------------------------------------------
-# device ops: drop-safe scatter writes + fixed-extent gather reads
-# ---------------------------------------------------------------------------
-
-
-def _route_rows(cache: PagedKVCache, table_row, rows):
-    """Map logical slot rows -> ``(physical block id, offset in
-    block)``, with every undroppable-unsafe row redirected to
-    ``num_blocks`` (out of pool range, dropped by ``mode="drop"``):
-    rows ``< 0`` (inactive-lane sentinel), rows ``>= max_len``
-    (bucket-padding overhang past capacity), and rows whose table
-    entry is the null block (padding past the allocated frontier, or a
-    released slot's zeroed table).  Real rows always route to a live
-    allocated block — the host manager guarantees the table covers the
-    declared write span before the dispatch."""
-    bs = cache.block_size
-    safe = jnp.clip(rows, 0, cache.max_len - 1)
-    blk = jnp.clip(safe // bs, 0, cache.blocks_per_slot - 1)
-    if table_row.ndim == 2:
-        # batched append: row i must read SLOT i's own table (the
-        # diagonal), not every slot's entry at offset blk[i] — a plain
-        # take here is an outer product that scatters each lane's token
-        # through every other slot's table
-        entry = jnp.take_along_axis(table_row, blk[:, None],
-                                    axis=-1)[:, 0]
-    else:
-        entry = jnp.take(table_row, blk, axis=-1)
-    ok = (rows >= 0) & (rows < cache.max_len) & (entry > NULL_BLOCK)
-    phys = jnp.where(ok, entry, cache.num_blocks)
-    return phys, safe % bs
-
-
-def paged_prefill_write(cache: PagedKVCache, layer: int, slot, k_seq,
-                        v_seq, start=0) -> PagedKVCache:
-    """Write one (padded) prompt chunk's K/V through ``slot``'s block
-    table at offset ``start`` — the paged twin of
-    :func:`~apex_tpu.serving.kv_cache.prefill_into_slot`.
-
-    ``k_seq`` / ``v_seq``: ``[chunk_len, kv_heads, head_dim]``;
-    ``slot`` / ``start`` may be traced, ``layer`` is a Python int.
-    Rows routing to the null block (bucket padding past the allocated
-    frontier) or past ``max_len`` are DROPPED — the paged cache never
-    writes padding into a block, so no stale table can route one into
-    a live neighbor.  ``lengths`` is untouched (the caller commits
-    once per model call, exactly like the dense primitive).
-    """
-    rows = jnp.asarray(start, jnp.int32) + jnp.arange(
-        k_seq.shape[0], dtype=jnp.int32)
-    table_row = lax.dynamic_index_in_dim(
-        cache.tables, jnp.asarray(slot, jnp.int32), axis=0,
-        keepdims=False)
-    phys, within = _route_rows(cache, table_row, rows)
-    if isinstance(cache, QuantPagedKVCache):
-        # scales scatter through the SAME (phys, within) routing as the
-        # payload: a dropped padding row drops both, a live row lands
-        # both in the same block
-        kq, ks = quantize_int8(k_seq, axis=-1)
-        vq, vs = quantize_int8(v_seq, axis=-1)
-        return dataclasses.replace(
-            cache,
-            k=cache.k.at[layer, phys, within].set(kq, mode="drop"),
-            v=cache.v.at[layer, phys, within].set(vq, mode="drop"),
-            k_scale=cache.k_scale.at[layer, phys, within].set(
-                ks, mode="drop"),
-            v_scale=cache.v_scale.at[layer, phys, within].set(
-                vs, mode="drop"))
-    return dataclasses.replace(
-        cache,
-        k=cache.k.at[layer, phys, within].set(k_seq.astype(cache.dtype),
-                                              mode="drop"),
-        v=cache.v.at[layer, phys, within].set(v_seq.astype(cache.dtype),
-                                              mode="drop"))
-
-
-def paged_append(cache: PagedKVCache, layer: int, k_tok, v_tok,
-                 positions) -> PagedKVCache:
-    """Write one token's K/V per slot at that slot's own position —
-    the paged twin of :func:`~apex_tpu.serving.kv_cache.append_token`.
-
-    ``k_tok`` / ``v_tok``: ``[slots, kv_heads, head_dim]``;
-    ``positions``: ``[slots]`` int32 — the slot's current depth, or
-    ``-1`` for an inactive lane (dense appends park inactive writes in
-    the lane's own masked rows; a paged table has no such private
-    scratch, so inactive lanes are DROPPED instead of routed).  One
-    shape-stable scatter covers every lane.
-    """
-    pos = jnp.asarray(positions, jnp.int32)
-    phys, within = _route_rows(cache, cache.tables, pos)
-    if isinstance(cache, QuantPagedKVCache):
-        kq, ks = quantize_int8(k_tok, axis=-1)
-        vq, vs = quantize_int8(v_tok, axis=-1)
-        return dataclasses.replace(
-            cache,
-            k=cache.k.at[layer, phys, within].set(kq, mode="drop"),
-            v=cache.v.at[layer, phys, within].set(vq, mode="drop"),
-            k_scale=cache.k_scale.at[layer, phys, within].set(
-                ks, mode="drop"),
-            v_scale=cache.v_scale.at[layer, phys, within].set(
-                vs, mode="drop"))
-    return dataclasses.replace(
-        cache,
-        k=cache.k.at[layer, phys, within].set(k_tok.astype(cache.dtype),
-                                              mode="drop"),
-        v=cache.v.at[layer, phys, within].set(v_tok.astype(cache.dtype),
-                                              mode="drop"))
-
-
-def _gathered(cache: PagedKVCache, arr, tables) -> jax.Array:
-    """``arr[layer]`` rows gathered through ``tables`` and re-laid as
-    contiguous token rows, sliced to exactly ``max_len`` — the
-    fixed-extent read every attention caller shares.  The gather shape
-    is static (``tables``' shape), so one compiled program serves
-    every slot state."""
-    g = jnp.take(arr, tables, axis=0)     # [..., bps, bs, kvh, hd]
-    flat = g.reshape(g.shape[:-4] + (g.shape[-4] * g.shape[-3],)
-                     + g.shape[-2:])
-    return flat[..., :cache.max_len, :, :]
-
-
-def _gathered_scale(cache, arr, tables) -> jax.Array:
-    """The scale-pool twin of :func:`_gathered`: ``arr[layer]`` rows
-    (``[num_blocks, block_size, kv_heads]`` — no head_dim axis)
-    gathered through ``tables`` and re-laid as contiguous token rows,
-    sliced to exactly ``max_len``."""
-    g = jnp.take(arr, tables, axis=0)     # [..., bps, bs, kvh]
-    flat = g.reshape(g.shape[:-3] + (g.shape[-3] * g.shape[-2],)
-                     + g.shape[-1:])
-    return flat[..., :cache.max_len, :]
-
-
-def decode_view(cache, layer: int) -> Tuple[jax.Array, jax.Array]:
-    """Every slot's K/V as ``[slots, max_len, kv_heads, head_dim]`` —
-    the batched decode read (same shape, same masked-read contract,
-    same reduction extents as the dense ``cache.k[layer]``).  A
-    :class:`QuantPagedKVCache` dequantizes through the gathered
-    per-(row, head) scales; unallocated rows carry q=0/scale=1 and so
-    stay exact finite zeros."""
-    if isinstance(cache, QuantPagedKVCache):
-        return (dequantize_int8(
-                    _gathered(cache, cache.k[layer], cache.tables),
-                    _gathered_scale(cache, cache.k_scale[layer],
-                                    cache.tables)),
-                dequantize_int8(
-                    _gathered(cache, cache.v[layer], cache.tables),
-                    _gathered_scale(cache, cache.v_scale[layer],
-                                    cache.tables)))
-    return (_gathered(cache, cache.k[layer], cache.tables),
-            _gathered(cache, cache.v[layer], cache.tables))
-
-
-def prefill_view(cache, layer: int, slot) -> Tuple[jax.Array, jax.Array]:
-    """One slot's K/V as ``[max_len, kv_heads, head_dim]`` — the
-    chunked-prefill read (``slot`` may be traced), dequantized for a
-    :class:`QuantPagedKVCache` exactly like :func:`decode_view`."""
-    table_row = lax.dynamic_index_in_dim(
-        cache.tables, jnp.asarray(slot, jnp.int32), axis=0,
-        keepdims=False)
-    if isinstance(cache, QuantPagedKVCache):
-        return (dequantize_int8(
-                    _gathered(cache, cache.k[layer], table_row),
-                    _gathered_scale(cache, cache.k_scale[layer],
-                                    table_row)),
-                dequantize_int8(
-                    _gathered(cache, cache.v[layer], table_row),
-                    _gathered_scale(cache, cache.v_scale[layer],
-                                    table_row)))
-    return (_gathered(cache, cache.k[layer], table_row),
-            _gathered(cache, cache.v[layer], table_row))
 
 
 # ---------------------------------------------------------------------------

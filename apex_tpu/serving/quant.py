@@ -286,12 +286,7 @@ def kv_bytes_per_token(cache) -> float:
     token capacity).  The capacity half of the streams-per-GB
     acceptance bar: ``fp_bytes / quant_bytes`` is exactly the
     concurrent-streams multiplier at a fixed byte budget."""
-    arrays = [cache.k, cache.v]
-    for name in ("k_scale", "v_scale"):
-        arr = getattr(cache, name, None)
-        if arr is not None:
-            arrays.append(arr)
-    total = sum(int(a.nbytes) for a in arrays)
+    total = sum(int(getattr(cache, name).nbytes) for name in cache.stored)
     # dense: [L, slots, max_len, ...]; paged: [L, blocks, block_size, ...]
     tokens = int(cache.k.shape[1]) * int(cache.k.shape[2])
     return total / tokens

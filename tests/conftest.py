@@ -60,3 +60,22 @@ def mesh8(devices):
 @pytest.fixture
 def rng():
     return np.random.default_rng(1234)
+
+
+# float32 logits of O(1) that two DIFFERENT programs (cached against
+# uncached, one chunking against another, a 2-lane step against a 1-lane
+# one) compute from the same dot products: XLA:CPU's gemm rounds a row by
+# the rows per batch it sits in, measured <= 3.9e-7 (ROADMAP D1).  What
+# these comparisons guard against - a clobbered row, a broken copy-on-write,
+# a wrong checkpoint - moves logits by 1e-2 and more.  Runs through the SAME
+# program with the same batch are still compared with ``==``.
+LOGITS_ATOL = 2e-6
+
+
+@pytest.fixture(scope="session")
+def same_logits():
+    """``same_logits(got, want, msg="")``: equal to ``LOGITS_ATOL``."""
+    def check(got, want, msg=""):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=0, atol=LOGITS_ATOL, err_msg=msg)
+    return check

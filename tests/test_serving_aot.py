@@ -1,7 +1,7 @@
 """The serving programs compiled for a described TPU v5e, without the chip.
 
 The cached read's speed on the chip rests on a layout decision of
-XLA:TPU that no CPU test can see: ``_cached_attention`` fences the cache
+XLA:TPU that no CPU test can see: ``cached_attention`` fences the cache
 view with ``jax.lax.optimization_barrier`` so that the two grouped
 contractions read it in the layout it is stored in.  Left free, the
 compiler gives the WHOLE cache a kv-head-major layout for those dots and
@@ -91,7 +91,8 @@ def _compiled_text(engine, one_chip, program):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     cache = on_chip(jax.eval_shape(
-        lambda: init_cache(CFG, slots=SLOTS, max_len=MAX_LEN,
+        lambda: init_cache(engine.model.cache_layers(), slots=SLOTS,
+                           max_len=MAX_LEN,
                            dtype=jnp.bfloat16)))
     params = on_chip(engine.params)
     if program == "decode":
@@ -208,8 +209,6 @@ def test_prefill_chunk_reads_one_slots_state_not_every_slots(
     state of all 64 slots first: a ``slice`` of 268 MB a recurrent layer,
     4.1 of the 11-29 ms of every prefill call on the chip (PERF.md §6,
     PR 27)."""
-    from apex_tpu.serving.kv_cache import init_hybrid_cache
-
     def on_chip(tree):
         return jax.tree.map(
             lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
@@ -219,7 +218,7 @@ def test_prefill_chunk_reads_one_slots_state_not_every_slots(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     model = hybrid_engine.model
-    cache = on_chip(jax.eval_shape(lambda: init_hybrid_cache(
+    cache = on_chip(jax.eval_shape(lambda: init_cache(
         model.cache_layers(), slots=HYBRID_SLOTS, max_len=MAX_LEN,
         dtype=jnp.bfloat16)))
     text = hybrid_engine._prefill.lower(

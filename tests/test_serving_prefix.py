@@ -38,6 +38,7 @@ from apex_tpu.serving.prefix_cache import PrefixCache
 CFG = LlamaConfig(vocab_size=128, hidden_size=64, intermediate_size=128,
                   num_hidden_layers=2, num_attention_heads=4,
                   num_key_value_heads=2, max_position_embeddings=256)
+LAYERS = LlamaForCausalLM(CFG).cache_layers()
 MAX = 96
 
 
@@ -91,7 +92,7 @@ def _region(seed, n):
 
 
 def test_slot_region_write_read_start_zero_roundtrip():
-    cache = init_cache(CFG, slots=3, max_len=16)
+    cache = init_cache(LAYERS, slots=3, max_len=16)
     k, v = _region(0, 6)
     cache = write_slot_region(cache, slot=1, start=0, k_region=k,
                               v_region=v)
@@ -107,7 +108,7 @@ def test_slot_region_write_read_start_zero_roundtrip():
 
 
 def test_slot_region_span_abutting_max_len():
-    cache = init_cache(CFG, slots=2, max_len=16)
+    cache = init_cache(LAYERS, slots=2, max_len=16)
     k, v = _region(1, 4)
     cache = write_slot_region(cache, slot=0, start=12, k_region=k,
                               v_region=v)      # rows [12, 16): exact fit
@@ -128,7 +129,7 @@ def test_slot_region_with_commit_slot_length_on_full_slot():
     """Fill a slot to max_len, commit, roll back via commit_slot_length
     (the PR-8 rollback primitive), and overwrite the rolled-back span —
     region reads see exactly the committed truth at each stage."""
-    cache = init_cache(CFG, slots=2, max_len=16)
+    cache = init_cache(LAYERS, slots=2, max_len=16)
     k, v = _region(3, 16)
     cache = write_slot_region(cache, slot=0, start=0, k_region=k,
                               v_region=v)
@@ -152,7 +153,7 @@ def test_slot_region_with_commit_slot_length_on_full_slot():
 
 
 def test_slot_region_validation():
-    cache = init_cache(CFG, slots=1, max_len=8)
+    cache = init_cache(LAYERS, slots=1, max_len=8)
     with pytest.raises(ValueError):           # empty region
         read_slot_region(cache, 0, 4, 4)
     with pytest.raises(ValueError):

@@ -330,10 +330,12 @@ def test_kv_int8_capacity_bar(model, params, fp_ref):
 # ---------------------------------------------------------------------------
 
 
-def test_chunked_prefill_invisible_under_quant(model, params):
+def test_chunked_prefill_invisible_under_quant(model, params, same_logits):
     """Chunk boundaries are scheduling, not numerics, under KV-int8
     too: per-(position, head) scales depend only on the row being
-    written, never on which chunk wrote it."""
+    written, never on which chunk wrote it.  The stream is the same
+    stream; the logits agree to ``conftest.LOGITS_ATOL`` (a 16-row and a
+    64-row chunk are two programs, and the gemm rounds by rows)."""
     prompt = _prompt(seed=3, n=40)
     small = sv.DecodeEngine(model, params, slots=1, max_len=MAX,
                             prefill_len=16, quant=W_KV)
@@ -342,7 +344,7 @@ def test_chunked_prefill_invisible_under_quant(model, params):
     s_small, l_small = _greedy(small, prompt, steps=8)
     s_big, l_big = _greedy(big, prompt, steps=8)
     assert s_small == s_big
-    np.testing.assert_array_equal(l_small, l_big)
+    same_logits(l_small, l_big)
 
 
 def test_preempt_capture_restore_bit_exact_under_quant(model, params):
@@ -458,14 +460,9 @@ def test_paged_quant_identical_to_dense_quant(model, params):
 def dense_like_block(cache):
     """A payload-only view for the bytes_per_block comparison: the
     quant pool must price strictly MORE than its payload alone."""
-    import dataclasses as _dc
+    import types
 
-    class _Payload:
-        pass
-
-    p = _Payload()
-    p.k, p.v = cache.k, cache.v
-    return p
+    return types.SimpleNamespace(k=cache.k, v=cache.v, stored=("k", "v"))
 
 
 def test_paged_cow_fork_isolated_under_quant(model, params):

@@ -109,7 +109,7 @@ PAGES = {
         "apex_tpu.obs.bridge",
     ]),
     "utils": ("Utilities", [
-        "apex_tpu.utils.nvtx", "apex_tpu.utils.packing",
+        "apex_tpu.utils.packing",
         "apex_tpu.utils.serialization", "apex_tpu.utils.compat",
         "apex_tpu.utils.compile_cache",
         "apex_tpu.feature_registry", "apex_tpu._logging",
@@ -457,7 +457,9 @@ masked scores sit at the flash kernels' exact `-1e30`:
 `exp(masked - max)` underflows to exactly `0.0`, so the fixed-extent
 softmax of a float32 cache is *bit-identical* to a same-extent uncached
 forward — masking is correctness, not approximation.  The read takes
-the cache **as it is stored** (`models.llama._cached_attention`): query
+the cache **as it is stored** (`serving.kv_cache.cached_attention`,
+behind the two calls a model's attention makes, `decode_attend` and
+`prefill_attend`, whatever the layout and storage format): query
 heads are grouped over their KV head (`[slots, kv_heads, rep * rows,
 head_dim]` against the `[slots, max_len, kv_heads, head_dim]` buffer,
 both contractions batched over `(slot, kv_head)`), K/V are never

@@ -72,6 +72,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.amp.quant import dequantize_int8, quantize_int8
+from apex_tpu.ops._dispatch import record_dispatch
+from apex_tpu.ops.cached_decode_attention import (
+    block_rows,
+    cached_decode_attention,
+)
 from apex_tpu.ops.flash_attention import _NEG_INF
 
 __all__ = ["KVCache", "QuantKVCache", "FloatRows", "Int8Rows", "DenseLayout",
@@ -192,6 +197,10 @@ def _write(cache, index, k_rows, v_rows):
 
 class DenseLayout:
     """``[layers, slots, max_len, ...]`` buffers: a slot's rows are its own."""
+
+    # ``buf[layer, lane]`` is the lane's rows: a kernel may index the
+    # stored buffers itself
+    lane_rows_in_place = True
 
     @property
     def num_layers(self) -> int:
@@ -548,20 +557,49 @@ def decode_attention(qt, kc, vc, position):
 # ---- the seam: what an attention layer calls -------------------------------
 
 
+def _reads_in_place(cache, q) -> bool:
+    """Whether the decode step's read is the Pallas kernel
+    (:func:`~apex_tpu.ops.cached_decode_attention.cached_decode_attention`)
+    or :func:`cached_attention` over the layout's view - decided by what
+    is in hand: rows a lane owns in the stored buffers (the dense layout)
+    in the query's own float dtype (so not int8, and nothing to cast), a
+    head width of whole lane tiles (at 64 XLA:TPU keeps ``max_len`` in the
+    lanes, ``{2,4,3,1,0}``, and the kernel's operand would be a copy of the
+    whole cache: compiled for a described v5e, PERF.md §6, PR 30) and a
+    ``max_len`` of whole blocks.  The ``kernel_dispatch`` event says
+    which."""
+    heads, nkv, hd = q.shape[2], cache.k.shape[-2], cache.k.shape[-1]
+    block = block_rows(cache.max_len, nkv)
+    return record_dispatch(
+        "cached_decode_attention",
+        cache.lane_rows_in_place and cache.k.dtype == q.dtype
+        and hd % 128 == 0 and cache.max_len % block == 0
+        and block % 8 == 0,
+        kv_heads=nkv, rep=heads // nkv, hd=hd, max_len=cache.max_len,
+        block=block)
+
+
 def decode_attend(cache, layer: int, q, k, v, position):
     """One decode step of one attention layer: append each lane's new K/V
     row at ``position`` (``[lanes]``; post-rope K, like the uncached path
-    sees), then attend over the whole masked cache.  ``q`` ``[1, lanes,
-    heads, hd]``, ``k`` / ``v`` ``[1, lanes, kv_heads, hd]``, the model's
-    own layout.  Returns ``(ctx [lanes, heads, 1, hd], cache)``.
+    sees), then attend over the lane's rows ``idx <= position``.  ``q``
+    ``[1, lanes, heads, hd]``, ``k`` / ``v`` ``[1, lanes, kv_heads, hd]``,
+    the model's own layout.  Returns ``(ctx [lanes, heads, 1, hd], cache)``.
 
-    The read is the layer's ``[lanes, max_len, kv_heads, hd]`` view with
-    the cache's own head count, cast to the query's dtype: the GQA
-    grouping happens on the query side (:func:`cached_attention`), and
-    every layout and format hands back identical values at every
-    unmasked position over identical reduction extents — hence
+    One read, two implementations (:func:`_reads_in_place` chooses).  On
+    a TPU a dense float cache is read where it lies: the kernel takes the
+    stored buffers whole, the layer index and the bounds, and stops at
+    each lane's last live block.  Everything else - a CPU backend, int8
+    rows, a block table, an odd head width - takes the layer's ``[lanes,
+    max_len, kv_heads, hd]`` view, cast to the query's dtype, through
+    :func:`cached_attention`: the GQA grouping happens on the query side,
+    and every layout and format hands back identical values at every
+    unmasked position over identical reduction extents - hence
     bit-identical logits dense against paged."""
     cache = append_token(cache, layer, k[0], v[0], jnp.asarray(position))
+    if _reads_in_place(cache, q):
+        return cached_decode_attention(q.transpose(1, 2, 0, 3), cache.k,
+                                       cache.v, layer, position), cache
     kc, vc = decode_read(cache, layer)
     kc = kc.astype(q.dtype)
     vc = vc.astype(q.dtype)

@@ -148,6 +148,9 @@ class PagedLayout:
     read takes (what :class:`~apex_tpu.serving.kv_cache.DenseLayout`
     answers for slot rows), for any storage format."""
 
+    # a lane's rows are wherever its table row says: reads gather
+    lane_rows_in_place = False
+
     @property
     def num_layers(self) -> int:
         return self.k.shape[0]

@@ -74,6 +74,17 @@ _TINY = dict(card=dict(metric="llama_tiny", family="llama", layers=2,
              steps=3, slots=4, max_len=128, prefill_len=32, new_tokens=6,
              prompt_lens=(8, 14, 32, 40, 70, 90))
 
+
+def _reference_expected(event) -> bool:
+    """A call site whose shape predicate is known to fail on the smoke's
+    cards: their heads are 64 wide, and the decode step's in-place K/V read
+    wants whole lane tiles (``serving/kv_cache.py::decode_attend``; at 64
+    XLA:TPU keeps ``max_len`` in the lanes and the kernel's operand would
+    be a copy of the whole cache)."""
+    return (event["op"] == "cached_decode_attention"
+            and event["hd"] % 128 != 0)
+
+
 _FAMILIES = {"flash_attention": "flash_attention_",
              "rms_norm": "rms_norm_",
              "fused_lm_head": "fused_lm_head_"}
@@ -102,7 +113,8 @@ class Smoke:
             obs = fn(self) or {}
             paths = collections.Counter(
                 (e["op"], e["path"]) for e in self.dispatch)
-            refs = [e for e in self.dispatch if e["path"] != "pallas"]
+            refs = [e for e in self.dispatch if e["path"] != "pallas"
+                    and not _reference_expected(e)]
             if refs:
                 raise AssertionError(
                     f"call sites took the jnp reference where a kernel "

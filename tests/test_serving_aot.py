@@ -13,7 +13,11 @@ programs with the TPU compiler that is installed here (nothing runs, no
 time is measured) and reads the compiled text: a ``copy`` or
 ``transpose`` of the cache's dtype as large as one layer's slab is the
 regression.  The same text says whether the decode step's append moves
-rows or slabs (PERF.md §6, PR 28).
+rows or slabs (PERF.md §6, PR 28), and whether its read takes the stored
+buffers whole (PR 30): the decode programs are compiled on the path the
+chip takes - ``ops._dispatch.on_tpu`` answers for the backend that traces,
+the CPU here, so these tests answer for it - where the read is the Pallas
+kernel ``cached_decode_attention``.
 
 All such compiles live in this one file and describe the topology inside
 a fixture: only one process at a time may load the TPU's library.
@@ -23,6 +27,7 @@ import functools
 import math
 import os
 import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +36,7 @@ from jax.sharding import SingleDeviceSharding
 
 from apex_tpu import serving as sv
 from apex_tpu.models import LlamaConfig, LlamaForCausalLM
+from apex_tpu.ops import _dispatch
 from apex_tpu.serving.kv_cache import init_cache
 
 # the serving cell's attention geometry (benchmark/configs/
@@ -81,7 +87,9 @@ def compiled_text(engine, one_chip):
         functools.partial(_compiled_text, engine, one_chip))
 
 
-def _compiled_text(engine, one_chip, program):
+def _placed(one_chip):
+    """``(on_chip(tree), arg(shape, dtype))``: shapes placed on the
+    described chip, which is what ``lower`` is handed."""
     def on_chip(tree):
         return jax.tree.map(
             lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
@@ -90,19 +98,33 @@ def _compiled_text(engine, one_chip, program):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    return on_chip, arg
+
+
+def _compiled_text(engine, one_chip, program):
+    on_chip, arg = _placed(one_chip)
     cache = on_chip(jax.eval_shape(
         lambda: init_cache(engine.model.cache_layers(), slots=SLOTS,
                            max_len=MAX_LEN,
                            dtype=jnp.bfloat16)))
     params = on_chip(engine.params)
     if program == "decode":
-        lowered = engine._decode.lower(
-            params, cache, arg((SLOTS,), jnp.int32), arg((SLOTS,), bool))
+        lowered = _lower_decode_as_on_the_chip(engine, params, cache, arg)
     else:
         lowered = engine._prefill.lower(
             params, cache, arg((1, CHUNK), jnp.int32), arg((), jnp.int32),
             arg((), jnp.int32), arg((), jnp.int32))
     return lowered.compile().as_text()
+
+
+def _lower_decode_as_on_the_chip(engine, params, cache, arg):
+    """``engine._decode`` lowered with every kernel's dispatch answering
+    as on a TPU backend (the described chip is no backend: left alone, the
+    trace takes each ``jax.numpy`` reference)."""
+    slots = cache.lengths.shape[0]
+    with mock.patch.object(_dispatch, "on_tpu", lambda: True):
+        return engine._decode.lower(
+            params, cache, arg((slots,), jnp.int32), arg((slots,), bool))
 
 
 def _entry_ops(text, dtype="bf16"):
@@ -119,7 +141,7 @@ def _entry_ops(text, dtype="bf16"):
             for dims in re.findall(r"\b%s\[([\d,]*)\]" % dtype, result)]
 
 
-def _slab_sized_layout_copies(text):
+def _slab_sized_layout_copies(text, slab=SLAB):
     """Instructions of the compiled program's entry computation that
     write a buffer of the cache's dtype, at least one layer's slab large,
     as a ``copy`` or ``transpose`` (alone or as a fusion XLA names after
@@ -129,7 +151,20 @@ def _slab_sized_layout_copies(text):
     return [f"{op} %{name}" for name, op, sizes in _entry_ops(text)
             if (op in ("copy", "transpose") or op == "fusion"
                 and name.startswith(("copy", "transpose")))
-            and any(size >= SLAB for size in sizes)]
+            and any(size >= slab for size in sizes)]
+
+
+def _slab_sized_cuts(text, slab=SLAB):
+    """``slice`` instructions (alone or as a fusion XLA names after one)
+    of the entry computation that write at least one layer's slab."""
+    return [name for name, op, sizes in _entry_ops(text)
+            if (op == "slice" or op == "fusion" and name.startswith("slice"))
+            and any(size >= slab for size in sizes)]
+
+
+def _kernel_calls(text, kernel):
+    return [name for name, op, _ in _entry_ops(text)
+            if op == "custom-call" and name.startswith(kernel)]
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -153,8 +188,7 @@ def test_decode_append_moves_rows_not_slabs(compiled_text):
     (``cache.k.at[layer].set(vmap(dynamic_update_slice)(cache.k[layer],
     ...))``) the compiled step cut the 67 MB slab out, looped over the
     slots and wrote it back, for K and for V, every layer: 8.6 GB a step
-    for 1 MB of new rows (PERF.md §6, PR 28).  What may remain is the
-    read's cut, one slab for K and one for V a layer (ROADMAP S1b)."""
+    for 1 MB of new rows (PERF.md §6, PR 28)."""
     ops = list(_entry_ops(compiled_text("decode")))
     layers = CFG.num_hidden_layers
     loops = [name for name, op, _ in ops if op == "while"]
@@ -165,12 +199,21 @@ def test_decode_append_moves_rows_not_slabs(compiled_text):
     assert not rewrites, (
         f"the decode step writes whole slabs back into the cache: "
         f"{rewrites}")
-    cuts = [name for name, op, sizes in ops
-            if (op == "slice" or op == "fusion" and name.startswith("slice"))
-            and any(size >= SLAB for size in sizes)]
-    assert len(cuts) <= 2 * layers, (
+
+
+def test_decode_reads_the_cache_where_it_lies(compiled_text):
+    """The read is one ``cached_decode_attention`` call a layer on the
+    stored buffers.  Through ``cached_attention`` it took ``cache.k[layer]``
+    and the compiled step cut the layer's 67 MB slab out first, for K and
+    for V: 32 cuts, 4.9 of the 19.1 ms of a step in the Mistral cell
+    (PERF.md §5, PR 28)."""
+    text = compiled_text("decode")
+    layers = CFG.num_hidden_layers
+    assert len(_kernel_calls(text, "cached_decode_attention")) == layers
+    cuts = _slab_sized_cuts(text)
+    assert not cuts, (
         f"{len(cuts)} slab-sized cuts of the cache where the read takes "
-        f"{2 * layers}: {cuts}")
+        f"none: {cuts}")
 
 
 # -- a model with recurrent state (PR 27) -----------------------------------
@@ -209,14 +252,7 @@ def test_prefill_chunk_reads_one_slots_state_not_every_slots(
     state of all 64 slots first: a ``slice`` of 268 MB a recurrent layer,
     4.1 of the 11-29 ms of every prefill call on the chip (PERF.md §6,
     PR 27)."""
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
-                                           sharding=one_chip), tree)
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+    on_chip, arg = _placed(one_chip)
     model = hybrid_engine.model
     cache = on_chip(jax.eval_shape(lambda: init_cache(
         model.cache_layers(), slots=HYBRID_SLOTS, max_len=MAX_LEN,
@@ -239,3 +275,26 @@ def test_prefill_chunk_reads_one_slots_state_not_every_slots(
     assert not found, (
         f"the prefill program copies the state of every slot "
         f"({found[:3]}): a chunk reads and writes one slot's state")
+
+
+def test_hybrid_decode_reads_the_cache_where_it_lies(hybrid_engine, one_chip):
+    """The same read at the hybrid cell's attention geometry: 16 query
+    heads a KV head, 2 KV heads, 64 slots - a ``[1024, 2, 128]`` tile."""
+    on_chip, arg = _placed(one_chip)
+    model = hybrid_engine.model
+    cache = on_chip(jax.eval_shape(lambda: init_cache(
+        model.cache_layers(), slots=HYBRID_SLOTS, max_len=MAX_LEN,
+        dtype=jnp.bfloat16)))
+    text = _lower_decode_as_on_the_chip(
+        hybrid_engine, on_chip(hybrid_engine.params), cache,
+        arg).compile().as_text()
+    cfg = model.config
+    slab = HYBRID_SLOTS * MAX_LEN * cfg.num_key_value_heads * cfg.head_dim
+    assert "bf16[1,%d,%d,%d,%d]" % (
+        HYBRID_SLOTS, MAX_LEN, cfg.num_key_value_heads,
+        cfg.head_dim) in text
+    assert len(_kernel_calls(text, "cached_decode_attention")) == 1
+    found = _slab_sized_cuts(text, slab) + _slab_sized_layout_copies(
+        text, slab)
+    assert not found, (
+        f"the hybrid decode program cuts or copies the K/V slab: {found}")

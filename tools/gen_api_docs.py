@@ -64,6 +64,7 @@ PAGES = {
         "apex_tpu.ops.rope", "apex_tpu.ops.layer_norm",
         "apex_tpu.ops.packed_update", "apex_tpu.ops.fused_lm_head",
         "apex_tpu.ops.pair_bias_attention",
+        "apex_tpu.ops.cached_decode_attention",
     ]),
     "models": ("Model zoo", [
         "apex_tpu.models", "apex_tpu.models.llama",
@@ -469,7 +470,21 @@ float32 — for a bf16 cache the flash kernel's arithmetic, the
 precision the model is trained under.  No buffer of the size of an
 expanded or float32 cache view exists in any program
 (`tests/test_serving.py` walks the decode and prefill programs for
-one).  Bytes past `lengths` (chunk
+one).  **The decode step's read has two implementations, and
+`decode_attend` chooses by what it is handed** (the `kernel_dispatch`
+event `cached_decode_attention` says which, `pallas` or `reference`):
+on a TPU backend a dense float cache in the query's dtype with a head
+width of whole 128-lane tiles and a `max_len` of whole blocks is read
+**in place** by one Pallas kernel (`ops.cached_decode_attention`) that
+takes the stored `[layers, slots, max_len, kv_heads, head_dim]` buffers
+whole with the layer index and each lane's bound as scalars, walks a
+lane's rows in blocks and stops at its last live block - no layer slab
+is cut out, nothing is fetched or multiplied past the bound, same
+operand and accumulation dtypes, sums taken blockwise (close to the
+reference, not bit-equal: `tests/test_decode_read_kernel.py`);
+everything else - a CPU backend, int8 rows, a block table, a 64-wide
+head, and every prefill chunk and verify - goes through
+`cached_attention`.  Bytes past `lengths` (chunk
 padding, evicted streams) are garbage by contract and unreadable by
 construction.
 

@@ -13,7 +13,13 @@ optimizers).  This package holds the production-shaped model definitions:
 - :mod:`apex_tpu.models.nemotron_h` — Nemotron-H hybrid decoder, serving
   only: Mamba-2, latent routed experts and rope-free GQA by a pattern
   string, one mixer a layer.
+- :mod:`apex_tpu.models.dots3` — dots3-note decoder, serving only: latent
+  attention in every layer (a learned top-k key selector on the full
+  layers, a window on the others, a gate a head) and sigmoid-routed gated
+  experts beside a shared expert.
 """
+
+from apex_tpu.models.dots3 import Dots3NoteConfig, Dots3NoteForCausalLM
 
 from apex_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from apex_tpu.models.llama_pipeline import (
@@ -25,7 +31,7 @@ from apex_tpu.models.llama_pipeline import (
 from apex_tpu.models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
 from apex_tpu.models.vit import ViTConfig, ViTForImageClassification
 
-__all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaPipeConfig",
+__all__ = ["Dots3NoteConfig", "Dots3NoteForCausalLM", "LlamaConfig", "LlamaForCausalLM", "LlamaPipeConfig",
            "build_llama_pipeline", "init_llama_pipeline_params",
            "make_llama_3d_train_step", "NemotronHConfig",
            "NemotronHForCausalLM", "ViTConfig", "ViTForImageClassification"]

@@ -76,11 +76,11 @@ from apex_tpu.obs import trace as obs_trace
 from apex_tpu.serving.kv_cache import (
     CallCounters,
     KVCache,
-    KVRows,
     RecurrentRows,
     commit_slot_length,
     gather_slot_rows,
     init_cache,
+    other_state,
     release_slot,
     value_dtype,
     write_slot_region,
@@ -325,29 +325,28 @@ class DecodeEngine:
         self.params = params
         self.slots = int(slots)
         # a model declares what each layer keeps a slot (K/V rows, a
-        # recurrent state, counters) and the cache is built from that.  What
-        # pages, shards or quantizes K/V rows knows nothing of the other two,
-        # so for a model that keeps them each such mechanism is refused here
-        # by name
+        # recurrent state, latent rows, a window ring, counters) and the
+        # cache is built from that.  What pages, shards, quantizes, copies or
+        # rolls back K/V rows knows nothing of the others, so for a model
+        # that keeps them each such mechanism is refused by name through
+        # refuse_other_state: the options here, the methods, the scheduler's
         self._layers = tuple(model.cache_layers())
+        self._other_state = other_state(self._layers)
         self._recurrent = any(isinstance(l, RecurrentRows)
                               for l in self._layers)
-        self._hybrid = any(l is not None and not isinstance(l, KVRows)
-                           for l in self._layers)
-        if self._hybrid:
-            for given, what in (
-                    (paged is not None, "paged= (a block table pages K/V "
-                     "rows; a recurrent state has no rows to page)"),
-                    (tp is not None, "tp= (the model's mixers have no "
-                     "tensor-parallel layout)"),
-                    (quant is not None and quant.kv, "QuantConfig(kv=True) "
-                     "(the int8 format stores K/V rows; a float32 state is "
-                     "not quantized)")):
-                if given:
-                    raise ValueError(
-                        f"{type(model).__name__} declares per-layer state "
-                        f"other than K/V rows (cache_layers()); it cannot be "
-                        f"served with {what}")
+        for given, what in (
+                (paged is not None, "paged= (a block table pages K/V rows)"),
+                (tp is not None, "tp= (the model's mixers have no "
+                 "tensor-parallel layout)"),
+                (quant is not None and quant.kv, "QuantConfig(kv=True) (the "
+                 "int8 format stores K/V rows)")):
+            if given:
+                self.refuse_other_state(what)
+        # the layers that say what a decode step reads of their rows
+        # (``rows_read``: a latent-attention model's), for the engine.decode
+        # span's counts and their running sums
+        self._reading = [l for l in self._layers if hasattr(l, "rows_read")]
+        self._rows_read: dict = {}
         # opt-in tensor parallelism: validate the head/vocab split up
         # front (a bad divisor must fail at construction, not as an XLA
         # sharding error three calls later) and build the serving mesh.
@@ -740,12 +739,45 @@ class DecodeEngine:
         (then nothing that copies, shares or rolls back K/V rows serves)."""
         return self._recurrent
 
-    def _refuse_recurrent(self, what: str) -> None:
-        if self._recurrent:
+    @property
+    def other_state(self) -> list:
+        """The kinds of per-layer state the model declares beside K/V rows
+        (``["RecurrentRows: a recurrent state", ...]``; empty for a model
+        that keeps K/V rows alone): nothing that pages, shards, quantizes,
+        copies, shares or rolls back K/V rows serves a model that has
+        any."""
+        return list(self._other_state)
+
+    def refuse_other_state(self, what: str) -> None:
+        """The one refusal for every slot state that is not K/V rows, naming
+        the declarations and the option or method ``what`` (the engine's
+        and the scheduler's alike)."""
+        if self._other_state:
             raise ValueError(
-                f"{what} on a model with recurrent state "
-                f"({type(self.model).__name__}): it moves K/V rows, and a "
-                f"slot's recurrent state is not among them")
+                f"{what} cannot serve {type(self.model).__name__}: it moves "
+                f"K/V rows, and the model declares per-layer state other "
+                f"than K/V rows that is not among them (cache_layers(): "
+                f"{', '.join(self._other_state)})")
+
+    def _count_rows(self, act) -> dict:
+        """What a decode step reads over the active lanes, the row it appends
+        among them, summed over the layers that declare it
+        (``LatentRows.rows_read``: ``index_rows``, ``attended_rows``;
+        ``RingRows.rows_read``: ``window_rows``), from the host mirror: no
+        readback.  Empty for a model that keeps K/V rows."""
+        out: dict = {}
+        if self._reading:
+            live = self._lengths_host[act] + 1
+            for layer in self._reading:
+                for name, rows in layer.rows_read(live).items():
+                    out[name] = out.get(name, 0) + rows
+        return out
+
+    def rows_read(self) -> dict:
+        """:meth:`_count_rows` summed over every decode step so far (what
+        each ``engine.decode`` span carries as attributes, for a run that
+        records no spans); empty for a model that keeps K/V rows."""
+        return dict(self._rows_read)
 
     def moe_stats(self) -> dict:
         """What the counting layers (routed experts) added up over every
@@ -778,14 +810,16 @@ class DecodeEngine:
                  else jax.device_put(np.zeros((self.slots,), np.int32),
                                      self._host_target))
         self._cache = dataclasses.replace(self._cache, lengths=zeros)
-        if self._hybrid:
-            # committed like every jit output, or the next call retraces
+        if self._other_state:
+            # a recurrent state and counters start from zero (rows are hidden
+            # by the lengths); committed like every jit output, or the next
+            # call retraces
             self._cache = dataclasses.replace(
                 self._cache, **jax.device_put(
-                    {"state": jax.tree.map(jnp.zeros_like,
-                                           self._cache.state),
-                     "counters": jnp.zeros_like(self._cache.counters)},
-                    self._device))
+                    {name: jax.tree.map(jnp.zeros_like,
+                                        getattr(self._cache, name))
+                     for name in ("state", "counters")
+                     if hasattr(self._cache, name)}, self._device))
         self._lengths_host[:] = 0
         self._restored.clear()
         if self._pager is not None:
@@ -1012,7 +1046,7 @@ class DecodeEngine:
         parallel-sampling / n-best primitive)."""
         self._check_slot(src)
         self._check_slot(dst)
-        self._refuse_recurrent("fork_slot")
+        self.refuse_other_state("fork_slot")
         if self._pager is None:
             raise ValueError("fork_slot on a dense engine — the dense "
                              "layout has no shareable blocks")
@@ -1094,9 +1128,9 @@ class DecodeEngine:
         self._check_slot(slot)
         n = len(tokens)
         bucket = self.bucket_for(n)      # raises on n < 1 / n too long
+        offset = int(self._lengths_host[slot])
         with obs_trace.span("engine.prefill_chunk", slot=int(slot),
-                            bucket=bucket, tokens=n):
-            offset = int(self._lengths_host[slot])
+                            bucket=bucket, tokens=n, offset=offset):
             if offset + n > self.max_len:
                 raise ValueError(
                     f"chunk of {n} tokens at offset {offset} overruns "
@@ -1189,7 +1223,7 @@ class DecodeEngine:
         blocks into one span read, so its compiles are bounded by
         ``ceil(prefill_len / block_size)`` distinct extents."""
         self._check_slot(slot)
-        self._refuse_recurrent("read_region / prefix capture")
+        self.refuse_other_state("read_region / prefix capture")
         if self._pager is not None:
             raise ValueError(
                 "read_region on a paged engine — prefix capture is "
@@ -1228,7 +1262,7 @@ class DecodeEngine:
         (:meth:`slot_block_ids` + pool refcounts), never by copy.
         """
         self._check_slot(slot)
-        self._refuse_recurrent("capture_slot (preemption snapshot)")
+        self.refuse_other_state("capture_slot (preemption snapshot)")
         if self._pager is not None:
             raise ValueError(
                 "capture_slot on a paged engine — capture by reference "
@@ -1291,7 +1325,7 @@ class DecodeEngine:
         compute the next-token logits the stream needs.
         """
         self._check_slot(slot)
-        self._refuse_recurrent("restore_prefix")
+        self.refuse_other_state("restore_prefix")
         if self._pager is not None:
             raise ValueError(
                 "restore_prefix on a paged engine — hits alias shared "
@@ -1361,6 +1395,10 @@ class DecodeEngine:
                 sp.set_attribute("lanes", int(act.sum()))
                 sp.set_attribute("kv_tokens",
                                  int(self._lengths_host[act].sum()))
+            for name, rows in self._count_rows(act).items():
+                self._rows_read[name] = self._rows_read.get(name, 0) + rows
+                if sp is not None:
+                    sp.set_attribute(name, rows)
             full = act & (self._lengths_host >= self.max_len)
             if full.any():
                 raise ValueError(
@@ -1436,8 +1474,8 @@ class DecodeEngine:
         the same invariant a plain decode step leaves.
         """
         self._check_slot(slot)
-        self._refuse_recurrent("verify_draft (speculation rolls rejected "
-                               "rows back by a length)")
+        self.refuse_other_state("verify_draft (speculation rolls rejected "
+                                 "rows back by a length)")
         k = len(tokens) - 1
         if k < 1:
             raise ValueError(

@@ -45,14 +45,17 @@ twin, and one seam a model's attention calls through:
   view a read takes, and the positions a decode step appends at - and
   nothing about int8.
 - **What a model keeps a slot** - ``model.cache_layers()``: one of
-  :class:`KVRows`, :class:`RecurrentRows`, :class:`CallCounters` or None
-  a layer; :func:`init_cache` builds every cache from that.
+  :class:`KVRows`, :class:`RecurrentRows`, :class:`LatentRows`,
+  :class:`RingRows`, :class:`CallCounters` or None a layer;
+  :func:`init_cache` builds every cache from that.
 - **The seam**: :func:`decode_attend` and :func:`prefill_attend` are the
   whole step an attention layer needs - append or chunk-write, view, cast
   to the query's dtype, the grouped masked read
   (:func:`cached_attention`) - whatever the layout and the format.  A
-  model imports those two (and, for a recurrent layer, the state
-  functions at the end of this module) and names no cache class.
+  model imports those two (for a recurrent layer the state functions, for
+  a latent-attention layer the latent pair :func:`latent_decode_attend` /
+  :func:`latent_prefill_attend` and their window twins, at the end of this
+  module) and names no cache class.
 
 Masking exactness: masked attention scores sit at ``-1e30`` (the flash
 kernels' ``_NEG_INF``), so ``exp(masked - max)`` underflows to exactly
@@ -87,7 +90,9 @@ __all__ = ["KVCache", "QuantKVCache", "FloatRows", "Int8Rows", "DenseLayout",
            "cached_attention", "decode_attention", "decode_attend",
            "prefill_attend", "KVRows", "RecurrentRows", "CallCounters",
            "RecurrentState", "HybridCache", "slot_state", "write_slot_state",
-           "write_lane_state", "add_counts"]
+           "write_lane_state", "add_counts", "LatentRows", "RingRows",
+           "LatentCache", "latent_decode_attend", "latent_prefill_attend",
+           "ring_decode_attend", "ring_prefill_attend", "other_state"]
 
 
 # ---- storage format: how a row is stored -----------------------------------
@@ -649,6 +654,58 @@ class KVRows:
     head_dim: int
 
 
+# rows of a window ring come in whole sublane tiles of the stored type
+RING_ROWS = 16
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentRows:
+    """A latent-attention layer with a key selector: ``[max_len, width]``
+    latent rows a slot (the compressed K/V and the rope key all heads
+    share) and ``[max_len, index_width]`` selector keys, of which a query
+    attends the ``top_k`` rows that score highest."""
+
+    width: int
+    index_width: int
+    top_k: int
+    what = "latent rows with selector keys"
+
+    @property
+    def stored_width(self) -> int:
+        """``width`` in whole lane tiles.  XLA:TPU stores ``[max_len, 576]``
+        bfloat16 with ``max_len`` in the lanes (no padding that way) and
+        then copies the whole buffer, 1.2 GB at 16 x 32,768 rows, into and
+        out of every program that reads rows of it (compiled for a described
+        v5e, PR 31); at 640 it is stored by rows."""
+        return -(-self.width // 128) * 128
+
+    def rows_read(self, live) -> dict:
+        """What one decode step of this layer reads, from the live rows
+        ``live [lanes]`` of its active lanes (the appended one among them):
+        every selector key, and the latent rows the selection leaves."""
+        return {"index_rows": int(live.sum()),
+                "attended_rows": int(live.clip(max=self.top_k).sum())}
+
+
+@dataclasses.dataclass(frozen=True)
+class RingRows:
+    """A latent-attention layer that sees ``window`` positions, the query's
+    own among them: a ring of ``rows`` latent rows a slot, position ``p`` at
+    row ``p mod rows``, whatever ``max_len`` is."""
+
+    width: int
+    window: int
+    what = "a ring of window rows"
+
+    @property
+    def rows(self) -> int:
+        return -(-self.window // RING_ROWS) * RING_ROWS
+
+    def rows_read(self, live) -> dict:
+        """As :meth:`LatentRows.rows_read`: the window's rows."""
+        return {"window_rows": int(live.clip(max=self.window).sum())}
+
+
 @dataclasses.dataclass(frozen=True)
 class RecurrentRows:
     """A layer that keeps a fixed-size state a slot: ``ssm`` (float32: it is
@@ -658,6 +715,7 @@ class RecurrentRows:
 
     ssm: tuple
     conv: tuple
+    what = "a recurrent state"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -666,6 +724,17 @@ class CallCounters:
     back by ``DecodeEngine.moe_stats`` when somebody asks."""
 
     names: tuple
+    what = "call counters"
+
+
+def other_state(layers) -> list:
+    """``["RecurrentRows: a recurrent state", ...]``: the kinds of per-layer
+    state in ``layers`` (``model.cache_layers()``) that are not K/V rows, by
+    declaration - what everything that pages, shards, quantizes, copies or
+    rolls back K/V rows has to refuse."""
+    kinds = {type(l) for l in layers
+             if l is not None and not isinstance(l, KVRows)}
+    return sorted(f"{k.__name__}: {k.what}" for k in kinds)
 
 
 @functools.partial(jax.tree_util.register_dataclass,
@@ -715,17 +784,36 @@ def init_cache(layers, *, slots: int, max_len: int, dtype=jnp.float32,
                int8: bool = False, paged=None):
     """Zero-filled cache for a model's per-layer declarations (``layers``:
     ``model.cache_layers()`` — a :class:`KVRows`, :class:`RecurrentRows`,
-    :class:`CallCounters` or None a layer), in the layout and the storage
+    :class:`LatentRows`, :class:`RingRows`, :class:`CallCounters` or None a
+    layer), in the layout and the storage
     format asked for: dense slot rows, or the block pool of ``paged`` (a
     :class:`~apex_tpu.serving.paged_kv_cache.PagedCacheConfig`); floats of
     ``dtype``, or :class:`Int8Rows` with ``int8``.
 
     Layers that keep K/V rows alone give a :class:`KVCache` /
     :class:`QuantKVCache` (or the paged pair); a recurrent state or
-    counters give a :class:`HybridCache`, which is dense floats only."""
+    counters give a :class:`HybridCache`, latent rows or window rings a
+    :class:`LatentCache`: both dense floats only."""
     kv, n_kv = _one_shape(layers, KVRows, "K/V rows")
     rec, n_rec = _one_shape(layers, RecurrentRows, "recurrent states")
     cnt, n_cnt = _one_shape(layers, CallCounters, "counters")
+    lat, n_lat = _one_shape(layers, LatentRows, "latent rows")
+    ring, n_ring = _one_shape(layers, RingRows, "window rings")
+    if lat or ring:
+        if kv or rec or paged is not None or int8:
+            raise ValueError(
+                "latent rows and window rings are dense floats, and a model "
+                "that keeps them keeps no K/V rows and no recurrent state "
+                "beside them (no model here mixes them)")
+        lat, ring = lat or LatentRows(0, 0, 0), ring or RingRows(0, 0)
+        return LatentCache(
+            latent=jnp.zeros((n_lat, slots, max_len, lat.stored_width),
+                             dtype),
+            index=jnp.zeros((n_lat, slots, max_len, lat.index_width), dtype),
+            ring=jnp.zeros((n_ring, slots, ring.rows, ring.width), dtype),
+            lengths=jnp.zeros((slots,), jnp.int32),
+            counters=jnp.zeros((n_cnt, len(cnt.names) if cnt else 0),
+                               jnp.int32))
     kv = kv or KVRows(0, 0)
     if paged is not None:
         if rec or cnt:
@@ -796,7 +884,338 @@ def write_lane_state(cache: HybridCache, layer: int, ssm, conv,
         conv=st.conv.at[layer].set(keep(conv, st.conv[layer]))))
 
 
-def add_counts(cache: HybridCache, layer: int, counts) -> HybridCache:
+def add_counts(cache, layer: int, counts):
     """Add one call's counts to a counting layer's row."""
     return dataclasses.replace(
         cache, counters=cache.counters.at[layer].add(counts))
+
+
+# ---- latent rows: what a latent-attention layer keeps, and its seam --------
+#
+# A latent-attention layer keeps, a token, the compressed row every head's K
+# and V are expanded from and the rope key all heads share - hundreds of
+# values where expanded K and V are tens of thousands - and, when it selects
+# its keys, a selector key.  A layer that sees a window keeps a ring of the
+# window's rows.  The four functions below are the latent pair of the seam
+# and its window twin: append or chunk-write, score, select, read.  A model
+# hands them queries, new rows and - for a chunk - ``expand``, its map from
+# stored rows to per-head K and V; it names no cache class.
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("latent", "index", "ring", "lengths",
+                                "counters"), meta_fields=())
+@dataclasses.dataclass(frozen=True)
+class LatentCache:
+    """``latent [selecting layers, slots, max_len, stored_width]`` (a row's
+    ``width`` values, then zeros up to whole lane tiles) and ``index
+    [selecting layers, slots, max_len, index_width]`` for the layers that
+    declared :class:`LatentRows`; ``ring [window layers, slots, rows,
+    width]`` for those that declared :class:`RingRows` (position ``p`` at
+    row ``p mod rows``: a slot keeps a window, whatever ``max_len``);
+    ``counters`` as :class:`HybridCache` has them.  ``lengths`` counts a
+    slot's tokens for every kind of layer alike: rows at or past it are
+    garbage, and a ring row holds position ``p`` only if ``p`` is below
+    it."""
+
+    latent: jax.Array
+    index: jax.Array
+    ring: jax.Array
+    lengths: jax.Array
+    counters: jax.Array
+
+    @property
+    def dtype(self):
+        return self.latent.dtype
+
+    @property
+    def num_slots(self) -> int:
+        return self.lengths.shape[0]
+
+    @property
+    def max_len(self) -> int:
+        return self.latent.shape[2]
+
+    def decode_positions(self, active):
+        """As :meth:`DenseLayout.decode_positions`: an idle lane writes the
+        row its length hides."""
+        del active
+        return self.lengths
+
+
+def _key_block(max_len: int) -> int:
+    """Rows a step of the blocked loops below reads: 512, or a quarter of a
+    short cache (the tests' sizes walk several blocks too)."""
+    block = min(512, max(8, max_len // 4))
+    return block if max_len % block == 0 else max_len
+
+
+def _index_scores(q, w, keys, scale: float):
+    """The selector's score of every key for every query: ``sum_j w[m, j]
+    relu(q[m, j] . keys[n]) scale``; ``q [..., m, J, d]``, ``w [..., m, J]``
+    float32, ``keys [..., n, d]``.  Products in the stored type, sums in
+    float32."""
+    dots = jnp.einsum("...mjd,...nd->...mjn", q, keys,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("...mjn,...mj->...mn", jax.nn.relu(dots),
+                      w.astype(jnp.float32)) * scale
+
+
+def _attend(q, k, v, mask, scale: float):
+    """Masked softmax read, all keys at once: ``q [b, m, H, dk]``; ``k [b,
+    n, Hk, dk]`` / ``v [b, n, Hk, dv]`` with ``Hk`` ``H`` (per-head K and V)
+    or 1 (rows every head reads as they are stored: the absorbed form);
+    ``mask [b, m, n]``.  Returns ``[b, m, H, dv]`` float32.  Products in
+    ``k``'s type, sums float32."""
+    qs = (q.astype(jnp.float32) * scale).astype(k.dtype)
+    if k.shape[2] == 1:
+        s = jnp.einsum("bmhd,bnd->bhmn", qs, k[:, :, 0],
+                       preferred_element_type=jnp.float32)
+    else:
+        s = jnp.einsum("bmhd,bnhd->bhmn", qs, k,
+                       preferred_element_type=jnp.float32)
+    s = jnp.where(mask[:, None], s, _NEG_INF)
+    e = jnp.exp(s - s.max(-1, keepdims=True))
+    p = (e / e.sum(-1, keepdims=True)).astype(v.dtype)
+    if v.shape[2] == 1:
+        return jnp.einsum("bhmn,bnd->bmhd", p, v[:, :, 0],
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum("bhmn,bnhd->bmhd", p, v,
+                      preferred_element_type=jnp.float32)
+
+
+def _select(scores, visible, top_k: int):
+    """The ``top_k`` largest ``scores [m, n]`` among each row's ``visible``
+    keys (all of them while a row sees fewer), as ``lax.top_k`` chooses
+    them - of equal scores the lower index first.  Returns ``(index [m, k],
+    chosen [m, k] bool)``: what a decode step gathers by."""
+    scores = jnp.where(visible, scores, -jnp.inf)
+    values, index = lax.top_k(scores, min(int(top_k), scores.shape[-1]))
+    return index, values > -jnp.inf
+
+
+def _select_mask(scores, visible, top_k: int):
+    """:func:`_select`'s choice as a mask ``[m, n]``, without the sort a
+    ``top_k`` of thousands is on the chip (25 ms for ``[1024, 32768]``, a
+    quarter of a chunk: PERF.md section 6, PR 31): the ``top_k``-th largest
+    score of each row is found bit by bit - scores as integers that order
+    alike, one compare-and-count pass over the row a bit - and of the scores
+    equal to it the lowest indices are kept, found the same way, as
+    ``lax.top_k`` keeps them."""
+    n = scores.shape[-1]
+    k = min(int(top_k), n)
+    bits = lax.bitcast_convert_type(
+        jnp.where(visible, scores, -jnp.inf).astype(jnp.float32), jnp.uint32)
+    # float32 bit patterns in unsigned order: negatives reversed below
+    # the positives (-0.0 below 0.0, the total order ``lax.top_k`` sorts by)
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def kth(i, found):
+        trial = found | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = (keys >= trial).sum(-1, keepdims=True) >= k
+        return jnp.where(enough, trial, found)
+
+    last = lax.fori_loop(0, 32, kth, jnp.zeros(keys.shape[:-1] + (1,),
+                                               jnp.uint32))
+    above = keys > last
+    equal = (keys == last) & visible
+    wanted = k - above.sum(-1, keepdims=True)
+    col = jnp.arange(n, dtype=jnp.int32)
+    width = max(n - 1, 1).bit_length()
+
+    def cut(i, found):
+        # the largest p with fewer than ``wanted`` equal scores before it:
+        # the column of the last one kept
+        trial = found | (1 << (width - 1 - i))
+        few = (equal & (col < trial)).sum(-1, keepdims=True) < wanted
+        return jnp.where(few, trial, found)
+
+    until = lax.fori_loop(0, width, cut,
+                          jnp.zeros(keys.shape[:-1] + (1,), jnp.int32))
+    return visible & (above | (equal & (col <= until)))
+
+
+def _as_stored(rows, buf):
+    """``rows [n, width]`` as ``buf`` stores them: its type, zeros up to its
+    last axis."""
+    return jnp.pad(rows.astype(buf.dtype),
+                   ((0, 0), (0, buf.shape[-1] - rows.shape[-1])))
+
+
+def _lane_write(buf, layer: int, rows_at, rows):
+    """``rows [lanes, width]`` at row ``rows_at[lane]`` of every lane; a row
+    out of range is dropped."""
+    lanes = jnp.arange(rows_at.shape[0], dtype=jnp.int32)
+    at = jnp.where(rows_at < 0, buf.shape[2], rows_at)
+    return buf.at[layer, lanes, at].set(_as_stored(rows, buf), mode="drop")
+
+
+def latent_decode_attend(cache, layer: int, q, row, position, *,
+                         scale: float, rank: int, select: dict):
+    """One decode step of one selecting latent layer: append each lane's
+    new ``row [lanes, width]`` and selector key at ``position [lanes]``,
+    score the selector's keys of the lane's rows ``idx <= position``, take
+    the ``select["top_k"]`` largest (all of them while a lane has fewer),
+    gather those latent rows and read them in the absorbed form: ``q [lanes,
+    H, width]`` against the rows as stored, values the rows' first ``rank``
+    columns.  ``select`` = ``{"q" [lanes, J, d], "w" [lanes, J], "key"
+    [lanes, d], "top_k", "scale"}``.  Returns ``(ctx [lanes, H, rank]
+    float32, cache)``.
+
+    What the step touches in proportion to ``max_len`` is the selector's
+    keys (blocks up to the longest lane's length) and one float32 score a
+    row; the latent rows it reads are the ``top_k`` it gathers."""
+    position = jnp.asarray(position, jnp.int32)
+    cache = dataclasses.replace(
+        cache, latent=_lane_write(cache.latent, layer, position, row),
+        index=_lane_write(cache.index, layer, position, select["key"]))
+    lanes, max_len = position.shape[0], cache.max_len
+    block = _key_block(max_len)
+    width = cache.index.shape[-1]
+
+    def score(i, scores):
+        keys = lax.dynamic_slice(cache.index, (layer, 0, i * block, 0),
+                                 (1, lanes, block, width))[0]
+        part = _index_scores(select["q"][:, None], select["w"][:, None],
+                             keys.astype(select["q"].dtype),
+                             select["scale"])[:, 0]
+        return lax.dynamic_update_slice(scores, part, (0, i * block))
+
+    scores = lax.fori_loop(
+        0, jnp.minimum(jnp.max(position) // block + 1, max_len // block),
+        score,
+        jnp.full((lanes, max_len), -jnp.inf, jnp.float32))
+    col = jnp.arange(max_len, dtype=jnp.int32)
+    index, chosen = _select(scores, col[None] <= position[:, None],
+                            select["top_k"])
+    rows = cache.latent[layer, jnp.arange(lanes)[:, None], index]
+    rows = rows.astype(q.dtype)[:, :, None]          # [lanes, k, 1, stored]
+    # the query takes the stored row's zeros rather than the rows a slice
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, rows.shape[-1] - q.shape[-1])))
+    ctx = _attend(q[:, None], rows, rows[..., :rank], chosen[:, None], scale)
+    return ctx[:, 0], cache
+
+
+def ring_decode_attend(cache, layer: int, q, row, position, *, scale: float,
+                       rank: int, window: int):
+    """One decode step of one window layer: put each lane's new ``row`` at
+    ring row ``position mod rows``, gather the rows of positions ``position
+    - window < p <= position`` and read them in the absorbed form (``q
+    [lanes, H, width]``).  Returns ``(ctx [lanes, H, rank] float32,
+    cache)``."""
+    position = jnp.asarray(position, jnp.int32)
+    ring = cache.ring.shape[2]
+    cache = dataclasses.replace(
+        cache, ring=_lane_write(cache.ring, layer, position % ring, row))
+    lanes = position.shape[0]
+    n = -(-window // 8) * 8
+    at = position[:, None] - (n - 1) + jnp.arange(n, dtype=jnp.int32)
+    seen = (at >= 0) & (at > position[:, None] - window)
+    rows = cache.ring[layer, jnp.arange(lanes)[:, None], at % ring]
+    rows = rows.astype(q.dtype)[:, :, None]
+    ctx = _attend(q[:, None], rows, rows[..., :rank], seen[:, None], scale)
+    return ctx[:, 0], cache
+
+
+def latent_prefill_attend(cache, layer: int, slot, q, rows, offset, *,
+                          scale: float, expand, select: dict):
+    """One prompt chunk of one selecting latent layer: write the chunk's
+    ``rows [s, width]`` and selector keys into ``slot`` at ``offset``, score
+    the selector's keys of rows ``idx <= offset + row`` - earlier chunks'
+    and the chunk's own, under one rule, so that splitting a prompt changes
+    no selection - take each query's ``top_k`` and read the selected rows
+    through ``expand(rows [n, width]) -> (k [n, H, dk], v [n, H, dv])``
+    with ``q [s, H, dk]``.  Returns ``(ctx [s, H, dv] float32, cache)``.
+
+    Both walks are over blocks of rows up to the chunk's end, never over
+    ``max_len``: one block of selector scores a head and one block of
+    expanded K and V exist at a time.  The read is masked by the selection
+    and dense over the visible blocks."""
+    s = q.shape[0]
+    slot = jnp.asarray(slot, jnp.int32)
+    offset = jnp.asarray(offset, jnp.int32)
+    at = offset + jnp.arange(s, dtype=jnp.int32)
+    cache = dataclasses.replace(
+        cache,
+        latent=cache.latent.at[layer, slot, at].set(
+            _as_stored(rows, cache.latent), mode="drop"),
+        index=cache.index.at[layer, slot, at].set(
+            select["key"].astype(cache.index.dtype), mode="drop"))
+    max_len = cache.max_len
+    block = _key_block(max_len)
+    blocks = jnp.minimum((offset + s - 1) // block + 1, max_len // block)
+
+    def stored(buf, i):
+        return lax.dynamic_slice(buf, (layer, slot, i * block, 0),
+                                 (1, 1, block, buf.shape[-1]))[0, 0]
+
+    def score(i, scores):
+        part = _index_scores(select["q"], select["w"],
+                             stored(cache.index, i).astype(q.dtype),
+                             select["scale"])
+        return lax.dynamic_update_slice(scores, part, (0, i * block))
+
+    scores = lax.fori_loop(0, blocks, score,
+                           jnp.full((s, max_len), -jnp.inf, jnp.float32))
+    col = jnp.arange(max_len, dtype=jnp.int32)
+    selected = _select_mask(scores, col[None] <= at[:, None],
+                            select["top_k"])
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+    def read(i, carry):
+        # the flash recurrence over key blocks: running max, sum and values
+        top, total, acc = carry
+        k, v = expand(
+            stored(cache.latent, i)[:, :rows.shape[-1]].astype(q.dtype))
+        sc = jnp.einsum("mhd,nhd->hmn", qs, k,
+                        preferred_element_type=jnp.float32)
+        mask = lax.dynamic_slice(selected, (0, i * block), (s, block))
+        sc = jnp.where(mask[None], sc, _NEG_INF)
+        new_top = jnp.maximum(top, sc.max(-1))
+        e = jnp.exp(sc - new_top[..., None])
+        keep = jnp.exp(top - new_top)
+        acc = acc * keep[..., None] + jnp.einsum(
+            "hmn,nhd->hmd", e.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return new_top, total * keep + e.sum(-1), acc
+
+    heads = q.shape[1]
+    dv = jax.eval_shape(expand, jax.ShapeDtypeStruct(
+        (block, rows.shape[-1]), q.dtype))[1].shape[-1]
+    _, total, acc = lax.fori_loop(
+        0, blocks, read,
+        (jnp.full((heads, s), _NEG_INF, jnp.float32),
+         jnp.zeros((heads, s), jnp.float32),
+         jnp.zeros((heads, s, dv), jnp.float32)))
+    return (acc / total[..., None]).transpose(1, 0, 2), cache
+
+
+def ring_prefill_attend(cache, layer: int, slot, q, rows, offset, length, *,
+                        scale: float, expand, window: int):
+    """One prompt chunk of one window layer: the chunk's queries read the
+    ``window - 1`` rows before the chunk from the ring and the chunk's own
+    rows from the ones in hand, under ``offset + row - window < p <= offset
+    + row``; then the chunk's last real rows (``length`` of them are real)
+    go into the ring.  ``q [s, H, dk]``, ``rows [s, width]``.  Returns
+    ``(ctx [s, H, dv] float32, cache)``."""
+    s = q.shape[0]
+    slot = jnp.asarray(slot, jnp.int32)
+    offset = jnp.asarray(offset, jnp.int32)
+    ring = cache.ring.shape[2]
+    before = -(-(window - 1) // 8) * 8
+    p_before = offset - before + jnp.arange(before, dtype=jnp.int32)
+    old = cache.ring[layer, slot, p_before % ring].astype(q.dtype)
+    mine = offset + jnp.arange(s, dtype=jnp.int32)
+    at = jnp.concatenate([p_before, mine])
+    seen = ((at[None] >= 0) & (at[None] <= mine[:, None])
+            & (at[None] > mine[:, None] - window))
+    k, v = expand(jnp.concatenate([old, rows.astype(q.dtype)]))
+    ctx = _attend(q[None], k[None], v[None], seen[None], scale)[0]
+    # only real rows, and of more than a ring's worth only the last: one
+    # scatter writes no ring row twice
+    n = jnp.arange(s, dtype=jnp.int32)
+    keep = (n < length) & (n >= length - ring)
+    cache = dataclasses.replace(cache, ring=cache.ring.at[
+        layer, slot, jnp.where(keep, mine % ring, ring)].set(
+        rows.astype(cache.ring.dtype), mode="drop"))
+    return ctx, cache

@@ -323,19 +323,15 @@ class ContinuousBatchingScheduler:
                 f"the engine's draft bucket table (max "
                 f"{engine.max_draft}) — widen draft_buckets or narrow "
                 f"the config")
-        if engine.recurrent_state:
-            # each of these copies, shares or rolls back K/V rows; a slot's
-            # recurrent state is not among them (ROADMAP Queue R)
-            for given, what in (
-                    (speculation is not None, "speculation="),
-                    (prefix_caching is not None, "prefix_caching="),
-                    (policy is not None and policy.preemption,
-                     "policy= with preemption (its snapshot is of K/V "
-                     "rows)")):
-                if given:
-                    raise ValueError(
-                        f"{what} cannot serve a model with recurrent "
-                        f"state ({type(engine.model).__name__})")
+        # each of these copies, shares or rolls back K/V rows; no other
+        # per-layer state is among them (ROADMAP Queue R)
+        for given, what in (
+                (speculation is not None, "speculation="),
+                (prefix_caching is not None, "prefix_caching="),
+                (policy is not None and policy.preemption,
+                 "policy= with preemption (its snapshot is of K/V rows)")):
+            if given:
+                engine.refuse_other_state(what)
         self.engine = engine
         # replica identity: None == anonymous (today's unlabeled event
         # stream and metric snapshot, byte-identical).  The engine gets

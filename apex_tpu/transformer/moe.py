@@ -24,6 +24,7 @@ Design notes:
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Optional, Tuple
 
 import jax
@@ -37,8 +38,9 @@ from apex_tpu.ops._dispatch import (
     use_interpret,
 )
 
-__all__ = ["ExpertParallelMLP", "top1_dispatch", "LatentMoE",
-           "topk_sigmoid_route", "grouped_matmul", "MOE_COUNTERS"]
+__all__ = ["ExpertParallelMLP", "top1_dispatch", "LatentMoE", "GatedMoE",
+           "topk_sigmoid_route", "grouped_matmul", "held_pairs",
+           "MOE_COUNTERS"]
 
 
 def top1_dispatch(logits32, capacity: int):
@@ -204,6 +206,70 @@ def grouped_matmul(lhs, rhs, group_sizes):
                               preferred_element_type=jnp.float32)
 
 
+def _held_inside(experts_held, num_experts: int) -> int:
+    """The count of ``experts_held = (start, count)``, a range inside the
+    ``num_experts`` routed experts."""
+    lo, held = experts_held
+    if not 0 <= lo <= lo + held <= num_experts or held < 1:
+        raise ValueError(
+            f"experts_held {tuple(experts_held)}: a range (start, count) "
+            f"inside the {num_experts} routed experts")
+    return held
+
+
+@dataclasses.dataclass(frozen=True)
+class HeldPairs:
+    """The token-expert pairs of one call, sorted by the expert held here
+    they land on (:func:`held_pairs`): ``token_of [t k]`` the token of each
+    sorted row, ``sizes [held + 1]`` the rows of each held expert and, last,
+    of the pairs that land nowhere here, ``order`` the sort, ``key`` each
+    pair's group before it, ``counts`` the call's :data:`MOE_COUNTERS`."""
+
+    key: jax.Array
+    order: jax.Array
+    sizes: jax.Array
+    token_of: jax.Array
+    counts: jax.Array
+
+    def combine(self, out, weights):
+        """Sorted rows ``out [t k, width]`` back to tokens: each real pair
+        times its weight (``weights [t, k]``), summed over a token's
+        ``k``; a row past the held groups is whatever the product left
+        there and counts as zero."""
+        t, k = weights.shape
+        held = self.sizes.shape[0] - 1
+        out = jnp.where((self.key[self.order] < held)[:, None],
+                        out * weights.reshape(t * k)[self.order][:, None],
+                        0.0)
+        # pair (token, j) sits at sorted row inverse[.]
+        inverse = jnp.zeros((t * k,), jnp.int32).at[self.order].set(
+            jnp.arange(t * k, dtype=jnp.int32))
+        return out[inverse].reshape(t, k, out.shape[-1]).sum(1)
+
+
+def held_pairs(chosen, experts_held, valid=None) -> HeldPairs:
+    """What every routed-expert layer that holds a share of the experts
+    does before and after its grouped products: of the pairs ``chosen [t,
+    k]`` (each token's experts) those on the experts ``[start, start +
+    count)`` of rows that are ``valid`` are sorted by expert, the rest sort
+    to the end as group ``count``, which no matrix is multiplied for."""
+    t, k = chosen.shape
+    lo, held = experts_held
+    here = (chosen >= lo) & (chosen < lo + held)
+    if valid is not None:
+        here &= valid[:, None]
+    key = jnp.where(here, chosen - lo, held).reshape(t * k)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+    load = sizes[:held]
+    tokens = t if valid is None else valid.sum()
+    counts = jnp.stack([jnp.int32(1), jnp.asarray(tokens, jnp.int32),
+                        load.sum(), (load > 0).sum().astype(jnp.int32),
+                        load.max()])
+    return HeldPairs(key=key, order=order, sizes=sizes, token_of=order // k,
+                     counts=counts)
+
+
 class LatentMoE(nn.Module):
     """One chip's share of a routed-expert layer whose experts live in a
     latent space (Nemotron-H ``E`` layers), plus the shared expert.
@@ -240,12 +306,8 @@ class LatentMoE(nn.Module):
     @nn.compact
     @jax.named_scope("latent_moe")
     def __call__(self, x, valid=None) -> Tuple[jax.Array, jax.Array]:
-        t, h = x.shape
-        lo, held = self.experts_held
-        if not 0 <= lo <= lo + held <= self.num_experts or held < 1:
-            raise ValueError(
-                f"experts_held {self.experts_held}: a range (start, count) "
-                f"inside the {self.num_experts} routed experts")
+        h = x.shape[1]
+        held = _held_inside(self.experts_held, self.num_experts)
         k = self.top_k
         normal = nn.initializers.normal(0.02)
 
@@ -267,37 +329,77 @@ class LatentMoE(nn.Module):
 
         chosen, weights = topk_sigmoid_route(
             x, router_kernel, router_bias, k, self.routed_scaling_factor)
-        here = (chosen >= lo) & (chosen < lo + held)
-        if valid is not None:
-            here &= valid[:, None]
-        # pairs sorted by the expert held here they land on; the rest sort
-        # to the end as group ``held``, which no matrix is multiplied for
-        key = jnp.where(here, chosen - lo, held).reshape(t * k)
-        order = jnp.argsort(key, stable=True)
-        sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
-        token_of = order // k
-
+        pairs = held_pairs(chosen, self.experts_held, valid)
         latent = dense("latent_down", self.latent_size)(x)
-        rows = latent[token_of]                              # [t k, latent]
-        hid = grouped_matmul(rows, w1.astype(x.dtype), sizes)
+        hid = grouped_matmul(latent[pairs.token_of], w1.astype(x.dtype),
+                             pairs.sizes)
         hid = jnp.square(jax.nn.relu(hid)).astype(x.dtype)
-        out = grouped_matmul(hid, w2.astype(x.dtype), sizes)
-        # a row past the held groups is whatever the product left there
-        out = jnp.where((key[order] < held)[:, None],
-                        out * weights.reshape(t * k)[order][:, None], 0.0)
-        # back to token order: pair (token, j) sits at sorted row inverse[.]
-        inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
-            jnp.arange(t * k, dtype=jnp.int32))
-        routed = out[inverse].reshape(t, k, self.latent_size).sum(1)
+        out = grouped_matmul(hid, w2.astype(x.dtype), pairs.sizes)
+        routed = pairs.combine(out, weights)
         routed = dense("latent_up", h)(routed.astype(x.dtype))
 
         shared = jnp.square(jax.nn.relu(
             dense("shared_up", self.shared_width)(x)))
         shared = dense("shared_down", h)(shared)
+        return routed + shared, pairs.counts
 
-        load = sizes[:held]
-        tokens = t if valid is None else valid.sum()
-        counts = jnp.stack([jnp.int32(1), jnp.asarray(tokens, jnp.int32),
-                            load.sum(), (load > 0).sum().astype(jnp.int32),
-                            load.max()])
-        return routed + shared, counts
+
+class GatedMoE(nn.Module):
+    """One chip's share of a routed-expert layer of gated (SwiGLU) experts
+    that read the hidden vector (DeepSeek-V3's form), plus the shared expert
+    of the same shape.
+
+    As :class:`LatentMoE`: the router is ``num_experts`` wide and keeps the
+    published ``top_k`` and weights (the chosen scores normalised over all
+    ``top_k``, times ``routed_scaling_factor``); this layer holds the
+    experts ``[experts_held[0], experts_held[0] + experts_held[1])`` and
+    computes ``sum over chosen e held here of w_e W_down_e (silu(W_gate_e x)
+    * W_up_e x)`` through :func:`held_pairs` and three grouped products;
+    what experts held elsewhere would add is left out.  ``x [tokens,
+    hidden]``, ``valid [tokens]``; returns ``(out, counts)``."""
+
+    num_experts: int
+    experts_held: Tuple[int, int]
+    top_k: int
+    hidden_size: int
+    expert_width: int
+    shared_width: int
+    routed_scaling_factor: float = 1.0
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    @jax.named_scope("gated_moe")
+    def __call__(self, x, valid=None) -> Tuple[jax.Array, jax.Array]:
+        h = x.shape[1]
+        held = _held_inside(self.experts_held, self.num_experts)
+        normal = nn.initializers.normal(0.02)
+
+        def dense(name, features):
+            return nn.Dense(features, use_bias=False, dtype=x.dtype,
+                            param_dtype=self.param_dtype, kernel_init=normal,
+                            name=name)
+
+        router_kernel = self.param("router_kernel", normal,
+                                   (h, self.num_experts), jnp.float32)
+        router_bias = self.param("router_bias", nn.initializers.zeros,
+                                 (self.num_experts,), jnp.float32)
+        w_gate, w_up = (self.param(name, normal, (held, h, self.expert_width),
+                                   self.param_dtype)
+                        for name in ("experts_gate", "experts_up"))
+        w_down = self.param("experts_down", normal,
+                            (held, self.expert_width, h), self.param_dtype)
+
+        chosen, weights = topk_sigmoid_route(
+            x, router_kernel, router_bias, self.top_k,
+            self.routed_scaling_factor)
+        pairs = held_pairs(chosen, self.experts_held, valid)
+        rows = x[pairs.token_of]                              # [t k, hidden]
+        gate = grouped_matmul(rows, w_gate.astype(x.dtype), pairs.sizes)
+        up = grouped_matmul(rows, w_up.astype(x.dtype), pairs.sizes)
+        hid = (jax.nn.silu(gate) * up).astype(x.dtype)
+        out = grouped_matmul(hid, w_down.astype(x.dtype), pairs.sizes)
+        routed = pairs.combine(out, weights).astype(x.dtype)
+
+        shared = (jax.nn.silu(dense("shared_gate", self.shared_width)(x))
+                  * dense("shared_up", self.shared_width)(x))
+        return routed + dense("shared_down", h)(shared), pairs.counts

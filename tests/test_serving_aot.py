@@ -298,3 +298,66 @@ def test_hybrid_decode_reads_the_cache_where_it_lies(hybrid_engine, one_chip):
         text, slab)
     assert not found, (
         f"the hybrid decode program cuts or copies the K/V slab: {found}")
+
+
+# -- a latent-attention model (PR 31) ---------------------------------------
+# the long-document cell's attention geometry (benchmark/configs/
+# dots3-note-ep8-l5.json, traffic/longdoc-closed.json): one selecting layer
+# and one window layer at published widths, 16 slots of 32,768 rows, a
+# 1,024-row chunk.  Both MLPs dense and narrow: they keep no rows
+
+LATENT_SLOTS, LATENT_MAX_LEN, LATENT_CHUNK = 16, 32768, 1024
+
+
+@pytest.fixture(scope="module")
+def latent_engine():
+    from apex_tpu.models.dots3 import Dots3NoteConfig, Dots3NoteForCausalLM
+
+    model = Dots3NoteForCausalLM(Dots3NoteConfig(
+        vocab_size=256, intermediate_size=256,
+        layer_types=("full_attention", "sliding_attention"),
+        first_k_dense_replace=2), params_dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    params = jax.tree.map(lambda l: jnp.zeros(l.shape, l.dtype), shapes)
+    return sv.DecodeEngine(model, params, slots=2, max_len=64,
+                           prefill_len=64, cache_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_latent_rows_are_stored_by_rows_and_never_copied(latent_engine,
+                                                         one_chip, program):
+    """A ``[max_len, 576]`` bfloat16 row array is one XLA:TPU lays out with
+    ``max_len`` in the lanes (576 is no whole lane tile, 32,768 is), and
+    every program that reads rows of it then copies the whole buffer in and
+    out: 1.2 GB each way a call at this cell's size.  ``LatentRows`` stores
+    640 (PERF.md §6, PR 31): the buffer stays row-major and no copy of its
+    size is made (with ``stored_width`` = 576 this test finds two)."""
+    on_chip, arg = _placed(one_chip)
+    model = latent_engine.model
+    cache = on_chip(jax.eval_shape(lambda: init_cache(
+        model.cache_layers(), slots=LATENT_SLOTS, max_len=LATENT_MAX_LEN,
+        dtype=jnp.bfloat16)))
+    assert cache.latent.shape == (1, LATENT_SLOTS, LATENT_MAX_LEN, 640)
+    params = on_chip(latent_engine.params)
+    with mock.patch.object(_dispatch, "on_tpu", lambda: True):
+        if program == "decode":
+            lowered = latent_engine._decode.lower(
+                params, cache, arg((LATENT_SLOTS,), jnp.int32),
+                arg((LATENT_SLOTS,), bool))
+        else:
+            lowered = latent_engine._prefill.lower(
+                params, cache, arg((1, LATENT_CHUNK), jnp.int32),
+                arg((), jnp.int32), arg((), jnp.int32), arg((), jnp.int32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    rows = "bf16[1,%d,%d,640]" % (LATENT_SLOTS, LATENT_MAX_LEN)
+    assert rows + "{3,2,1,0" in text, "the latent rows are not row-major"
+    slab = LATENT_SLOTS * LATENT_MAX_LEN * 640
+    copies = _slab_sized_layout_copies(text, slab)
+    assert not copies, (
+        f"{program}: {len(copies)} copies of the whole latent buffer, e.g. "
+        f"{copies[:3]}")
+    # what the program keeps beside its arguments: far below the buffer
+    # that a layout copy would add (1.45 GB of temporaries with one, PR 31)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9

@@ -69,7 +69,7 @@ PAGES = {
     "models": ("Model zoo", [
         "apex_tpu.models", "apex_tpu.models.llama",
         "apex_tpu.models.llama_pipeline", "apex_tpu.models.vit",
-        "apex_tpu.models.nemotron_h",
+        "apex_tpu.models.nemotron_h", "apex_tpu.models.dots3",
     ]),
     "contrib": ("Contrib extensions", [
         "apex_tpu.contrib.xentropy", "apex_tpu.contrib.focal_loss",
@@ -563,6 +563,41 @@ against the dense engine *and* the uncached shape-stable forward,
 across prefill, decode, speculation, and prefix hits.  The dense
 layout stays available (the `paged=None` default) so every guarantee
 remains provable side by side.
+
+## What a model keeps a slot: other state than K/V rows
+
+The engine builds its cache from what the model declares, sublayer by
+sublayer (`model.cache_layers()`), through one `init_cache`, and a model's
+layers reach it through the seam's functions and name no cache class:
+
+| declaration | what a slot keeps | the seam | served families |
+|---|---|---|---|
+| `KVRows(kv_heads, head_dim)` | `[max_len, kv_heads, head_dim]` K and V | `decode_attend` / `prefill_attend` | `models.llama`, `models.nemotron_h` (`*` layers) |
+| `RecurrentRows(ssm, conv)` | a float32 state and a convolution tail of fixed size | `slot_state` / `write_slot_state` / `write_lane_state` | `models.nemotron_h` (`M` layers) |
+| `LatentRows(width, index_width, top_k)` | `[max_len, width]` latent rows (the compressed K/V and the shared rope key, stored in whole lane tiles) and `[max_len, index_width]` selector keys | `latent_decode_attend` (append, score the live rows' keys, `top_k`, gather, absorbed read) / `latent_prefill_attend` (chunk-write, blocked scores, the selection as a mask, blocked explicit read) | `models.dots3` (`full_attention` layers) |
+| `RingRows(width, window)` | a ring of `window` rows in whole 16-row tiles, position `p` at row `p mod rows`, whatever `max_len` | `ring_decode_attend` / `ring_prefill_attend` | `models.dots3` (`sliding_attention` layers) |
+| `CallCounters(names)` | int32 counts a decode step adds (`engine.moe_stats()`) | `add_counts` | both routed-expert layers (`transformer.moe.LatentMoE`, `GatedMoE`) |
+
+`models.dots3.Dots3NoteForCausalLM` (latent attention in every layer: a
+learned top-`index_topk` key selector on the full layers, a
+`sliding_window_size` window on the others, a gate a head; sigmoid-routed
+gated experts beside a shared expert, the layer told which experts it
+holds) serves through `DecodeEngine` and the scheduler at their defaults
+up to the `max_len` asked for (32,768 in the benchmark's cell): one decode
+program, one prefill program a bucket.  A decode step touches, in
+proportion to `max_len`, the selector's keys and one float32 score a row
+only; a chunk walks blocks of rows up to its own end.  `engine.decode`'s
+span carries `index_rows`, `attended_rows` and `window_rows` (host counts;
+`engine.rows_read()` sums them), `engine.prefill_chunk`'s its `offset`.
+
+Everything that pages, shards, quantizes, copies, shares or rolls back
+K/V rows knows nothing of the other declarations and is **refused by
+name** for a model that has any (`engine.other_state` lists them): `paged=`,
+`tp=`, `QuantConfig(kv=True)` at the engine's construction;
+`speculation=`, `prefix_caching=` and `policy=` with preemption at the
+scheduler's; `capture_slot`, `read_region`, `restore_prefix`, `fork_slot`
+and `verify_draft` by the method.  The message names the option and the
+declarations (ROADMAP Queue R says what lifting each would take).
 
 ## The prefill bucket table
 

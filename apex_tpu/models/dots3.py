@@ -201,7 +201,8 @@ class LatentAttention(nn.Module):
                            (rank, heads, nope + dv), dt).astype(x.dtype)
 
         def expand(stored):
-            """Stored rows ``[n, rank + dr]`` to each head's K and V."""
+            """Stored rows ``[n, rank + dr]`` to each head's K and V (the
+            uncached path's)."""
             kv = jnp.einsum("nr,rhd->nhd", stored[:, :rank], w_kvb,
                             preferred_element_type=jnp.float32
                             ).astype(stored.dtype)
@@ -262,15 +263,17 @@ class LatentAttention(nn.Module):
             offset = jnp.asarray(0 if position is None else position,
                                  jnp.int32)
             q_full = jnp.concatenate([q[..., :nope], q_rope], -1)[:, 0]
+            # what ``expand`` is made of: the seam reads through it itself
+            expansion = {"w": w_kvb, "nope": nope}
             if select is None:
                 ctx, kv_cache = ring_prefill_attend(
                     kv_cache, layer_idx, slot, q_full, rows[:, 0], offset,
-                    length, scale=scale, expand=expand,
+                    length, scale=scale, expand=expansion,
                     window=cfg.sliding_window_size)
             else:
                 ctx, kv_cache = latent_prefill_attend(
                     kv_cache, layer_idx, slot, q_full, rows[:, 0], offset,
-                    scale=scale, expand=expand,
+                    scale=scale, expand=expansion,
                     select=dict(select, q=q_i[:, 0], w=w_i[:, 0],
                                 key=k_i[:, 0]))
             ctx = ctx[:, None]                             # [s, 1, H, dv]

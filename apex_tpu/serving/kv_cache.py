@@ -81,6 +81,10 @@ from apex_tpu.ops.cached_decode_attention import (
     cached_decode_attention,
 )
 from apex_tpu.ops.flash_attention import _NEG_INF
+from apex_tpu.ops.latent_chunk_attention import (
+    kernel_takes,
+    latent_chunk_attention,
+)
 
 __all__ = ["KVCache", "QuantKVCache", "FloatRows", "Int8Rows", "DenseLayout",
            "init_cache", "prefill_into_slot", "append_token",
@@ -898,8 +902,8 @@ def add_counts(cache, layer: int, counts):
 # its keys, a selector key.  A layer that sees a window keeps a ring of the
 # window's rows.  The four functions below are the latent pair of the seam
 # and its window twin: append or chunk-write, score, select, read.  A model
-# hands them queries, new rows and - for a chunk - ``expand``, its map from
-# stored rows to per-head K and V; it names no cache class.
+# hands them queries, new rows and - for a chunk - ``expand``, what its map
+# from stored rows to per-head K and V is made of; it names no cache class.
 
 
 @functools.partial(jax.tree_util.register_dataclass,
@@ -1117,20 +1121,63 @@ def ring_decode_attend(cache, layer: int, q, row, position, *, scale: float,
     return ctx[:, 0], cache
 
 
+def _expand(expand: dict, stored):
+    """Stored rows ``[n, rank + rope]`` to each head's ``(k [n, H, nope +
+    rope], v [n, H, dv])``: ``expand`` = ``{"w" [rank, H, nope + dv],
+    "nope"}``, a row's first ``rank`` columns through the matrix (products
+    in the rows' type, sums float32, rounded to the rows' type), the rope
+    key - the row's other columns - the same for every head."""
+    w, nope = expand["w"], expand["nope"]
+    rank, heads = w.shape[:2]
+    kv = jnp.einsum("nr,rhd->nhd", stored[:, :rank], w,
+                    preferred_element_type=jnp.float32).astype(stored.dtype)
+    k_rope = jnp.broadcast_to(
+        stored[:, None, rank:],
+        (stored.shape[0], heads, stored.shape[-1] - rank))
+    return (jnp.concatenate([kv[..., :nope], k_rope], axis=-1),
+            kv[..., nope:])
+
+
+def _reads_chunk_in_place(cache, q, expand: dict, block: int) -> bool:
+    """Whether a chunk's read of a selecting latent layer is the Pallas
+    kernel (:func:`~apex_tpu.ops.latent_chunk_attention.
+    latent_chunk_attention`) or the blocked loop of
+    :func:`latent_prefill_attend` - decided by what is in hand, as
+    :func:`_reads_in_place` decides the decode step's: rows stored in the
+    queries' own float dtype, and every slice the kernel takes on whole
+    tiles (:func:`~apex_tpu.ops.latent_chunk_attention.kernel_takes`: the
+    stored width, the rank and a head's halves in lanes, the bucket in
+    sublanes, ``max_len`` in blocks of at least 128 rows).  The
+    ``kernel_dispatch`` event says which."""
+    rank, _, wide = expand["w"].shape
+    shape = dict(m=q.shape[0], stored=cache.latent.shape[-1], rank=rank,
+                 nope=expand["nope"], dv=wide - expand["nope"], block=block,
+                 max_len=cache.max_len)
+    return record_dispatch(
+        "latent_chunk_attention",
+        cache.latent.dtype == q.dtype == expand["w"].dtype
+        and jnp.issubdtype(q.dtype, jnp.floating) and kernel_takes(**shape),
+        heads=q.shape[1], **shape)
+
+
 def latent_prefill_attend(cache, layer: int, slot, q, rows, offset, *,
-                          scale: float, expand, select: dict):
+                          scale: float, expand: dict, select: dict):
     """One prompt chunk of one selecting latent layer: write the chunk's
     ``rows [s, width]`` and selector keys into ``slot`` at ``offset``, score
     the selector's keys of rows ``idx <= offset + row`` - earlier chunks'
     and the chunk's own, under one rule, so that splitting a prompt changes
     no selection - take each query's ``top_k`` and read the selected rows
-    through ``expand(rows [n, width]) -> (k [n, H, dk], v [n, H, dv])``
-    with ``q [s, H, dk]``.  Returns ``(ctx [s, H, dv] float32, cache)``.
+    with ``q [s, H, dk]`` through each head's K and V, expanded from the
+    stored rows by ``expand`` = ``{"w" [rank, H, nope + dv], "nope"}``
+    (:func:`_expand`).  Returns ``(ctx [s, H, dv] float32, cache)``.
 
     Both walks are over blocks of rows up to the chunk's end, never over
     ``max_len``: one block of selector scores a head and one block of
     expanded K and V exist at a time.  The read is masked by the selection
-    and dense over the visible blocks."""
+    and dense over the visible blocks: one recurrence, two implementations
+    (:func:`_reads_chunk_in_place` chooses) - on a TPU the kernel that
+    takes the stored rows whole and keeps a block's scores in fast memory,
+    everywhere else the loop below."""
     s = q.shape[0]
     slot = jnp.asarray(slot, jnp.int32)
     offset = jnp.asarray(offset, jnp.int32)
@@ -1161,12 +1208,31 @@ def latent_prefill_attend(cache, layer: int, slot, q, rows, offset, *,
     selected = _select_mask(scores, col[None] <= at[:, None],
                             select["top_k"])
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    if _reads_chunk_in_place(cache, q, expand, block):
+        ctx = latent_chunk_attention(qs, cache.latent, selected, expand["w"],
+                                     layer, slot, blocks,
+                                     nope=expand["nope"], block=block)
+    else:
+        ctx = _chunk_read(qs, cache.latent, selected, expand, layer, slot,
+                          blocks, block=block, width=rows.shape[-1])
+    return ctx, cache
+
+
+def _chunk_read(qs, latent, selected, expand: dict, layer, slot, blocks, *,
+                block: int, width: int):
+    """The read of :func:`latent_prefill_attend` as a loop in plain
+    ``jax.numpy``: the flash recurrence - running max, sum and values - over
+    the first ``blocks`` key blocks of ``latent[layer, slot]``, a block's
+    rows (their first ``width`` columns) expanded for every head at a time.
+    ``qs [s, H, dk]`` scaled, ``selected [s, max_len]``; returns ``[s, H,
+    dv]`` float32."""
+    s, heads = qs.shape[:2]
 
     def read(i, carry):
-        # the flash recurrence over key blocks: running max, sum and values
         top, total, acc = carry
-        k, v = expand(
-            stored(cache.latent, i)[:, :rows.shape[-1]].astype(q.dtype))
+        stored = lax.dynamic_slice(latent, (layer, slot, i * block, 0),
+                                   (1, 1, block, latent.shape[-1]))[0, 0]
+        k, v = _expand(expand, stored[:, :width].astype(qs.dtype))
         sc = jnp.einsum("mhd,nhd->hmn", qs, k,
                         preferred_element_type=jnp.float32)
         mask = lax.dynamic_slice(selected, (0, i * block), (s, block))
@@ -1179,25 +1245,24 @@ def latent_prefill_attend(cache, layer: int, slot, q, rows, offset, *,
             preferred_element_type=jnp.float32)
         return new_top, total * keep + e.sum(-1), acc
 
-    heads = q.shape[1]
-    dv = jax.eval_shape(expand, jax.ShapeDtypeStruct(
-        (block, rows.shape[-1]), q.dtype))[1].shape[-1]
+    dv = expand["w"].shape[-1] - expand["nope"]
     _, total, acc = lax.fori_loop(
         0, blocks, read,
         (jnp.full((heads, s), _NEG_INF, jnp.float32),
          jnp.zeros((heads, s), jnp.float32),
          jnp.zeros((heads, s, dv), jnp.float32)))
-    return (acc / total[..., None]).transpose(1, 0, 2), cache
+    return (acc / total[..., None]).transpose(1, 0, 2)
 
 
 def ring_prefill_attend(cache, layer: int, slot, q, rows, offset, length, *,
-                        scale: float, expand, window: int):
+                        scale: float, expand: dict, window: int):
     """One prompt chunk of one window layer: the chunk's queries read the
     ``window - 1`` rows before the chunk from the ring and the chunk's own
     rows from the ones in hand, under ``offset + row - window < p <= offset
     + row``; then the chunk's last real rows (``length`` of them are real)
-    go into the ring.  ``q [s, H, dk]``, ``rows [s, width]``.  Returns
-    ``(ctx [s, H, dv] float32, cache)``."""
+    go into the ring.  ``q [s, H, dk]``, ``rows [s, width]``, ``expand`` as
+    :func:`latent_prefill_attend` takes it.  Returns ``(ctx [s, H, dv]
+    float32, cache)``."""
     s = q.shape[0]
     slot = jnp.asarray(slot, jnp.int32)
     offset = jnp.asarray(offset, jnp.int32)
@@ -1209,7 +1274,7 @@ def ring_prefill_attend(cache, layer: int, slot, q, rows, offset, length, *,
     at = jnp.concatenate([p_before, mine])
     seen = ((at[None] >= 0) & (at[None] <= mine[:, None])
             & (at[None] > mine[:, None] - window))
-    k, v = expand(jnp.concatenate([old, rows.astype(q.dtype)]))
+    k, v = _expand(expand, jnp.concatenate([old, rows.astype(q.dtype)]))
     ctx = _attend(q[None], k[None], v[None], seen[None], scale)[0]
     # only real rows, and of more than a ring's worth only the last: one
     # scatter writes no ring row twice

@@ -324,32 +324,43 @@ def latent_engine():
                            prefill_len=64, cache_dtype=jnp.bfloat16)
 
 
+@pytest.fixture(scope="module")
+def latent_compiled(latent_engine, one_chip):
+    """``latent_compiled(program)``: the latent engine's program compiled
+    for the cell-sized cache on the path the chip takes, once for the
+    module."""
+    def compiled(program):
+        on_chip, arg = _placed(one_chip)
+        cache = on_chip(jax.eval_shape(lambda: init_cache(
+            latent_engine.model.cache_layers(), slots=LATENT_SLOTS,
+            max_len=LATENT_MAX_LEN, dtype=jnp.bfloat16)))
+        assert cache.latent.shape == (1, LATENT_SLOTS, LATENT_MAX_LEN, 640)
+        params = on_chip(latent_engine.params)
+        with mock.patch.object(_dispatch, "on_tpu", lambda: True):
+            if program == "decode":
+                lowered = latent_engine._decode.lower(
+                    params, cache, arg((LATENT_SLOTS,), jnp.int32),
+                    arg((LATENT_SLOTS,), bool))
+            else:
+                lowered = latent_engine._prefill.lower(
+                    params, cache, arg((1, LATENT_CHUNK), jnp.int32),
+                    arg((), jnp.int32), arg((), jnp.int32),
+                    arg((), jnp.int32))
+        return lowered.compile()
+
+    return functools.cache(compiled)
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_latent_rows_are_stored_by_rows_and_never_copied(latent_engine,
-                                                         one_chip, program):
+def test_latent_rows_are_stored_by_rows_and_never_copied(latent_compiled,
+                                                         program):
     """A ``[max_len, 576]`` bfloat16 row array is one XLA:TPU lays out with
     ``max_len`` in the lanes (576 is no whole lane tile, 32,768 is), and
     every program that reads rows of it then copies the whole buffer in and
     out: 1.2 GB each way a call at this cell's size.  ``LatentRows`` stores
     640 (PERF.md §6, PR 31): the buffer stays row-major and no copy of its
     size is made (with ``stored_width`` = 576 this test finds two)."""
-    on_chip, arg = _placed(one_chip)
-    model = latent_engine.model
-    cache = on_chip(jax.eval_shape(lambda: init_cache(
-        model.cache_layers(), slots=LATENT_SLOTS, max_len=LATENT_MAX_LEN,
-        dtype=jnp.bfloat16)))
-    assert cache.latent.shape == (1, LATENT_SLOTS, LATENT_MAX_LEN, 640)
-    params = on_chip(latent_engine.params)
-    with mock.patch.object(_dispatch, "on_tpu", lambda: True):
-        if program == "decode":
-            lowered = latent_engine._decode.lower(
-                params, cache, arg((LATENT_SLOTS,), jnp.int32),
-                arg((LATENT_SLOTS,), bool))
-        else:
-            lowered = latent_engine._prefill.lower(
-                params, cache, arg((1, LATENT_CHUNK), jnp.int32),
-                arg((), jnp.int32), arg((), jnp.int32), arg((), jnp.int32))
-    compiled = lowered.compile()
+    compiled = latent_compiled(program)
     text = compiled.as_text()
     rows = "bf16[1,%d,%d,640]" % (LATENT_SLOTS, LATENT_MAX_LEN)
     assert rows + "{3,2,1,0" in text, "the latent rows are not row-major"
@@ -361,3 +372,45 @@ def test_latent_rows_are_stored_by_rows_and_never_copied(latent_engine,
     # what the program keeps beside its arguments: far below the buffer
     # that a layout copy would add (1.45 GB of temporaries with one, PR 31)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+def test_latent_chunk_reads_in_place_and_keeps_no_block_of_scores(
+        latent_compiled):
+    """A chunk's read of the selecting layer is one
+    ``latent_chunk_attention`` call on the stored rows.  As a loop in plain
+    ``jax.numpy`` each 512-row block's ``[128 heads, 1024, 512]`` float32
+    scores went to HBM and back four times, 1.07 GB and ~1.3 ms a block
+    where the products take a quarter of that (PERF.md §5, PR 31; §6,
+    PR 32): no float32 array of that size over the layer's heads is left
+    in any computation of the program, and no cut of the latent buffer
+    feeds the kernel."""
+    text = latent_compiled("prefill").as_text()
+    assert len(_kernel_calls(text, "latent_chunk_attention")) == 1
+    # every operand and the result in HBM: left to XLA:TPU, the
+    # long-document engine's second selecting layer was handed its 32 MB
+    # selection in VMEM (``S(1)`` in its layout) and kept its result there
+    # (up to 32 MB, a 512-row bucket), beside the kernel's own 56 MB, and
+    # the chip never came back from the warm-up (PERF.md §6, PR 32)
+    call = next(line for line in text.splitlines()
+                if re.match(r"\s*%latent_chunk_attention[\w.]* = ", line))
+    assert "S(1)" not in call.split(" = ")[1].split(" ")[0], call[:200]
+    for name in re.findall(r"%([\w.\-]+)", call.split("custom-call(")[1]
+                           .split(")")[0]):
+        made = next(line for line in text.splitlines()
+                    if re.match(r"\s*%%%s = " % re.escape(name), line))
+        assert "S(1)" not in made.split(" = ")[1].split(" ")[0], made[:200]
+    slab = LATENT_SLOTS * LATENT_MAX_LEN * 640
+    found = _slab_sized_cuts(text, slab) + _slab_sized_layout_copies(
+        text, slab)
+    assert not found, f"the chunk cuts or copies the latent buffer: {found}"
+    # (the window layer's [64, 1024, 1536] scores are ``_attend``'s: its
+    # own matter, PERF.md §7)
+    heads = 128
+    scores = heads * LATENT_CHUNK * 512
+    large = sorted({
+        dims for dims in re.findall(r"\bf32\[([\d,]+)\]", text)
+        if str(heads) in dims.split(",")
+        and math.prod(int(d) for d in dims.split(",")) >= scores})
+    assert not large, (
+        f"float32 arrays over the {heads} heads of a block's scores or more "
+        f"({scores} elements) in the prefill program: {large}")

@@ -65,6 +65,7 @@ PAGES = {
         "apex_tpu.ops.packed_update", "apex_tpu.ops.fused_lm_head",
         "apex_tpu.ops.pair_bias_attention",
         "apex_tpu.ops.cached_decode_attention",
+        "apex_tpu.ops.latent_chunk_attention",
     ]),
     "models": ("Model zoo", [
         "apex_tpu.models", "apex_tpu.models.llama",
@@ -574,7 +575,7 @@ layers reach it through the seam's functions and name no cache class:
 |---|---|---|---|
 | `KVRows(kv_heads, head_dim)` | `[max_len, kv_heads, head_dim]` K and V | `decode_attend` / `prefill_attend` | `models.llama`, `models.nemotron_h` (`*` layers) |
 | `RecurrentRows(ssm, conv)` | a float32 state and a convolution tail of fixed size | `slot_state` / `write_slot_state` / `write_lane_state` | `models.nemotron_h` (`M` layers) |
-| `LatentRows(width, index_width, top_k)` | `[max_len, width]` latent rows (the compressed K/V and the shared rope key, stored in whole lane tiles) and `[max_len, index_width]` selector keys | `latent_decode_attend` (append, score the live rows' keys, `top_k`, gather, absorbed read) / `latent_prefill_attend` (chunk-write, blocked scores, the selection as a mask, blocked explicit read) | `models.dots3` (`full_attention` layers) |
+| `LatentRows(width, index_width, top_k)` | `[max_len, width]` latent rows (the compressed K/V and the shared rope key, stored in whole lane tiles) and `[max_len, index_width]` selector keys | `latent_decode_attend` (append, score the live rows' keys, `top_k`, gather, absorbed read) / `latent_prefill_attend` (chunk-write, blocked scores, the selection as a mask, blocked explicit read: on a TPU one Pallas kernel, `ops.latent_chunk_attention`, over the rows in place with a block's scores in fast memory, elsewhere a loop; the `kernel_dispatch` event `latent_chunk_attention` says which) | `models.dots3` (`full_attention` layers) |
 | `RingRows(width, window)` | a ring of `window` rows in whole 16-row tiles, position `p` at row `p mod rows`, whatever `max_len` | `ring_decode_attend` / `ring_prefill_attend` | `models.dots3` (`sliding_attention` layers) |
 | `CallCounters(names)` | int32 counts a decode step adds (`engine.moe_stats()`) | `add_counts` | both routed-expert layers (`transformer.moe.LatentMoE`, `GatedMoE`) |
 

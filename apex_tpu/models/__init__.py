@@ -17,6 +17,10 @@ optimizers).  This package holds the production-shaped model definitions:
   attention in every layer (a learned top-k key selector on the full
   layers, a window on the others, a gate a head) and sigmoid-routed gated
   experts beside a shared expert.
+- :mod:`apex_tpu.models.mellum` — Mellum decoder, serving only:
+  grouped-query attention under a window of K/V rows on the
+  ``sliding_attention`` layers and at full extent under YaRN on the
+  ``full_attention`` ones, softmax-routed gated experts in every layer.
 """
 
 from apex_tpu.models.dots3 import Dots3NoteConfig, Dots3NoteForCausalLM
@@ -28,10 +32,12 @@ from apex_tpu.models.llama_pipeline import (
     init_llama_pipeline_params,
     make_llama_3d_train_step,
 )
+from apex_tpu.models.mellum import MellumConfig, MellumForCausalLM
 from apex_tpu.models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
 from apex_tpu.models.vit import ViTConfig, ViTForImageClassification
 
 __all__ = ["Dots3NoteConfig", "Dots3NoteForCausalLM", "LlamaConfig", "LlamaForCausalLM", "LlamaPipeConfig",
            "build_llama_pipeline", "init_llama_pipeline_params",
-           "make_llama_3d_train_step", "NemotronHConfig",
+           "make_llama_3d_train_step", "MellumConfig", "MellumForCausalLM",
+           "NemotronHConfig",
            "NemotronHForCausalLM", "ViTConfig", "ViTForImageClassification"]

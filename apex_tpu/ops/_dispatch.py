@@ -74,6 +74,17 @@ def record_dispatch(op: str, shape_ok: bool, **shape) -> bool:
     return bool(shape_ok)
 
 
+def record_choice(op: str, path: str, **shape) -> None:
+    """A call site's choice between two reads that are both plain
+    ``jax.numpy``, decided from the shapes in hand (``path`` names the one
+    taken): a ``read_dispatch`` event, emitted where and when
+    :func:`record_dispatch` emits its own - once per trace, and only with
+    kernels enabled, so that a default CPU run's event stream is what it
+    was."""
+    if kernels_enabled():
+        emit_event("read_dispatch", op=op, path=path, **shape)
+
+
 def lane_aligned(*dims: int, lane: int = 128) -> bool:
     """TPU kernels want the trailing dim to be a multiple of the lane width."""
     return all(d % lane == 0 for d in dims)

@@ -22,14 +22,50 @@ through (matching the CUDA kernels' d2 < d handling).
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 __all__ = [
+    "yarn_inv_freq",
     "fused_apply_rotary_pos_emb",
     "fused_apply_rotary_pos_emb_cached",
     "fused_apply_rotary_pos_emb_thd",
     "fused_apply_rotary_pos_emb_2d",
 ]
+
+
+def yarn_inv_freq(dim: int, theta: float, *, factor: float,
+                  original_max_position_embeddings: int, beta_fast: float,
+                  beta_slow: float):
+    """The ``dim // 2`` rotary frequencies of a head under YaRN (Peng et
+    al. 2023, "NTK-by-parts"; the ``rope_type`` ``yarn`` of published
+    configs), float32::
+
+        extra_i = theta^(-2i/dim)             the trained frequencies
+        inter_i = extra_i / factor            the same, positions interpolated
+        dim(r) = dim ln(original / (2 pi r)) / (2 ln theta)
+        low = floor(dim(beta_fast)),  high = ceil(dim(beta_slow))
+        ramp_i = clip((i - low) / (high - low), 0, 1)
+        inv_freq_i = inter_i ramp_i + extra_i (1 - ramp_i)
+
+    ``dim(r)`` is the channel pair that turns ``r`` times over the original
+    context: pairs that turn more than ``beta_fast`` times keep their
+    frequency, pairs that turn less than ``beta_slow`` times are
+    interpolated, the ramp between.  The ``attention_factor`` that goes with
+    it (``0.1 ln(factor) + 1``) multiplies cos and sin and is the caller's."""
+
+    def pair_of(turns: float) -> float:
+        return (dim * math.log(original_max_position_embeddings
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    pair = jnp.arange(dim // 2, dtype=jnp.float32)
+    extra = 1.0 / theta ** (2 * pair / dim)
+    ramp = jnp.clip((pair - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
 
 
 def _rotate_half(x):

@@ -324,8 +324,9 @@ class DecodeEngine:
         self.model = model
         self.params = params
         self.slots = int(slots)
-        # a model declares what each layer keeps a slot (K/V rows, a
-        # recurrent state, latent rows, a window ring, counters) and the
+        # a model declares what each layer keeps a slot (K/V rows, a ring
+        # of window K/V rows, a recurrent state, latent rows, a window ring,
+        # counters) and the
         # cache is built from that.  What pages, shards, quantizes, copies or
         # rolls back K/V rows knows nothing of the others, so for a model
         # that keeps them each such mechanism is refused by name through
@@ -343,8 +344,8 @@ class DecodeEngine:
             if given:
                 self.refuse_other_state(what)
         # the layers that say what a decode step reads of their rows
-        # (``rows_read``: a latent-attention model's), for the engine.decode
-        # span's counts and their running sums
+        # (``rows_read``: a latent-attention model's, a window layer's), for
+        # the engine.decode span's counts and their running sums
         self._reading = [l for l in self._layers if hasattr(l, "rows_read")]
         self._rows_read: dict = {}
         # opt-in tensor parallelism: validate the head/vocab split up
@@ -763,8 +764,9 @@ class DecodeEngine:
         """What a decode step reads over the active lanes, the row it appends
         among them, summed over the layers that declare it
         (``LatentRows.rows_read``: ``index_rows``, ``attended_rows``;
-        ``RingRows.rows_read``: ``window_rows``), from the host mirror: no
-        readback.  Empty for a model that keeps K/V rows."""
+        ``RingRows.rows_read``: ``window_rows``; ``KVWindowRows.rows_read``:
+        ``window_rows``, ``window_live_rows``), from the host mirror: no
+        readback.  Empty for a model that keeps K/V rows alone."""
         out: dict = {}
         if self._reading:
             live = self._lengths_host[act] + 1
@@ -776,7 +778,7 @@ class DecodeEngine:
     def rows_read(self) -> dict:
         """:meth:`_count_rows` summed over every decode step so far (what
         each ``engine.decode`` span carries as attributes, for a run that
-        records no spans); empty for a model that keeps K/V rows."""
+        records no spans); empty for a model that keeps K/V rows alone."""
         return dict(self._rows_read)
 
     def moe_stats(self) -> dict:

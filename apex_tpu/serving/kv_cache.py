@@ -45,17 +45,19 @@ twin, and one seam a model's attention calls through:
   view a read takes, and the positions a decode step appends at - and
   nothing about int8.
 - **What a model keeps a slot** - ``model.cache_layers()``: one of
-  :class:`KVRows`, :class:`RecurrentRows`, :class:`LatentRows`,
-  :class:`RingRows`, :class:`CallCounters` or None a layer;
-  :func:`init_cache` builds every cache from that.
+  :class:`KVRows`, :class:`KVWindowRows`, :class:`RecurrentRows`,
+  :class:`LatentRows`, :class:`RingRows`, :class:`CallCounters` or None a
+  layer; :func:`init_cache` builds every cache from that.
 - **The seam**: :func:`decode_attend` and :func:`prefill_attend` are the
   whole step an attention layer needs - append or chunk-write, view, cast
   to the query's dtype, the grouped masked read
   (:func:`cached_attention`) - whatever the layout and the format.  A
-  model imports those two (for a recurrent layer the state functions, for
-  a latent-attention layer the latent pair :func:`latent_decode_attend` /
-  :func:`latent_prefill_attend` and their window twins, at the end of this
-  module) and names no cache class.
+  model imports those two (for a layer that sees a window of K/V rows
+  their window pair :func:`window_decode_attend` /
+  :func:`window_prefill_attend`, for a recurrent layer the state functions,
+  for a latent-attention layer the latent pair :func:`latent_decode_attend`
+  / :func:`latent_prefill_attend` and their window twins, at the end of
+  this module) and names no cache class.
 
 Masking exactness: masked attention scores sit at ``-1e30`` (the flash
 kernels' ``_NEG_INF``), so ``exp(masked - max)`` underflows to exactly
@@ -73,14 +75,17 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from apex_tpu.amp.quant import dequantize_int8, quantize_int8
-from apex_tpu.ops._dispatch import record_dispatch
+from apex_tpu.ops._dispatch import record_choice, record_dispatch
 from apex_tpu.ops.cached_decode_attention import (
     block_rows,
     cached_decode_attention,
 )
 from apex_tpu.ops.flash_attention import _NEG_INF
+from apex_tpu.ops.kv_chunk_attention import kernel_takes as chunk_kernel_takes
+from apex_tpu.ops.kv_chunk_attention import kv_chunk_attention
 from apex_tpu.ops.latent_chunk_attention import (
     kernel_takes,
     latent_chunk_attention,
@@ -96,7 +101,9 @@ __all__ = ["KVCache", "QuantKVCache", "FloatRows", "Int8Rows", "DenseLayout",
            "RecurrentState", "HybridCache", "slot_state", "write_slot_state",
            "write_lane_state", "add_counts", "LatentRows", "RingRows",
            "LatentCache", "latent_decode_attend", "latent_prefill_attend",
-           "ring_decode_attend", "ring_prefill_attend", "other_state"]
+           "ring_decode_attend", "ring_prefill_attend", "other_state",
+           "KVWindowRows", "WindowKVCache", "window_decode_attend",
+           "window_prefill_attend"]
 
 
 # ---- storage format: how a row is stored -----------------------------------
@@ -616,20 +623,55 @@ def decode_attend(cache, layer: int, q, k, v, position):
     return decode_attention(qt, kc, vc, position), cache
 
 
+# the float32 scores of one full-extent chunk read, ``heads x chunk x max_len
+# x 4`` bytes, up to which :func:`prefill_attend` attends the whole extent:
+# 128 MiB, what 32 heads x 512 rows x 2,048 rows come to.  At 32,768 rows
+# every bucket from 64 rows up walks (4 GiB at 32 x 1,024)
+_FULL_READ_BYTES = 1 << 27
+
+
+def _walks_blocks(cache, q) -> bool:
+    """Whether a chunk's read walks the visible blocks of the slot's rows
+    (:func:`_chunk_walk`: work in proportion to ``offset + length``, one
+    block's scores at a time) or attends the whole masked ``max_len`` extent
+    (:func:`cached_attention`) - decided by what is in hand: float rows a
+    slot owns in the stored buffers, in whole blocks, whose full-extent
+    float32 scores would pass :data:`_FULL_READ_BYTES`.  The
+    ``read_dispatch`` event says which."""
+    s, heads = q.shape[0], q.shape[2]
+    scores = 4 * heads * s * cache.max_len
+    block = _key_block(cache.max_len)
+    walks = bool(cache.lane_rows_in_place
+                 and jnp.issubdtype(cache.k.dtype, jnp.floating)
+                 and cache.max_len % block == 0
+                 and scores > _FULL_READ_BYTES)
+    record_choice("prefill_attend", "blocked_walk" if walks else
+                  "full_extent", heads=heads, chunk=s, max_len=cache.max_len,
+                  score_bytes=scores)
+    return walks
+
+
 def prefill_attend(cache, layer: int, slot, q, k, v, offset):
     """One prompt chunk (or speculative verify) of one attention layer:
-    write the chunk's K/V into ``slot`` at ``offset``, then attend over
-    the whole masked cache — the chunk's own rows AND every previously
-    cached token go through one fixed-extent read under per-row bounds
-    (``idx <= offset + row``), so splitting a prompt into chunks never
-    changes any bit.  ``q`` ``[s, 1, heads, hd]``, ``k`` / ``v`` ``[s, 1,
-    kv_heads, hd]``.  Returns ``(ctx [1, heads, s, hd], cache)``."""
+    write the chunk's K/V into ``slot`` at ``offset``, then attend the
+    slot's rows - the chunk's own AND every previously cached token under
+    per-row bounds (``idx <= offset + row``).  ``q`` ``[s, 1, heads, hd]``,
+    ``k`` / ``v`` ``[s, 1, kv_heads, hd]``.  Returns ``(ctx [1, heads, s,
+    hd], cache)``.
+
+    One read, two implementations (:func:`_walks_blocks` chooses).  Where
+    the scores of the whole extent are small, one fixed-extent masked read
+    (:func:`cached_attention`), so splitting a prompt into chunks never
+    changes any bit; at long extents the blocked walk (:func:`_chunk_walk`),
+    which stops at the chunk's end and rounds a block at a time."""
     s, b = q.shape[:2]
     if b != 1:
         raise ValueError(
             f"prefill expects one slot per call (b=1), got b={b}")
     cache = prefill_into_slot(cache, layer, slot, k[:, 0], v[:, 0],
                               start=offset)
+    if _walks_blocks(cache, q):
+        return _chunk_walk(cache, layer, slot, q[:, 0], offset)[None], cache
     kc, vc = slot_read(cache, layer, slot)
     kc = kc.astype(q.dtype)                         # [max, kv_heads, hd]
     vc = vc.astype(q.dtype)
@@ -638,12 +680,103 @@ def prefill_attend(cache, layer: int, slot, q, k, v, offset):
     return cached_attention(qt, kc[None], vc[None], bounds), cache
 
 
+def _chunk_walk(cache, layer: int, slot, q, offset):
+    """The blocked walk of :func:`prefill_attend`: ``q [s, heads, hd]`` over
+    the visible blocks of ``slot``'s rows; ``[heads, s, hd]`` in ``q``'s
+    dtype.  One recurrence, two implementations, chosen as
+    :func:`_reads_in_place` chooses and said by the ``kernel_dispatch`` event
+    ``kv_chunk_attention``: on a TPU, rows in the queries' own float dtype
+    with a head and a block in whole lane tiles go through the Pallas kernel
+    (:func:`~apex_tpu.ops.kv_chunk_attention.kv_chunk_attention`), which
+    keeps a block's scores in fast memory and takes the slot's rows
+    head-major - cut out of the stored layout here, once a call -;
+    everything else through the loop :func:`_kv_chunk_read`."""
+    s, heads, hd = q.shape
+    max_len = cache.max_len
+    block = _key_block(max_len)
+    shape = dict(m=s, hd=hd, block=block, max_len=max_len)
+    if not record_dispatch(
+            "kv_chunk_attention",
+            cache.k.dtype == q.dtype and chunk_kernel_takes(**shape),
+            heads=heads, kv_heads=cache.k.shape[-2], **shape):
+        return _kv_chunk_read(q.transpose(1, 0, 2), cache.k, cache.v, layer,
+                              slot, offset)
+    slot = jnp.asarray(slot, jnp.int32)
+    offset = jnp.asarray(offset, jnp.int32)
+    # the slot's rows pinned to the layout they are stored in, then turned
+    # head-major: left free, XLA:TPU turns the WHOLE stacked buffer instead,
+    # once for all its layers - 1.07 GB each way for K and for V a chunk
+    # where the slot's rows are 33 MB (compiled for a described v5e and
+    # measured: 6 ms of a 40 ms chunk; PERF.md section 6, PR 33).  A fence
+    # (``optimization_barrier``) does not hold it: the layout of the cut
+    # is assigned through it
+    stored = Layout(major_to_minor=(0, 1, 2))
+    kt, vt = (with_layout_constraint(lax.dynamic_slice(
+        buf, (layer, slot, 0, 0, 0), (1, 1) + buf.shape[2:])[0, 0],
+        stored).transpose(1, 0, 2)
+        for buf in (cache.k, cache.v))                # [kv_heads, max, hd]
+    blocks = jnp.minimum((offset + s - 1) // block + 1, max_len // block)
+    ctx = kv_chunk_attention(q, kt, vt, offset, blocks, block=block)
+    return ctx.astype(q.dtype).transpose(1, 0, 2)
+
+
+def _kv_chunk_read(qt, k, v, layer: int, slot, offset):
+    """The chunk read of :func:`prefill_attend` as a walk in plain
+    ``jax.numpy``: the flash recurrence - running max, sum and values - over
+    the blocks of ``k`` / ``v`` ``[layers, slots, max_len, kv_heads, hd]``
+    at ``[layer, slot]`` that hold a row the chunk sees, ``idx <= offset +
+    row``, and no further: its work follows ``offset + s``, never
+    ``max_len``.  ``qt [heads, s, hd]``; returns ``[heads, s, hd]`` in its
+    dtype.  Grouped as :func:`cached_attention` groups: query head ``j``
+    reads KV head ``j // rep`` on the stored layout, operands in the rows'
+    dtype, sums float32.  Rows past the chunk's end are garbage by
+    contract: masked out of the scores and zeroed in V (``0 * nan`` is not
+    ``0``)."""
+    heads, s, hd = qt.shape
+    max_len, nkv = k.shape[2], k.shape[3]
+    rep = heads // nkv
+    block = _key_block(max_len)
+    slot = jnp.asarray(slot, jnp.int32)
+    offset = jnp.asarray(offset, jnp.int32)
+    qg = (qt.astype(jnp.float32) * (1.0 / hd ** 0.5)).astype(k.dtype)
+    qg = qg.reshape(nkv, rep * s, hd)
+    # a KV head's query rows are (head of the group, row of the chunk)
+    bound = jnp.tile(offset + jnp.arange(s, dtype=jnp.int32), rep)
+
+    def read(i, carry):
+        top, total, acc = carry
+        kb, vb = (lax.dynamic_slice(
+            buf, (layer, slot, i * block, 0, 0),
+            (1, 1, block, nkv, hd))[0, 0] for buf in (k, v))
+        idx = i * block + jnp.arange(block, dtype=jnp.int32)
+        sc = jnp.einsum("grd,ngd->grn", qg, kb,
+                        preferred_element_type=jnp.float32)
+        sc = jnp.where(idx[None, None] <= bound[None, :, None], sc, _NEG_INF)
+        new_top = jnp.maximum(top, sc.max(-1))
+        e = jnp.exp(sc - new_top[..., None])
+        keep = jnp.exp(top - new_top)
+        vb = jnp.where((idx < offset + s)[:, None, None], vb,
+                       jnp.zeros_like(vb))
+        acc = acc * keep[..., None] + jnp.einsum(
+            "grn,ngd->grd", e.astype(vb.dtype), vb,
+            preferred_element_type=jnp.float32)
+        return new_top, total * keep + e.sum(-1), acc
+
+    blocks = jnp.minimum((offset + s - 1) // block + 1, max_len // block)
+    _, total, acc = lax.fori_loop(
+        0, blocks, read,
+        (jnp.full((nkv, rep * s), _NEG_INF, jnp.float32),
+         jnp.zeros((nkv, rep * s), jnp.float32),
+         jnp.zeros((nkv, rep * s, hd), jnp.float32)))
+    return (acc / total[..., None]).reshape(heads, s, hd).astype(qt.dtype)
+
+
 # ---- what a model's layers keep a slot --------------------------------------
 #
 # A model declares, layer by layer, what a slot keeps between calls
-# (``model.cache_layers()``: one of the three declarations below, or None, a
-# layer): K/V rows that grow with the sequence, a recurrent state of fixed
-# size, or counters the layer adds to a call.  :func:`init_cache` builds ONE
+# (``model.cache_layers()``: one of the declarations below, or None, a
+# layer): K/V rows that grow with the sequence, a ring of the last K/V rows, a
+# recurrent state of fixed size, or counters the layer adds to a call.  :func:`init_cache` builds ONE
 # pytree from the declarations; its ``k`` / ``v`` hold the K/V layers only, in
 # declaration order, and the model owns the map from its layer index to the
 # index on each leading axis.
@@ -660,6 +793,29 @@ class KVRows:
 
 # rows of a window ring come in whole sublane tiles of the stored type
 RING_ROWS = 16
+
+
+@dataclasses.dataclass(frozen=True)
+class KVWindowRows:
+    """A layer whose queries see ``window`` positions, their own among them:
+    a ring of ``rows`` K and V rows a slot (``[rows, kv_heads, head_dim]``
+    each), position ``p`` at row ``p mod rows``, whatever ``max_len`` is."""
+
+    kv_heads: int
+    head_dim: int
+    window: int
+    what = "a ring of window K/V rows"
+
+    @property
+    def rows(self) -> int:
+        return -(-self.window // RING_ROWS) * RING_ROWS
+
+    def rows_read(self, live) -> dict:
+        """As :meth:`RingRows.rows_read`: the window's rows of each active
+        lane's ``live`` rows, and beside them the live rows themselves: what
+        a read at full extent would walk."""
+        return {"window_rows": int(live.clip(max=self.window).sum()),
+                "window_live_rows": int(live.sum())}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -774,6 +930,24 @@ class HybridCache(KVCache):
     counters: jax.Array
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("k", "v", "lengths", "ring_k", "ring_v",
+                                "counters"), meta_fields=())
+@dataclasses.dataclass(frozen=True)
+class WindowKVCache(KVCache):
+    """A :class:`KVCache` over the layers that declared :class:`KVRows`,
+    plus ``ring_k`` / ``ring_v [window layers, slots, rows, kv_heads,
+    head_dim]`` for those that declared :class:`KVWindowRows` (position
+    ``p`` at row ``p mod rows``: a slot keeps a window, whatever
+    ``max_len``) and ``counters`` as :class:`HybridCache` has them.
+    ``lengths`` counts a slot's tokens for every kind of layer alike: a
+    ring row holds position ``p`` only if ``p`` is below it."""
+
+    ring_k: jax.Array
+    ring_v: jax.Array
+    counters: jax.Array
+
+
 def _one_shape(layers, kind, what: str):
     found = {l for l in layers if isinstance(l, kind)}
     if len(found) > 1:
@@ -787,22 +961,41 @@ def _one_shape(layers, kind, what: str):
 def init_cache(layers, *, slots: int, max_len: int, dtype=jnp.float32,
                int8: bool = False, paged=None):
     """Zero-filled cache for a model's per-layer declarations (``layers``:
-    ``model.cache_layers()`` — a :class:`KVRows`, :class:`RecurrentRows`,
-    :class:`LatentRows`, :class:`RingRows`, :class:`CallCounters` or None a
-    layer), in the layout and the storage
+    ``model.cache_layers()`` — a :class:`KVRows`, :class:`KVWindowRows`,
+    :class:`RecurrentRows`, :class:`LatentRows`, :class:`RingRows`,
+    :class:`CallCounters` or None a layer), in the layout and the storage
     format asked for: dense slot rows, or the block pool of ``paged`` (a
     :class:`~apex_tpu.serving.paged_kv_cache.PagedCacheConfig`); floats of
     ``dtype``, or :class:`Int8Rows` with ``int8``.
 
     Layers that keep K/V rows alone give a :class:`KVCache` /
     :class:`QuantKVCache` (or the paged pair); a recurrent state or
-    counters give a :class:`HybridCache`, latent rows or window rings a
-    :class:`LatentCache`: both dense floats only."""
+    counters give a :class:`HybridCache`, rings of window K/V rows (beside
+    K/V rows or alone) a :class:`WindowKVCache`, latent rows or their window
+    rings a :class:`LatentCache`: all three dense floats only."""
     kv, n_kv = _one_shape(layers, KVRows, "K/V rows")
     rec, n_rec = _one_shape(layers, RecurrentRows, "recurrent states")
     cnt, n_cnt = _one_shape(layers, CallCounters, "counters")
     lat, n_lat = _one_shape(layers, LatentRows, "latent rows")
     ring, n_ring = _one_shape(layers, RingRows, "window rings")
+    win, n_win = _one_shape(layers, KVWindowRows, "K/V window rings")
+    if win:
+        if rec or lat or ring or paged is not None or int8:
+            raise ValueError(
+                "rings of window K/V rows are dense floats beside K/V rows "
+                "and counters: no block table, no int8 rows, no recurrent "
+                "state and no latent rows beside them")
+        kv = kv or KVRows(win.kv_heads, win.head_dim)
+        return WindowKVCache(
+            **KVCache.zeros((n_kv, slots, max_len, kv.kv_heads, kv.head_dim),
+                            dtype),
+            lengths=jnp.zeros((slots,), jnp.int32),
+            ring_k=jnp.zeros((n_win, slots, win.rows, win.kv_heads,
+                              win.head_dim), dtype),
+            ring_v=jnp.zeros((n_win, slots, win.rows, win.kv_heads,
+                              win.head_dim), dtype),
+            counters=jnp.zeros((n_cnt, len(cnt.names) if cnt else 0),
+                               jnp.int32))
     if lat or ring:
         if kv or rec or paged is not None or int8:
             raise ValueError(
@@ -892,6 +1085,148 @@ def add_counts(cache, layer: int, counts):
     """Add one call's counts to a counting layer's row."""
     return dataclasses.replace(
         cache, counters=cache.counters.at[layer].add(counts))
+
+
+# ---- a window of K/V rows: the window pair of the seam ----------------------
+#
+# A layer whose queries see the last ``window`` positions keeps a ring of that
+# many K and V rows a slot: rope went into K before the store and a softmax
+# does not care in which order its rows lie, so a read needs to know which
+# ring rows hold a position in the window, never where the window starts.
+
+
+def _masked_read(qt, kc, vc, seen):
+    """Masked grouped softmax read, all keys at once: ``qt [b, h, m, hd]``
+    over ``kc`` / ``vc [b, n, kv_heads, hd]`` under ``seen [b, m, n]``;
+    returns ``[b, h, m, hd]`` in ``qt``'s dtype.  :func:`cached_attention`'s
+    arithmetic (query head ``j`` reads KV head ``j // rep`` on the stored
+    layout, the scale folded into ``q``, operands in the rows' dtype, sums
+    float32) under a mask that is no bound.  Every row must see a key; a key
+    no row sees is garbage by contract and is zeroed in V (``0 * nan`` is not
+    ``0``)."""
+    b, h, m, hd = qt.shape
+    n, nkv = kc.shape[1], kc.shape[2]
+    rep = h // nkv
+    qg = (qt.astype(jnp.float32) * (1.0 / hd ** 0.5)).astype(kc.dtype)
+    qg = qg.reshape(b, nkv, rep * m, hd)
+    s = jnp.einsum("bgrd,blgd->bgrl", qg, kc,
+                   preferred_element_type=jnp.float32).reshape(b, h, m, n)
+    s = jnp.where(seen[:, None], s, _NEG_INF)
+    e = jnp.exp(s - s.max(-1, keepdims=True))
+    p = (e / e.sum(-1, keepdims=True)).astype(vc.dtype)
+    vc = jnp.where(seen.any(1)[:, :, None, None], vc, jnp.zeros_like(vc))
+    out = jnp.einsum("bgrl,blgd->bgrd", p.reshape(b, nkv, rep * m, n), vc,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, h, m, hd).astype(qt.dtype)
+
+
+def _ring_reads_in_place(cache, q, window: int) -> bool:
+    """Whether a window layer's decode read is the Pallas kernel of
+    :func:`decode_attend` on the ring buffers or :func:`_masked_read` over
+    the ring under a mask - decided as :func:`_reads_in_place` decides, with
+    one more condition: a ring of exactly ``window`` rows (after the append
+    every row a bound admits is then in the window; a ring rounded up to
+    whole tiles also holds rows that have left it).  The
+    ``kernel_dispatch`` event says which."""
+    heads, hd = q.shape[2], q.shape[3]
+    ring, nkv = cache.ring_k.shape[2], cache.ring_k.shape[3]
+    block = block_rows(ring, nkv)
+    return record_dispatch(
+        "cached_decode_attention",
+        ring == window and cache.ring_k.dtype == q.dtype and hd % 128 == 0
+        and ring % block == 0 and block % 8 == 0,
+        kv_heads=nkv, rep=heads // nkv, hd=hd, max_len=ring, block=block,
+        window=window)
+
+
+def window_decode_attend(cache, layer: int, q, k, v, position, *,
+                         window: int):
+    """:func:`decode_attend` for a layer that sees ``window`` positions: put
+    each lane's new K/V row at ring row ``position mod rows``, then attend
+    the rows of positions ``position - window < p <= position``.  Shapes as
+    :func:`decode_attend`; ``layer`` counts the window layers.
+
+    One read, two implementations (:func:`_ring_reads_in_place` chooses):
+    the kernel that reads a dense cache in place, handed the ring buffers
+    and the bound ``min(position, rows - 1)``, or the ring as it lies under
+    the mask of the rows that hold a position in the window.  Either way a
+    step reads at most the ring, never ``max_len`` rows."""
+    position = jnp.asarray(position, jnp.int32)
+    ring = cache.ring_k.shape[2]
+    at = (layer, jnp.arange(position.shape[0], dtype=jnp.int32),
+          position % ring)
+    cache = dataclasses.replace(
+        cache,
+        ring_k=cache.ring_k.at[at].set(k[0].astype(cache.ring_k.dtype)),
+        ring_v=cache.ring_v.at[at].set(v[0].astype(cache.ring_v.dtype)))
+    qt = q.transpose(1, 2, 0, 3)                    # [lanes, heads, 1, hd]
+    if _ring_reads_in_place(cache, q, window):
+        return cached_decode_attention(
+            qt, cache.ring_k, cache.ring_v, layer,
+            jnp.minimum(position, ring - 1)), cache
+    # ring row r holds the last position <= position that is r mod rows
+    row = jnp.arange(ring, dtype=jnp.int32)
+    held = position[:, None] - (position[:, None] - row[None]) % ring
+    seen = (held >= 0) & (held > position[:, None] - window)
+    return _masked_read(qt, cache.ring_k[layer].astype(q.dtype),
+                        cache.ring_v[layer].astype(q.dtype),
+                        seen[:, None]), cache
+
+
+def window_prefill_attend(cache, layer: int, slot, q, k, v, offset, length,
+                          *, window: int):
+    """:func:`prefill_attend` for a layer that sees ``window`` positions:
+    the chunk's queries read the ``window - 1`` rows before the chunk from
+    the ring and the chunk's own rows from the ones in hand, under ``offset
+    + row - window < p <= offset + row``; then the chunk's last real rows
+    (``length`` of them are real) go into the ring.  Shapes as
+    :func:`prefill_attend`; returns ``(ctx [1, heads, s, hd], cache)``."""
+    s, b = q.shape[:2]
+    if b != 1:
+        raise ValueError(
+            f"prefill expects one slot per call (b=1), got b={b}")
+    slot = jnp.asarray(slot, jnp.int32)
+    offset = jnp.asarray(offset, jnp.int32)
+    ring = cache.ring_k.shape[2]
+    before = -(-(window - 1) // 8) * 8
+    p_before = offset - before + jnp.arange(before, dtype=jnp.int32)
+    mine = offset + jnp.arange(s, dtype=jnp.int32)
+    kc, vc = (jnp.concatenate(
+        [buf[layer, slot, p_before % ring].astype(q.dtype), rows[:, 0]])
+        for buf, rows in ((cache.ring_k, k), (cache.ring_v, v)))
+    heads, hd = q.shape[2:]
+    block = min(512, -(-(before + s) // 128) * 128)
+    shape = dict(m=s, hd=hd, block=block, max_len=block)
+    if record_dispatch("kv_chunk_attention", chunk_kernel_takes(**shape),
+                       heads=heads, kv_heads=k.shape[2], window=window,
+                       **shape):
+        # the same walk as a full layer's chunk, over this short extent in
+        # whole blocks: key j holds position offset - before + j
+        pad = -(before + s) % block
+        kt, vt = (jnp.pad(rows, ((0, pad), (0, 0), (0, 0))).transpose(1, 0, 2)
+                  for rows in (kc, vc))
+        ctx = kv_chunk_attention(
+            q[:, 0], kt, vt, before, (before + s + pad) // block,
+            block=block, window=window,
+            first=jnp.maximum(before - offset, 0))
+        ctx = ctx.astype(q.dtype).transpose(1, 0, 2)[None]
+    else:
+        at = jnp.concatenate([p_before, mine])
+        seen = ((at[None] >= 0) & (at[None] <= mine[:, None])
+                & (at[None] > mine[:, None] - window))
+        ctx = _masked_read(q.transpose(1, 2, 0, 3), kc[None], vc[None],
+                           seen[None])
+    # only real rows, and of more than a ring's worth only the last: one
+    # scatter writes no ring row twice
+    n = jnp.arange(s, dtype=jnp.int32)
+    keep = (n < length) & (n >= length - ring)
+    to = (layer, slot, jnp.where(keep, mine % ring, ring))
+    return ctx, dataclasses.replace(
+        cache,
+        ring_k=cache.ring_k.at[to].set(k[:, 0].astype(cache.ring_k.dtype),
+                                       mode="drop"),
+        ring_v=cache.ring_v.at[to].set(v[:, 0].astype(cache.ring_v.dtype),
+                                       mode="drop"))
 
 
 # ---- latent rows: what a latent-attention layer keeps, and its seam --------

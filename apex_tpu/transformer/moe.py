@@ -39,7 +39,8 @@ from apex_tpu.ops._dispatch import (
 )
 
 __all__ = ["ExpertParallelMLP", "top1_dispatch", "LatentMoE", "GatedMoE",
-           "topk_sigmoid_route", "grouped_matmul", "held_pairs",
+           "topk_sigmoid_route", "topk_softmax_route", "grouped_matmul",
+           "held_pairs",
            "MOE_COUNTERS"]
 
 
@@ -175,6 +176,19 @@ def topk_sigmoid_route(x, kernel, bias, top_k: int, scale: float):
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
     return chosen.astype(jnp.int32), weights
+
+
+def topk_softmax_route(x, kernel, top_k: int):
+    """Softmax-scored top-k routing without a selection bias (Mixtral /
+    Qwen-MoE with ``norm_topk_prob``): ``p = softmax(x W_r)`` in float32 over
+    every published expert, the ``top_k`` largest ``p`` chosen, weights the
+    chosen ``p`` normalised to sum 1.  Returns ``(chosen [t, top_k] int32,
+    weights [t, top_k] float32)``."""
+    probs = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), kernel.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    picked, chosen = jax.lax.top_k(probs, top_k)
+    return chosen.astype(jnp.int32), picked / picked.sum(-1, keepdims=True)
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -347,11 +361,13 @@ class LatentMoE(nn.Module):
 class GatedMoE(nn.Module):
     """One chip's share of a routed-expert layer of gated (SwiGLU) experts
     that read the hidden vector (DeepSeek-V3's form), plus the shared expert
-    of the same shape.
+    of the same shape where ``shared_width`` is not 0.
 
     As :class:`LatentMoE`: the router is ``num_experts`` wide and keeps the
-    published ``top_k`` and weights (the chosen scores normalised over all
-    ``top_k``, times ``routed_scaling_factor``); this layer holds the
+    published ``top_k`` and weights - by ``scoring``, ``"sigmoid"`` with a
+    selection bias (:func:`topk_sigmoid_route`: the chosen scores normalised
+    over all ``top_k``, times ``routed_scaling_factor``) or ``"softmax"``
+    without one (:func:`topk_softmax_route`); this layer holds the
     experts ``[experts_held[0], experts_held[0] + experts_held[1])`` and
     computes ``sum over chosen e held here of w_e W_down_e (silu(W_gate_e x)
     * W_up_e x)`` through :func:`held_pairs` and three grouped products;
@@ -366,6 +382,7 @@ class GatedMoE(nn.Module):
     shared_width: int
     routed_scaling_factor: float = 1.0
     param_dtype: Any = jnp.float32
+    scoring: str = "sigmoid"
 
     @nn.compact
     @jax.named_scope("gated_moe")
@@ -379,19 +396,27 @@ class GatedMoE(nn.Module):
                             param_dtype=self.param_dtype, kernel_init=normal,
                             name=name)
 
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {self.scoring!r}: 'sigmoid' or "
+                             f"'softmax'")
         router_kernel = self.param("router_kernel", normal,
                                    (h, self.num_experts), jnp.float32)
-        router_bias = self.param("router_bias", nn.initializers.zeros,
-                                 (self.num_experts,), jnp.float32)
+        if self.scoring == "sigmoid":
+            router_bias = self.param("router_bias", nn.initializers.zeros,
+                                     (self.num_experts,), jnp.float32)
         w_gate, w_up = (self.param(name, normal, (held, h, self.expert_width),
                                    self.param_dtype)
                         for name in ("experts_gate", "experts_up"))
         w_down = self.param("experts_down", normal,
                             (held, self.expert_width, h), self.param_dtype)
 
-        chosen, weights = topk_sigmoid_route(
-            x, router_kernel, router_bias, self.top_k,
-            self.routed_scaling_factor)
+        if self.scoring == "sigmoid":
+            chosen, weights = topk_sigmoid_route(
+                x, router_kernel, router_bias, self.top_k,
+                self.routed_scaling_factor)
+        else:
+            chosen, weights = topk_softmax_route(x, router_kernel,
+                                                 self.top_k)
         pairs = held_pairs(chosen, self.experts_held, valid)
         rows = x[pairs.token_of]                              # [t k, hidden]
         gate = grouped_matmul(rows, w_gate.astype(x.dtype), pairs.sizes)
@@ -399,6 +424,8 @@ class GatedMoE(nn.Module):
         hid = (jax.nn.silu(gate) * up).astype(x.dtype)
         out = grouped_matmul(hid, w_down.astype(x.dtype), pairs.sizes)
         routed = pairs.combine(out, weights).astype(x.dtype)
+        if not self.shared_width:
+            return routed, pairs.counts
 
         shared = (jax.nn.silu(dense("shared_gate", self.shared_width)(x))
                   * dense("shared_up", self.shared_width)(x))

@@ -10,6 +10,7 @@ whose counters exactly match the injected fault / request counts plus a
 loadable Chrome trace, ending with the no-exporter overhead budget.
 """
 
+import collections
 import json
 import logging
 import math
@@ -1029,44 +1030,62 @@ def test_acceptance_serving_drain_metrics_match_request_counts(events,
 # overhead: instrumentation must be negligible with no exporter attached
 # --------------------------------------------------------------------------
 
-def test_instrumented_step_overhead_is_bounded():
+def test_instrumented_step_overhead_is_bounded(monkeypatch):
     """Full per-step instrumentation (span with no recorder + histogram
-    observe + counter inc) on a ~100 µs CPU step must stay within a
-    small multiple of the bare step.  Best-of-5 timings to shrug off
-    scheduler noise, bare and instrumented turn about so that a burst of
-    load from the other test workers falls on both; at ~7 µs of measured
-    instrumentation the 3x bar leaves ~30x headroom against the ~100 µs
-    step."""
+    observe + counter inc) stays what the default path promises, as a count
+    of what a step does and not as a time (a 3x wall-clock bar on a ~100 us
+    step went red whenever the other test workers took the cores): with no
+    recorder installed and no profiler session a step allocates no span
+    record and no profiler annotation, sets no current span, and makes one
+    ``observe`` and one ``inc``."""
     reg = MetricsRegistry()
     hist = reg.histogram("apex_t_step_seconds", "t")
     ctr = reg.counter("apex_t_steps_total", "t")
     a = np.ones((128, 128), np.float64)
+    made = []
+
+    class CountedSpan(trace.Span):
+        __slots__ = ()
+
+        def __init__(self, *args, **kwargs):
+            made.append("span")
+            super().__init__(*args, **kwargs)
+
+    class CountedAnnotation:
+        is_enabled = staticmethod(trace.TraceAnnotation.is_enabled)
+
+        def __new__(cls, *args, **kwargs):
+            made.append("annotation")
+            return trace.TraceAnnotation(*args, **kwargs)
+
+    def counted(method, name):
+        def call(*args, **kwargs):
+            made.append(name)
+            return method(*args, **kwargs)
+        return call
+
+    monkeypatch.setattr(trace, "Span", CountedSpan)
+    monkeypatch.setattr(trace, "TraceAnnotation", CountedAnnotation)
+    monkeypatch.setattr(hist, "observe", counted(hist.observe, "observe"))
+    monkeypatch.setattr(ctr, "inc", counted(ctr.inc, "inc"))
     prev = trace.uninstall_recorder()  # measure the true default path
     try:
-        def bare(n):
-            t0 = time.perf_counter()
-            for _ in range(n):
+        n = 6 * 200
+        for _ in range(n):
+            ts = time.perf_counter()
+            with trace.span("step", step=1) as live:
                 (a @ a).sum()
-            return time.perf_counter() - t0
-
-        def instrumented(n):
-            t0 = time.perf_counter()
-            for _ in range(n):
-                ts = time.perf_counter()
-                with trace.span("step"):
-                    (a @ a).sum()
-                hist.observe(time.perf_counter() - ts)
-                ctr.inc()
-            return time.perf_counter() - t0
-
-        n = 200
-        bare(n), instrumented(n)  # warm caches
-        t_bare, t_inst = (min(ts) for ts in zip(
-            *[(bare(n), instrumented(n)) for _ in range(5)]))
+                assert live is None and trace.current_span() is None
+            hist.observe(time.perf_counter() - ts)
+            ctr.inc()
     finally:
         if prev is not None:
             trace.install_recorder(prev)
-    assert ctr.value() == 6 * n
-    assert t_inst <= 3.0 * t_bare, (
-        f"instrumented {t_inst:.4f}s vs bare {t_bare:.4f}s "
-        f"({t_inst / t_bare:.2f}x > 3x budget)")
+    assert ctr.value() == n
+    assert made == ["observe", "inc"] * n, collections.Counter(made)
+    # the count tells a recorder: installed, the same step allocates a span
+    del made[:]
+    with trace.recording():
+        with trace.span("step"):
+            pass
+    assert made == ["span"]

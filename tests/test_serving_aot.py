@@ -414,3 +414,130 @@ def test_latent_chunk_reads_in_place_and_keeps_no_block_of_scores(
     assert not large, (
         f"float32 arrays over the {heads} heads of a block's scores or more "
         f"({scores} elements) in the prefill program: {large}")
+
+
+# -- a model with K/V rows and rings of window K/V rows (PR 33) --------------
+# the repository cell's attention geometry (benchmark/configs/
+# mellum2-12b-l8.json, traffic/repo-closed.json): one window layer and two
+# full layers at published widths (GQA 32 over 4 heads of 128, a window of
+# 1,024), 16 slots of 32,768 rows, a 1,024-row chunk.  Two full layers: what
+# XLA:TPU does to the stacked buffer once for all its layers shows from two
+# on.  Eight experts and a small vocabulary: they keep no rows
+
+WINDOW_SLOTS, WINDOW_MAX_LEN, WINDOW_CHUNK = 16, 32768, 1024
+
+
+@pytest.fixture(scope="module")
+def window_engine():
+    from apex_tpu.models.mellum import MellumConfig, MellumForCausalLM
+
+    model = MellumForCausalLM(MellumConfig(
+        vocab_size=256, layer_types=("sliding_attention", "full_attention",
+                                     "full_attention"),
+        num_experts=8, experts_held=(0, 8)), params_dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    params = jax.tree.map(lambda l: jnp.zeros(l.shape, l.dtype), shapes)
+    return sv.DecodeEngine(model, params, slots=2, max_len=64,
+                           prefill_len=64, cache_dtype=jnp.bfloat16)
+
+
+@pytest.fixture(scope="module")
+def window_compiled(window_engine, one_chip):
+    """``window_compiled(program)``: the engine's program compiled for the
+    cell-sized cache on the path the chip takes, once for the module."""
+    def compiled(program):
+        on_chip, arg = _placed(one_chip)
+        cache = on_chip(jax.eval_shape(lambda: init_cache(
+            window_engine.model.cache_layers(), slots=WINDOW_SLOTS,
+            max_len=WINDOW_MAX_LEN, dtype=jnp.bfloat16)))
+        assert cache.k.shape == (2, WINDOW_SLOTS, WINDOW_MAX_LEN, 4, 128)
+        assert cache.ring_k.shape == (1, WINDOW_SLOTS, 1024, 4, 128)
+        params = on_chip(window_engine.params)
+        with mock.patch.object(_dispatch, "on_tpu", lambda: True):
+            if program == "decode":
+                lowered = window_engine._decode.lower(
+                    params, cache, arg((WINDOW_SLOTS,), jnp.int32),
+                    arg((WINDOW_SLOTS,), bool))
+            else:
+                lowered = window_engine._prefill.lower(
+                    params, cache, arg((1, WINDOW_CHUNK), jnp.int32),
+                    arg((), jnp.int32), arg((), jnp.int32),
+                    arg((), jnp.int32))
+        return lowered.compile()
+
+    return functools.cache(compiled)
+
+
+WINDOW_SLAB = WINDOW_SLOTS * WINDOW_MAX_LEN * 4 * 128
+
+
+def test_window_decode_reads_rows_and_ring_where_they_lie(window_compiled):
+    """A decode step reads the full layer's rows and the window layer's ring
+    through the same in-place kernel, one call each, on the stored buffers:
+    no cut and no copy of either, and four KV heads take no padding (the
+    buffers' tiles are ``T(4,128)``: the cache is the 2.35 GB it is reckoned
+    at, not twice that)."""
+    text = window_compiled("decode").as_text()
+    assert len(_kernel_calls(text, "cached_decode_attention")) == 3
+    assert "bf16[2,%d,%d,4,128]{4,3,2,1,0:T(4,128)(2,1)}" % (
+        WINDOW_SLOTS, WINDOW_MAX_LEN) in text
+    found = _slab_sized_cuts(text, WINDOW_SLAB) + _slab_sized_layout_copies(
+        text, WINDOW_SLAB)
+    assert not found, f"the decode step cuts or copies the rows: {found}"
+    # the ring is smaller than a weight matrix, whose prefetch is a copy:
+    # told by its shape (this model's one ring is small enough for XLA:TPU
+    # to prefetch whole, ``copy-start`` / ``copy-done``: no layout copy)
+    ring = re.compile(r"bf16\[(1,)?%d,1024,4,128\]" % WINDOW_SLOTS)
+    moved = []
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if m and ring.search(m.group(2)) and (
+                m.group(3) in ("copy", "transpose", "slice")
+                or m.group(3) == "fusion" and m.group(1).startswith(
+                    ("copy", "transpose", "slice"))):
+            moved.append(line.strip()[:120])
+    assert not moved, f"the decode step cuts or copies the ring: {moved}"
+
+
+def test_window_chunk_walks_in_the_kernel_and_keeps_no_block_of_scores(
+        window_compiled):
+    """A 1,024-row chunk reads the full layers' visible blocks and the
+    window layer's short extent through ``kv_chunk_attention``, one call
+    each, operands and result in HBM; what is cut out of the cache is one
+    slot's rows head-major (``[4, 32768, 128]``, K and V, a full layer),
+    never a layer's slab and never the stacked buffer - whose layout the
+    cut's is pinned against: with a fence alone XLA:TPU turned all 1.07 GB
+    of K and of V head-major once a chunk, 6 of its 40 ms on the chip
+    (PERF.md §6, PR 33) -; and no float32 array as large as one block's
+    scores is left anywhere (as a loop in ``jax.numpy`` the walk sent a
+    block's ``[4, 8192, 512]`` scores to HBM and back four or five times,
+    the window layer ``[4, 8192, 2048]`` at once: PERF.md §6, PR 33)."""
+    compiled = window_compiled("prefill")
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if re.match(r"\s*%kv_chunk_attention[\w.]* = ", line)]
+    assert len(calls) == 3
+    for call in calls:
+        assert "S(1)" not in call.split(" = ")[1].split(" ")[0], call[:200]
+        for name in re.findall(r"%([\w.\-]+)", call.split("custom-call(")[1]
+                               .split(")")[0]):
+            made = next(line for line in text.splitlines()
+                        if re.match(r"\s*%%%s = " % re.escape(name), line))
+            assert "S(1)" not in made.split(" = ")[1].split(" ")[0], made[:200]
+    found = (_slab_sized_cuts(text, WINDOW_SLAB)
+             + _slab_sized_layout_copies(text, WINDOW_SLAB))
+    assert not found, f"the chunk cuts or copies the layer's slab: {found}"
+    one_slot = [name for name, op, sizes in _entry_ops(text)
+                if WINDOW_MAX_LEN * 4 * 128 in sizes and op == "fusion"
+                and name.startswith("copy")]
+    assert len(one_slot) == 4, one_slot
+    scores = 32 * WINDOW_CHUNK * 512
+    large = sorted({
+        dims for dims in re.findall(r"\bf32\[([\d,]+)\]", text)
+        if math.prod(int(d) for d in dims.split(",")) >= scores
+        and dims.split(",")[:2] == ["4", "8192"]})
+    assert not large, (
+        f"float32 arrays as large as a block's scores over a KV head's 8 x "
+        f"1,024 query rows in the prefill program: {large}")
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9

@@ -24,15 +24,18 @@ PACKAGE = pathlib.Path(kvc.__file__).resolve().parents[1]
 MODELS = sorted(p.name for p in (PACKAGE / "models").glob("*.py"))
 CACHE_MODULES = ("serving/kv_cache.py", "serving/paged_kv_cache.py")
 CACHE_CLASSES = ("KVCache", "QuantKVCache", "PagedKVCache",
-                 "QuantPagedKVCache", "HybridCache", "LatentCache")
+                 "QuantPagedKVCache", "HybridCache", "LatentCache",
+                 "WindowKVCache")
 # what a model may import from apex_tpu.serving: the two calls of an
 # attention layer (K/V rows, latent rows, a window ring), the state functions
 # of a recurrent or counting layer, and the declarations cache_layers()
 # returns
-SEAM = {"decode_attend", "prefill_attend", "latent_decode_attend",
+SEAM = {"decode_attend", "prefill_attend", "window_decode_attend",
+        "window_prefill_attend", "latent_decode_attend",
         "latent_prefill_attend", "ring_decode_attend", "ring_prefill_attend",
         "slot_state", "write_slot_state", "write_lane_state", "add_counts",
-        "KVRows", "RecurrentRows", "LatentRows", "RingRows", "CallCounters"}
+        "KVRows", "KVWindowRows", "RecurrentRows", "LatentRows", "RingRows",
+        "CallCounters"}
 
 
 # ---- (a) the source: who knows what ---------------------------------------

@@ -102,6 +102,11 @@ ALLOWLIST = {
     # a call site took (ops/_dispatch.record_dispatch) — chip_smoke.py
     # reads it from a private sink; countable via apex_events_total
     "kernel_dispatch",
+    # its sibling for a choice between two plain jax.numpy reads
+    # (ops/_dispatch.record_choice: a chunk's K/V read walks the visible
+    # blocks or attends the whole extent) - the benchmark reads it from a
+    # private sink
+    "read_dispatch",
 }
 
 

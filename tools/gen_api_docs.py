@@ -66,11 +66,13 @@ PAGES = {
         "apex_tpu.ops.pair_bias_attention",
         "apex_tpu.ops.cached_decode_attention",
         "apex_tpu.ops.latent_chunk_attention",
+        "apex_tpu.ops.kv_chunk_attention",
     ]),
     "models": ("Model zoo", [
         "apex_tpu.models", "apex_tpu.models.llama",
         "apex_tpu.models.llama_pipeline", "apex_tpu.models.vit",
         "apex_tpu.models.nemotron_h", "apex_tpu.models.dots3",
+        "apex_tpu.models.mellum",
     ]),
     "contrib": ("Contrib extensions", [
         "apex_tpu.contrib.xentropy", "apex_tpu.contrib.focal_loss",
@@ -573,11 +575,12 @@ layers reach it through the seam's functions and name no cache class:
 
 | declaration | what a slot keeps | the seam | served families |
 |---|---|---|---|
-| `KVRows(kv_heads, head_dim)` | `[max_len, kv_heads, head_dim]` K and V | `decode_attend` / `prefill_attend` | `models.llama`, `models.nemotron_h` (`*` layers) |
+| `KVRows(kv_heads, head_dim)` | `[max_len, kv_heads, head_dim]` K and V | `decode_attend` / `prefill_attend` (a chunk's read is one of two, chosen from the shapes in hand and said by a `read_dispatch` event: the whole masked extent while its float32 scores stay within 128 MiB - 32 heads x 512 x 2,048 -, a walk over the visible blocks that stops at the chunk's end past that - 4 GiB at 32 x 1,024 x 32,768; on a TPU the walk is the Pallas kernel `ops.kv_chunk_attention` over the slot's rows cut head-major once a call, elsewhere a loop: the `kernel_dispatch` event `kv_chunk_attention` says which) | `models.llama`, `models.nemotron_h` (`*` layers), `models.mellum` (`full_attention` layers) |
+| `KVWindowRows(kv_heads, head_dim, window)` | a ring of `window` K and V rows in whole 16-row tiles, position `p` at row `p mod rows`, whatever `max_len` | `window_decode_attend` (append at `position mod rows`, then the in-place decode kernel on the ring buffers where the ring is exactly the window, else the ring under a mask) / `window_prefill_attend` (the `window - 1` rows before the chunk from the ring, the chunk's own from the ones in hand, then the chunk's last real rows into the ring) | `models.mellum` (`sliding_attention` layers) |
 | `RecurrentRows(ssm, conv)` | a float32 state and a convolution tail of fixed size | `slot_state` / `write_slot_state` / `write_lane_state` | `models.nemotron_h` (`M` layers) |
 | `LatentRows(width, index_width, top_k)` | `[max_len, width]` latent rows (the compressed K/V and the shared rope key, stored in whole lane tiles) and `[max_len, index_width]` selector keys | `latent_decode_attend` (append, score the live rows' keys, `top_k`, gather, absorbed read) / `latent_prefill_attend` (chunk-write, blocked scores, the selection as a mask, blocked explicit read: on a TPU one Pallas kernel, `ops.latent_chunk_attention`, over the rows in place with a block's scores in fast memory, elsewhere a loop; the `kernel_dispatch` event `latent_chunk_attention` says which) | `models.dots3` (`full_attention` layers) |
 | `RingRows(width, window)` | a ring of `window` rows in whole 16-row tiles, position `p` at row `p mod rows`, whatever `max_len` | `ring_decode_attend` / `ring_prefill_attend` | `models.dots3` (`sliding_attention` layers) |
-| `CallCounters(names)` | int32 counts a decode step adds (`engine.moe_stats()`) | `add_counts` | both routed-expert layers (`transformer.moe.LatentMoE`, `GatedMoE`) |
+| `CallCounters(names)` | int32 counts a decode step adds (`engine.moe_stats()`) | `add_counts` | both routed-expert layers (`transformer.moe.LatentMoE`, `GatedMoE`, the latter sigmoid- or softmax-routed) |
 
 `models.dots3.Dots3NoteForCausalLM` (latent attention in every layer: a
 learned top-`index_topk` key selector on the full layers, a
@@ -590,6 +593,21 @@ proportion to `max_len`, the selector's keys and one float32 score a row
 only; a chunk walks blocks of rows up to its own end.  `engine.decode`'s
 span carries `index_rows`, `attended_rows` and `window_rows` (host counts;
 `engine.rows_read()` sums them), `engine.prefill_chunk`'s its `offset`.
+
+`models.mellum.MellumForCausalLM` (grouped-query attention in every layer:
+a `sliding_window` of K/V rows under plain rope on the `sliding_attention`
+layers, every row under YaRN-scaled rope on the `full_attention` ones;
+softmax-routed gated experts without a shared expert, every expert held
+or a share of them) keeps both kinds of rows in ONE cache
+(`WindowKVCache`: `[max_len, kv_heads, head_dim]` rows for the full layers,
+rings for the others, call counters): at 16 slots x 32,768 rows the
+benchmark's 6 window layers keep 0.20 GB where full extents would be 6.44.
+A decode step reads each full layer up to the lane's length and each
+window layer's ring, both through `ops.cached_decode_attention`; a chunk
+walks a full layer's visible blocks and reads a window layer's ring once.
+`engine.decode`'s span carries `kv_tokens` (a full layer's rows) and
+`window_rows` / `window_live_rows` (what the window layers read, and what
+a read at full extent would have walked).
 
 Everything that pages, shards, quantizes, copies, shares or rolls back
 K/V rows knows nothing of the other declarations and is **refused by

@@ -14,12 +14,12 @@ probabilities in VMEM only:
 - operands: the chunk's queries, scaled, ``[m, heads x hd]``, and the slot's
   rows **head-major**, ``[kv_heads, max_len, hd]`` - the seam cuts them out
   of the stored ``[max_len, kv_heads, hd]`` once a call (33 MB each way at
-  32,768 rows of 4 heads: a tenth of a millisecond, where a tile of one KV
-  head's rows taken from the stored layout would be a strided half-word
-  gather) - with ``offset`` and the number of visible key blocks as
-  prefetched scalars.  The grid is (group of query heads, visible key
-  block), its second extent a runtime value: no tile past the chunk's last
-  row is fetched, and no step runs for one.
+  32,768 rows of 4 heads: a tenth of a millisecond, 4 MB at 2,048 rows of 8:
+  a hundredth, where a tile of one KV head's rows taken from the stored
+  layout would be a strided half-word gather) - with ``offset`` and the
+  number of visible key blocks as prefetched scalars.  The grid is (group
+  of query heads, visible key block), its second extent a runtime value: no
+  tile past the chunk's last row is fetched, and no step runs for one.
 - a step takes one ``[block, hd]`` tile of K and of V of the group's KV
   head (query head ``j`` reads KV head ``j // rep``: a group never spans
   two) and, a tile of ``TILE`` queries at a time, scores, masks ``idx <=
@@ -156,6 +156,19 @@ def kv_chunk_attention(q, k, v, offset, blocks, *, block: int,
     + m - 1`` or ``max_len // block``) and ``first`` are runtime scalars.
     Returns ``[m, heads, hd]`` float32.  The shapes are ones
     :func:`kernel_takes` accepts."""
+    return _call(q, k, v, jnp.asarray(offset, jnp.int32).reshape(1),
+                 jnp.asarray(blocks, jnp.int32).reshape(1),
+                 jnp.asarray(first, jnp.int32).reshape(1), block=block,
+                 window=int(window), interpret=use_interpret())
+
+
+# a function of its own under ``jit``: a program calls the kernel once a
+# layer with the same shapes, and traces and lowers it once - sixteen traces
+# of the unrolled body a program, six programs an engine, were 9 s of a
+# warm start-up (PERF.md section 6, PR 34)
+@functools.partial(jax.jit, static_argnames=("block", "window", "interpret"))
+def _call(q, k, v, offset, blocks, first, *, block: int, window: int,
+          interpret: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     m, heads, hd = q.shape
@@ -176,29 +189,26 @@ def kv_chunk_attention(q, k, v, offset, blocks, *, block: int,
         # where it finds room; the kernel's pipeline is from and to HBM
         # (ops/latent_chunk_attention.py).  The interpreter knows no
         # memory spaces
-        return x if use_interpret() else pltpu.with_memory_space_constraint(
+        return x if interpret else pltpu.with_memory_space_constraint(
             x, pltpu.HBM)
 
     out = pl.pallas_call(
         functools.partial(_kernel, group=group, hd=hd, tile=tile,
-                          window=int(window)),
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(heads // group, blocks),
+            grid=(heads // group, blocks[0]),
             in_specs=[pl.BlockSpec((m, group * hd), of_group),
                       pl.BlockSpec((None, block, hd), rows),
                       pl.BlockSpec((None, block, hd), rows)],
             out_specs=pl.BlockSpec((m, group * hd), of_group),
             scratch_shapes=[pltpu.VMEM((group, m, _LANES), jnp.float32),
                             pltpu.VMEM((group, m, _LANES), jnp.float32)]),
-        out_shape=(jax.ShapeDtypeStruct if use_interpret() else pltpu.HBM)(
+        out_shape=(jax.ShapeDtypeStruct if interpret else pltpu.HBM)(
             (m, heads * hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=use_interpret(),
+        interpret=interpret,
         name="kv_chunk_attention",
-    )(jnp.asarray(offset, jnp.int32).reshape(1),
-      jnp.asarray(blocks, jnp.int32).reshape(1),
-      jnp.asarray(first, jnp.int32).reshape(1),
-      *map(in_hbm, (q.reshape(m, heads * hd), k, v)))
+    )(offset, blocks, first, *map(in_hbm, (q.reshape(m, heads * hd), k, v)))
     return out.reshape(m, heads, hd)

@@ -49,8 +49,11 @@ twin, and one seam a model's attention calls through:
   :class:`LatentRows`, :class:`RingRows`, :class:`CallCounters` or None a
   layer; :func:`init_cache` builds every cache from that.
 - **The seam**: :func:`decode_attend` and :func:`prefill_attend` are the
-  whole step an attention layer needs - append or chunk-write, view, cast
-  to the query's dtype, the grouped masked read
+  whole step an attention layer needs - append or chunk-write, then the
+  read: on a TPU a dense float cache's rows where they lie, through the
+  Pallas kernels of :mod:`apex_tpu.ops.cached_decode_attention` and
+  :mod:`apex_tpu.ops.kv_chunk_attention`; everything else a view, cast to
+  the query's dtype, under the grouped masked read
   (:func:`cached_attention`) - whatever the layout and the format.  A
   model imports those two (for a layer that sees a window of K/V rows
   their window pair :func:`window_decode_attend` /
@@ -624,31 +627,60 @@ def decode_attend(cache, layer: int, q, k, v, position):
 
 
 # the float32 scores of one full-extent chunk read, ``heads x chunk x max_len
-# x 4`` bytes, up to which :func:`prefill_attend` attends the whole extent:
-# 128 MiB, what 32 heads x 512 rows x 2,048 rows come to.  At 32,768 rows
-# every bucket from 64 rows up walks (4 GiB at 32 x 1,024)
+# x 4`` bytes, up to which a chunk that the kernel does not take attends the
+# whole extent (:func:`cached_attention`) and past which it walks the visible
+# blocks in the loop :func:`_kv_chunk_read`: 128 MiB, what 32 heads x 512 rows
+# x 2,048 rows come to.  At 32,768 rows every bucket from 64 rows up walks (4
+# GiB at 32 x 1,024).  It decides nothing where the kernel runs, and no
+# second number of score bytes does: on the chip cut + kernel beat the full
+# extent at every bucket of a 2,048-row cache, the 16-row one's 4 MiB of
+# scores included (0.78-1.28 ms against 3.17 over 16 layers,
+# ``tools/chunk_read_bench.py``; every traced program of ``chat-closed`` is
+# shorter, 13.73 -> 10.91 ms the smallest: PERF.md section 6, PR 34)
 _FULL_READ_BYTES = 1 << 27
 
 
-def _walks_blocks(cache, q) -> bool:
-    """Whether a chunk's read walks the visible blocks of the slot's rows
-    (:func:`_chunk_walk`: work in proportion to ``offset + length``, one
-    block's scores at a time) or attends the whole masked ``max_len`` extent
-    (:func:`cached_attention`) - decided by what is in hand: float rows a
-    slot owns in the stored buffers, in whole blocks, whose full-extent
-    float32 scores would pass :data:`_FULL_READ_BYTES`.  The
-    ``read_dispatch`` event says which."""
-    s, heads = q.shape[0], q.shape[2]
-    scores = 4 * heads * s * cache.max_len
-    block = _key_block(cache.max_len)
-    walks = bool(cache.lane_rows_in_place
-                 and jnp.issubdtype(cache.k.dtype, jnp.floating)
-                 and cache.max_len % block == 0
-                 and scores > _FULL_READ_BYTES)
-    record_choice("prefill_attend", "blocked_walk" if walks else
-                  "full_extent", heads=heads, chunk=s, max_len=cache.max_len,
+def _prefill_read(cache, q) -> str:
+    """Which read a chunk's queries ``q [s, 1, heads, hd]`` take over the
+    slot's rows, decided once a trace by what is in hand:
+
+    - ``"kernel"``: the Pallas kernel
+      (:func:`~apex_tpu.ops.kv_chunk_attention.kv_chunk_attention`) wherever
+      it takes the call, as :func:`_reads_in_place` decides for the decode
+      step - kernels enabled (a TPU), rows a slot owns in the stored buffers
+      in the queries' own float dtype, a head, a key block and a chunk in
+      whole tiles (``kernel_takes``) - **at any extent**: its work follows
+      ``offset + length``, a block's scores stay in fast memory;
+    - everything else as it was: ``"full_extent"``
+      (:func:`cached_attention` over the whole masked ``max_len``) while the
+      float32 scores of that read stay within :data:`_FULL_READ_BYTES`,
+      ``"loop"`` (:func:`_kv_chunk_read`, the same walk in ``jax.numpy``)
+      past it, for float rows a slot owns in whole blocks.
+
+    The ``kernel_dispatch`` event ``kv_chunk_attention`` says kernel or not,
+    the ``read_dispatch`` event ``blocked_walk`` (kernel or loop) or
+    ``full_extent``."""
+    s, heads, hd = q.shape[0], q.shape[2], q.shape[3]
+    max_len = cache.max_len
+    scores = 4 * heads * s * max_len
+    block = _key_block(max_len)
+    shape = dict(m=s, hd=hd, block=block, max_len=max_len)
+    if record_dispatch(
+            "kv_chunk_attention",
+            cache.lane_rows_in_place and cache.k.dtype == q.dtype
+            and chunk_kernel_takes(**shape),
+            heads=heads, kv_heads=cache.k.shape[-2], **shape):
+        read = "kernel"
+    elif (cache.lane_rows_in_place
+          and jnp.issubdtype(cache.k.dtype, jnp.floating)
+          and max_len % block == 0 and scores > _FULL_READ_BYTES):
+        read = "loop"
+    else:
+        read = "full_extent"
+    record_choice("prefill_attend", "full_extent" if read == "full_extent"
+                  else "blocked_walk", heads=heads, chunk=s, max_len=max_len,
                   score_bytes=scores)
-    return walks
+    return read
 
 
 def prefill_attend(cache, layer: int, slot, q, k, v, offset):
@@ -659,19 +691,29 @@ def prefill_attend(cache, layer: int, slot, q, k, v, offset):
     ``k`` / ``v`` ``[s, 1, kv_heads, hd]``.  Returns ``(ctx [1, heads, s,
     hd], cache)``.
 
-    One read, two implementations (:func:`_walks_blocks` chooses).  Where
-    the scores of the whole extent are small, one fixed-extent masked read
-    (:func:`cached_attention`), so splitting a prompt into chunks never
-    changes any bit; at long extents the blocked walk (:func:`_chunk_walk`),
-    which stops at the chunk's end and rounds a block at a time."""
+    One read, three implementations (:func:`_prefill_read` chooses).  On a
+    TPU a dense float cache whose shapes the kernel takes is walked a
+    visible block at a time by :func:`_chunk_kernel`, short cache or long:
+    a chunked and an unchunked prompt then agree to rounding, as decode
+    steps do (:func:`decode_attend`).  Everything else - a CPU backend, int8
+    rows, a block table, an odd head width, a verify's odd row count - takes
+    one fixed-extent masked read (:func:`cached_attention`) where the scores
+    of the whole extent are small, so that splitting a prompt into chunks
+    never changes any bit, and at long extents the same walk as a loop
+    (:func:`_kv_chunk_read`), which stops at the chunk's end and rounds a
+    block at a time."""
     s, b = q.shape[:2]
     if b != 1:
         raise ValueError(
             f"prefill expects one slot per call (b=1), got b={b}")
     cache = prefill_into_slot(cache, layer, slot, k[:, 0], v[:, 0],
                               start=offset)
-    if _walks_blocks(cache, q):
-        return _chunk_walk(cache, layer, slot, q[:, 0], offset)[None], cache
+    read = _prefill_read(cache, q)
+    if read == "kernel":
+        return _chunk_kernel(cache, layer, slot, q[:, 0], offset)[None], cache
+    if read == "loop":
+        return _kv_chunk_read(q[:, 0].transpose(1, 0, 2), cache.k, cache.v,
+                              layer, slot, offset)[None], cache
     kc, vc = slot_read(cache, layer, slot)
     kc = kc.astype(q.dtype)                         # [max, kv_heads, hd]
     vc = vc.astype(q.dtype)
@@ -680,27 +722,17 @@ def prefill_attend(cache, layer: int, slot, q, k, v, offset):
     return cached_attention(qt, kc[None], vc[None], bounds), cache
 
 
-def _chunk_walk(cache, layer: int, slot, q, offset):
-    """The blocked walk of :func:`prefill_attend`: ``q [s, heads, hd]`` over
-    the visible blocks of ``slot``'s rows; ``[heads, s, hd]`` in ``q``'s
-    dtype.  One recurrence, two implementations, chosen as
-    :func:`_reads_in_place` chooses and said by the ``kernel_dispatch`` event
-    ``kv_chunk_attention``: on a TPU, rows in the queries' own float dtype
-    with a head and a block in whole lane tiles go through the Pallas kernel
-    (:func:`~apex_tpu.ops.kv_chunk_attention.kv_chunk_attention`), which
-    keeps a block's scores in fast memory and takes the slot's rows
-    head-major - cut out of the stored layout here, once a call -;
-    everything else through the loop :func:`_kv_chunk_read`."""
-    s, heads, hd = q.shape
+def _chunk_kernel(cache, layer: int, slot, q, offset):
+    """The kernel's read of :func:`prefill_attend`: ``q [s, heads, hd]`` over
+    the visible blocks of ``slot``'s rows through
+    :func:`~apex_tpu.ops.kv_chunk_attention.kv_chunk_attention`, which keeps
+    a block's scores in fast memory and takes the slot's rows head-major -
+    cut out of the stored layout here, once a call (4 MB each for K and V at
+    2,048 rows of 8 heads, 33 MB at 32,768 of 4); ``[heads, s, hd]`` in
+    ``q``'s dtype."""
+    s = q.shape[0]
     max_len = cache.max_len
     block = _key_block(max_len)
-    shape = dict(m=s, hd=hd, block=block, max_len=max_len)
-    if not record_dispatch(
-            "kv_chunk_attention",
-            cache.k.dtype == q.dtype and chunk_kernel_takes(**shape),
-            heads=heads, kv_heads=cache.k.shape[-2], **shape):
-        return _kv_chunk_read(q.transpose(1, 0, 2), cache.k, cache.v, layer,
-                              slot, offset)
     slot = jnp.asarray(slot, jnp.int32)
     offset = jnp.asarray(offset, jnp.int32)
     # the slot's rows pinned to the layout they are stored in, then turned

@@ -77,11 +77,12 @@ _TINY = dict(card=dict(metric="llama_tiny", family="llama", layers=2,
 
 def _reference_expected(event) -> bool:
     """A call site whose shape predicate is known to fail on the smoke's
-    cards: their heads are 64 wide, and the decode step's in-place K/V read
-    wants whole lane tiles (``serving/kv_cache.py::decode_attend``; at 64
-    XLA:TPU keeps ``max_len`` in the lanes and the kernel's operand would
-    be a copy of the whole cache)."""
-    return (event["op"] == "cached_decode_attention"
+    cards: their heads are 64 wide, and the K/V read kernels of the decode
+    step and of a prompt chunk want whole lane tiles
+    (``serving/kv_cache.py::decode_attend`` / ``prefill_attend``; at 64
+    XLA:TPU keeps ``max_len`` in the lanes and the decode kernel's operand
+    would be a copy of the whole cache)."""
+    return (event["op"] in ("cached_decode_attention", "kv_chunk_attention")
             and event["hd"] % 128 != 0)
 
 

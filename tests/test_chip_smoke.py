@@ -47,20 +47,21 @@ def test_rehearsal_runs_every_phase_and_is_stamped(tmp_path):
     assert phases == ["device", "kernels", "train", "hand-off", "serve"]
     assert all(ln["passed"] for ln in lines if "phase" in ln)
     # every kernel call site took the kernel, none a reference - but the
-    # decode step's K/V read, which at the card's 64-wide heads is the
-    # reference by its shape predicate
+    # K/V reads of the decode step and of a prompt chunk, which at the
+    # card's 64-wide heads are the reference by their shape predicates
     for ln in lines:
         for key in ln.get("kernel_dispatch", {}):
-            assert key.endswith(":pallas") or (
-                ln["phase"], key) == (
-                    "serve", "cached_decode_attention:reference"), ln
+            assert key.endswith(":pallas") or (ln["phase"], key) in (
+                ("serve", "cached_decode_attention:reference"),
+                ("serve", "kv_chunk_attention:reference")), ln
     by = {ln["phase"]: ln for ln in lines if "phase" in ln}
     assert {k.split(":")[0] for k in by["train"]["kernel_dispatch"]} == {
         "flash_attention", "norm_fwd", "norm_bwd", "fused_lm_head"}
     assert by["train"]["losses"][-1] < by["train"]["losses"][0]
     assert by["serve"]["decode_compiles"] == 1
-    assert "cached_decode_attention:reference" in by["serve"][
-        "kernel_dispatch"]
+    assert {"cached_decode_attention:reference",
+            "kv_chunk_attention:reference"} <= set(
+                by["serve"]["kernel_dispatch"])
     assert by["device"]["compile_cache_dir"] == str(tmp_path / "cache")
     # no file-size limit here: the params went over as ONE checkpoint
     assert by["hand-off"]["checkpoints"] == 1

@@ -13,6 +13,9 @@ rounded to bf16 before the second product on both sides).  What the
 comparisons guard - a row read past the chunk's end, outside the window,
 another slot's or another layer's - is a NaN here, not a small error."""
 
+import contextlib
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -101,6 +104,16 @@ def test_heads_a_step_follow_the_budget():
             m=1024, hd=128, block=512, max_len=32768), **bad}), bad
 
 
+def _short_cache(dtype, nkv, offset, hidden):
+    """Two layers of two slots of 2,048 rows; slot 1 of layer 1 holds
+    ``offset`` rows, everything else is ``hidden``."""
+    mine = jnp.zeros((2, 2, 2048), bool).at[1, 1, :offset].set(True)
+    k, v = (jnp.where(mine[..., None, None],
+                      _rows(dtype, 2, 2, 2048, nkv, HD, seed=n), hidden)
+            for n in (3, 4))
+    return kvc.KVCache(k=k, v=v, lengths=jnp.zeros((2,), jnp.int32))
+
+
 @DTYPES
 @pytest.mark.parametrize("offset", [0, 40, 512, 1500],
                          ids=lambda o: f"offset{o}")
@@ -111,16 +124,8 @@ def test_full_layer_chunk_behind_the_seam(monkeypatch, interpreted, dtype,
     off (the loop).  Other slots, the other layer and every row past the
     chunk are NaN in both."""
     monkeypatch.setattr(kvc, "_FULL_READ_BYTES", 0)
-    s, heads, nkv, max_len = 64, 8, 2, 2048
-    cache = kvc.init_cache([kvc.KVRows(nkv, HD)] * 2, slots=2,
-                           max_len=max_len, dtype=dtype)
-    mine = jnp.zeros((2, 2, max_len), bool).at[1, 1, :offset].set(True)
-    cache = kvc.KVCache(
-        k=jnp.where(mine[..., None, None], _rows(dtype, 2, 2, max_len, nkv,
-                                                 HD, seed=3), jnp.nan),
-        v=jnp.where(mine[..., None, None], _rows(dtype, 2, 2, max_len, nkv,
-                                                 HD, seed=4), jnp.nan),
-        lengths=cache.lengths)
+    s, heads, nkv = 64, 8, 2
+    cache = _short_cache(dtype, nkv, offset, jnp.nan)
     q = _rows(dtype, s, 1, heads, HD, seed=5)
     k, v = (_rows(dtype, s, 1, nkv, HD, seed=n) for n in (6, 7))
 
@@ -141,6 +146,98 @@ def test_full_layer_chunk_behind_the_seam(monkeypatch, interpreted, dtype,
     wrote = np.asarray(after.k[1, 1, offset:offset + s], np.float32)
     assert (wrote == np.asarray(k[:, 0], np.float32)).all()
     assert np.isnan(np.asarray(after.k[0], np.float32)).all()
+
+
+@contextlib.contextmanager
+def _dispatch_events():
+    """The ``(op, path)`` of the dispatch events emitted inside."""
+    seen = []
+
+    def sink(event):
+        if event["event"] in ("kernel_dispatch", "read_dispatch"):
+            seen.append((event["op"], event["path"]))
+
+    _logging.add_event_sink(sink)
+    try:
+        yield seen
+    finally:
+        _logging.remove_event_sink(sink)
+
+
+@functools.cache
+def _seam(kernels):
+    """``prefill_attend`` on layer 1 under ``jit``, one function a setting of
+    ``APEX_TPU_KERNELS`` (a trace is kept by shapes: the offsets of a bucket
+    share a program, the two settings must not)."""
+    return jax.jit(lambda cache, *args: kvc.prefill_attend(cache, 1, *args))
+
+
+@DTYPES
+@pytest.mark.parametrize("offset", [0, 512, 1536], ids=lambda o: f"offset{o}")
+@pytest.mark.parametrize("m", [16, 32, 64, 128, 256, 512],
+                         ids=lambda m: f"m{m}")
+@pytest.mark.parametrize("nkv", [8, 2], ids=lambda n: f"kv{n}")
+def test_short_cache_chunk_behind_the_seam(monkeypatch, dtype, nkv, m,
+                                           offset):
+    """The two short-cache cells' attention shapes - 32 query heads over 8
+    KV heads (``chat-closed``) and over 2 (the hybrid cell), heads of 128,
+    2,048 rows a slot - at every default bucket with one, two and four
+    visible key blocks: ``prefill_attend`` takes the kernel whatever the
+    extent, and agrees with :func:`cached_attention` over the whole masked
+    extent (the same call with kernels off, which these shapes' 4-128 MiB
+    of scores keep on ``full_extent``).  The file's tolerances for the loop
+    hold against the full extent too: float32 differs by the order of its
+    sums alone; bf16 rounds the probabilities to 8 bits on both sides, there
+    after the division by the row's sum and here before it.  Other slots, the
+    other layer and every row past the chunk are NaN for the kernel - which
+    must not read them - and zeros for the masked read, whose ``0 * v`` has
+    to stay finite."""
+    heads = 32
+    q = _rows(dtype, m, 1, heads, HD, seed=5)
+    k, v = (_rows(dtype, m, 1, nkv, HD, seed=n) for n in (6, 7))
+
+    def call(kernels, hidden, read):
+        monkeypatch.setenv("APEX_TPU_KERNELS", kernels)
+        cache = _short_cache(dtype, nkv, offset, hidden)
+        assert kvc._prefill_read(cache, q) == read
+        return _seam(kernels)(cache, jnp.int32(1), q, k, v,
+                              jnp.int32(offset))
+
+    got, after = call("interpret", jnp.nan, "kernel")
+    want, _ = call("0", 0.0, "full_extent")
+    assert got.shape == (1, heads, m, HD) and got.dtype == dtype
+    _close(got, want, dtype, f"kv {nkv} m {m} offset {offset}")
+    wrote = np.asarray(after.k[1, 1, offset:offset + m], np.float32)
+    assert (wrote == np.asarray(k[:, 0], np.float32)).all()
+
+
+@DTYPES
+def test_a_verify_the_kernel_refuses_stays_on_the_full_extent(monkeypatch,
+                                                              dtype):
+    """Nine rows (a draft bucket of 8 and the token before it) are no whole
+    sublane tile: with kernels on ``prefill_attend`` still takes
+    ``full_extent``, says so, and returns bit for bit what it returns with
+    kernels off - the parent's read."""
+    m, heads, nkv, offset = 9, 32, 8, 700
+    q = _rows(dtype, m, 1, heads, HD, seed=5)
+    k, v = (_rows(dtype, m, 1, nkv, HD, seed=n) for n in (6, 7))
+    cache = _short_cache(dtype, nkv, offset, 0.0)
+
+    def call():
+        return jax.jit(lambda *args: kvc.prefill_attend(cache, 1, *args))(
+            jnp.int32(1), q, k, v, jnp.int32(offset))
+
+    monkeypatch.setenv("APEX_TPU_KERNELS", "interpret")
+    with _dispatch_events() as seen:
+        got, after = call()
+    assert seen == [("kv_chunk_attention", "reference"),
+                    ("prefill_attend", "full_extent")]
+    monkeypatch.setenv("APEX_TPU_KERNELS", "0")
+    want, after_want = call()
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(after.k, np.float32),
+                                  np.asarray(after_want.k, np.float32))
 
 
 @DTYPES

@@ -23,6 +23,7 @@ All such compiles live in this one file and describe the topology inside
 a fixture: only one process at a time may load the TPU's library.
 """
 
+import dataclasses
 import functools
 import math
 import os
@@ -298,6 +299,100 @@ def test_hybrid_decode_reads_the_cache_where_it_lies(hybrid_engine, one_chip):
         text, slab)
     assert not found, (
         f"the hybrid decode program cuts or copies the K/V slab: {found}")
+
+
+# -- a short cache's prompt chunk through the chunk kernel (PR 34) -----------
+# ``chat-closed``'s and the hybrid cell's chunks score exactly the 128 MiB up
+# to which ``prefill_attend`` used to attend the whole masked extent; on the
+# chip they now walk the visible blocks in ``kv_chunk_attention`` like the long
+# cells' chunks.  The dense program is compiled with ALL 16 layers of the cell:
+# what XLA:TPU does to the stacked buffer once for all its layers (PRs 26, 33)
+# does not show in a two-layer program
+
+DENSE_LAYERS = 16
+
+
+def _chunk_program_text(engine, params, cache, arg, bucket=CHUNK):
+    with mock.patch.object(_dispatch, "on_tpu", lambda: True):
+        return engine._prefill.lower(
+            params, cache, arg((1, bucket), jnp.int32), arg((), jnp.int32),
+            arg((), jnp.int32), arg((), jnp.int32)).compile().as_text()
+
+
+def _score_sized_f32(text, heads, chunk, block=512):
+    """Shapes of float32 arrays, anywhere in the program, as large as one
+    key block's ``[heads, chunk, block]`` scores (a quarter of the full
+    extent's at 2,048 rows)."""
+    return sorted({
+        dims for dims in re.findall(r"\bf32\[([\d,]+)\]", text)
+        if math.prod(int(d) for d in dims.split(",")) >= heads * chunk * block})
+
+
+def test_dense_chunk_walks_in_the_kernel_at_all_its_layers(one_chip):
+    """The dense engine's 512-row ``_prefill`` at Mistral's attention widths
+    and depth: one ``kv_chunk_attention`` call a layer; what is cut out of
+    the cache is one slot's ``[2048, 8, 128]`` rows, K and V a layer, each
+    turned head-major once (4 MB; the layout of the cut is pinned, PR 33) -
+    never a layer's slab (the cut ``slot_read`` made for
+    ``cached_attention`` is gone with that read) and never the stacked
+    buffer; and no float32 array as large as a block's scores is left:
+    ``cached_attention`` sent ``[32, 512, 2048]`` scores, 134 MB a layer,
+    through HBM five times (PERF.md section 6, PR 34)."""
+    on_chip, arg = _placed(one_chip)
+    cfg = dataclasses.replace(CFG, num_hidden_layers=DENSE_LAYERS)
+    model = LlamaForCausalLM(cfg)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    # the programs are lowered on shapes: the engine holds one dummy leaf
+    params = on_chip(jax.tree.map(lambda l: jax.ShapeDtypeStruct(
+        l.shape, jnp.bfloat16 if len(l.shape) >= 2 else l.dtype), shapes))
+    engine = sv.DecodeEngine(model, {"w": jnp.zeros((1,), jnp.bfloat16)},
+                             slots=2, max_len=CHUNK, prefill_len=CHUNK,
+                             cache_dtype=jnp.bfloat16)
+    cache = on_chip(jax.eval_shape(lambda: init_cache(
+        model.cache_layers(), slots=SLOTS, max_len=MAX_LEN,
+        dtype=jnp.bfloat16)))
+    text = _chunk_program_text(engine, params, cache, arg)
+    assert "bf16[%d,%d,%d,%d,128]" % (
+        DENSE_LAYERS, SLOTS, MAX_LEN, cfg.kv_heads) in text
+    assert len(_kernel_calls(text, "kv_chunk_attention")) == DENSE_LAYERS
+    found = _slab_sized_cuts(text) + _slab_sized_layout_copies(text)
+    assert not found, f"the chunk cuts or copies a layer's slab: {found}"
+    one_slot = [name for name, op, sizes in _entry_ops(text)
+                if MAX_LEN * cfg.kv_heads * 128 in sizes and op == "fusion"
+                and name.startswith("copy")]
+    assert len(one_slot) == 2 * DENSE_LAYERS, one_slot
+    large = _score_sized_f32(text, cfg.num_attention_heads, CHUNK)
+    assert not large, (
+        f"float32 arrays as large as a block's scores in the prefill "
+        f"program: {large}")
+
+
+def test_hybrid_chunk_walks_in_the_kernel(hybrid_engine, one_chip):
+    """The same read at the hybrid cell's attention geometry - 16 query
+    heads a KV head, 2 KV heads, 64 slots -: its one attention layer's
+    512-row chunk is one ``kv_chunk_attention`` call, the layer's slab is
+    neither cut nor copied, and no float32 array over the 32 heads is as
+    large as a block's scores (the scan's own arrays have no such axis)."""
+    on_chip, arg = _placed(one_chip)
+    model = hybrid_engine.model
+    cache = on_chip(jax.eval_shape(lambda: init_cache(
+        model.cache_layers(), slots=HYBRID_SLOTS, max_len=MAX_LEN,
+        dtype=jnp.bfloat16)))
+    text = _chunk_program_text(hybrid_engine, on_chip(hybrid_engine.params),
+                               cache, arg)
+    cfg = model.config
+    slab = HYBRID_SLOTS * MAX_LEN * cfg.num_key_value_heads * cfg.head_dim
+    assert len(_kernel_calls(text, "kv_chunk_attention")) == 1
+    found = _slab_sized_cuts(text, slab) + _slab_sized_layout_copies(
+        text, slab)
+    assert not found, f"the hybrid chunk cuts or copies the slab: {found}"
+    large = [dims for dims in _score_sized_f32(
+        text, cfg.num_attention_heads, CHUNK)
+        if str(cfg.num_attention_heads) in dims.split(",")]
+    assert not large, (
+        f"float32 arrays over the 32 heads as large as a block's scores: "
+        f"{large}")
 
 
 # -- a latent-attention model (PR 31) ---------------------------------------

@@ -351,15 +351,19 @@ def test_blocked_chunk_read_matches_the_full_extent_read(dtype, offset):
 
 
 def test_chunk_read_is_chosen_from_the_shapes_in_hand(monkeypatch):
-    """128 MiB of scores at ``chat-closed``'s shapes: the full extent still;
-    4 GiB at the long cell's largest bucket and anything past 128 MiB there:
-    the walk; rows that are no floats a slot owns: the full extent whatever
-    the size.  Said as a ``read_dispatch`` event."""
+    """With kernels on, a dense float cache in the queries' dtype whose
+    shapes the kernel takes is walked by it at ANY extent - ``chat-closed``'s
+    128 MiB of scores and a 16-row bucket's 4 MiB as the long cell's 4 GiB;
+    what the kernel refuses keeps the rule of the score bytes: a verify's odd
+    row count the full extent within 128 MiB, a bucket of 384 rows the loop
+    past it; rows that are no floats a slot owns: the full extent whatever
+    the size.  With kernels off the score bytes alone decide.  Said as a
+    ``read_dispatch`` and a ``kernel_dispatch`` event."""
     monkeypatch.setenv("APEX_TPU_KERNELS", "interpret")
     seen = []
 
     def sink(event):
-        if event["event"] == "read_dispatch":
+        if event["event"] in ("read_dispatch", "kernel_dispatch"):
             seen.append(event)
 
     def cache_of(max_len, **kw):
@@ -367,25 +371,45 @@ def test_chunk_read_is_chosen_from_the_shapes_in_hand(monkeypatch):
             [kvc.KVRows(4, 128)], slots=1, max_len=max_len,
             dtype=jnp.bfloat16, **kw))
 
-    def q(chunk):
-        return jax.ShapeDtypeStruct((chunk, 1, 32, 128), jnp.bfloat16)
+    def q(chunk, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((chunk, 1, 32, 128), dtype)
 
     _logging.add_event_sink(sink)
     try:
-        assert not kvc._walks_blocks(cache_of(2048), q(512))
-        assert kvc._walks_blocks(cache_of(32768), q(1024))
-        assert [kvc._walks_blocks(cache_of(32768), q(b))
-                for b in (16, 32, 64, 512)] == [False, False, True, True]
-        del seen[2:]
-        assert not kvc._walks_blocks(cache_of(32768, int8=True), q(1024))
-        assert not kvc._walks_blocks(cache_of(
-            32768, paged=sv.PagedCacheConfig(block_size=128)), q(1024))
+        assert kvc._prefill_read(cache_of(2048), q(512)) == "kernel"
+        assert kvc._prefill_read(cache_of(32768), q(1024)) == "kernel"
+        assert {kvc._prefill_read(cache_of(n), q(b)) for n in (2048, 32768)
+                for b in (16, 32, 64, 128, 256, 512)} == {"kernel"}
+        del seen[4:]
+        assert kvc._prefill_read(cache_of(2048), q(9)) == "full_extent"
+        assert kvc._prefill_read(cache_of(32768), q(384)) == "loop"
+        assert kvc._prefill_read(cache_of(2048), q(512, jnp.float32)) == (
+            "full_extent")
+        assert kvc._prefill_read(cache_of(32768, int8=True), q(1024)) == (
+            "full_extent")
+        assert kvc._prefill_read(cache_of(
+            32768, paged=sv.PagedCacheConfig(block_size=128)), q(1024)) == (
+            "full_extent")
+        told = [(e["event"], e["op"], e["path"]) for e in seen]
+        del seen[:]
+        monkeypatch.setenv("APEX_TPU_KERNELS", "0")
+        assert kvc._prefill_read(cache_of(2048), q(512)) == "full_extent"
+        assert [kvc._prefill_read(cache_of(32768), q(b))
+                for b in (16, 32, 64, 512)] == [
+            "full_extent", "full_extent", "loop", "loop"]
+        assert seen == []
     finally:
         _logging.remove_event_sink(sink)
-    assert [(e["op"], e["path"], e["score_bytes"]) for e in seen[:2]] == [
-        ("prefill_attend", "full_extent", 32 * 512 * 2048 * 4),
-        ("prefill_attend", "blocked_walk", 32 * 1024 * 32768 * 4)]
-    assert [e["path"] for e in seen[2:]] == ["full_extent"] * 2
+    kernel = ("kernel_dispatch", "kv_chunk_attention")
+    read = ("read_dispatch", "prefill_attend")
+    assert told == [
+        kernel + ("pallas",), read + ("blocked_walk",),
+        kernel + ("pallas",), read + ("blocked_walk",),
+        kernel + ("reference",), read + ("full_extent",),
+        kernel + ("reference",), read + ("blocked_walk",),
+        kernel + ("reference",), read + ("full_extent",),
+        kernel + ("reference",), read + ("full_extent",),
+        kernel + ("reference",), read + ("full_extent",)]
     assert 32 * 512 * 2048 * 4 == kvc._FULL_READ_BYTES == 1 << 27
 
 

@@ -575,7 +575,7 @@ layers reach it through the seam's functions and name no cache class:
 
 | declaration | what a slot keeps | the seam | served families |
 |---|---|---|---|
-| `KVRows(kv_heads, head_dim)` | `[max_len, kv_heads, head_dim]` K and V | `decode_attend` / `prefill_attend` (a chunk's read is one of two, chosen from the shapes in hand and said by a `read_dispatch` event: the whole masked extent while its float32 scores stay within 128 MiB - 32 heads x 512 x 2,048 -, a walk over the visible blocks that stops at the chunk's end past that - 4 GiB at 32 x 1,024 x 32,768; on a TPU the walk is the Pallas kernel `ops.kv_chunk_attention` over the slot's rows cut head-major once a call, elsewhere a loop: the `kernel_dispatch` event `kv_chunk_attention` says which) | `models.llama`, `models.nemotron_h` (`*` layers), `models.mellum` (`full_attention` layers) |
+| `KVRows(kv_heads, head_dim)` | `[max_len, kv_heads, head_dim]` K and V | `decode_attend` / `prefill_attend` (a chunk's read is one of three, chosen once a trace from what is in hand: on a TPU, a dense float cache in the queries' dtype with heads, key blocks and chunk in whole tiles is walked a visible block at a time by the Pallas kernel `ops.kv_chunk_attention` over the slot's rows cut head-major once a call, at any extent - a 2,048-row slot's chunks as a 32,768-row slot's; everything else - the CPU, int8 rows, a block table, 64-wide heads, a verify's odd row count - attends the whole masked extent while its float32 scores stay within 128 MiB - 32 heads x 512 x 2,048 - and past that walks the visible blocks in a loop; the `kernel_dispatch` event `kv_chunk_attention` says kernel or not, the `read_dispatch` event `blocked_walk` or `full_extent`) | `models.llama`, `models.nemotron_h` (`*` layers), `models.mellum` (`full_attention` layers) |
 | `KVWindowRows(kv_heads, head_dim, window)` | a ring of `window` K and V rows in whole 16-row tiles, position `p` at row `p mod rows`, whatever `max_len` | `window_decode_attend` (append at `position mod rows`, then the in-place decode kernel on the ring buffers where the ring is exactly the window, else the ring under a mask) / `window_prefill_attend` (the `window - 1` rows before the chunk from the ring, the chunk's own from the ones in hand, then the chunk's last real rows into the ring) | `models.mellum` (`sliding_attention` layers) |
 | `RecurrentRows(ssm, conv)` | a float32 state and a convolution tail of fixed size | `slot_state` / `write_slot_state` / `write_lane_state` | `models.nemotron_h` (`M` layers) |
 | `LatentRows(width, index_width, top_k)` | `[max_len, width]` latent rows (the compressed K/V and the shared rope key, stored in whole lane tiles) and `[max_len, index_width]` selector keys | `latent_decode_attend` (append, score the live rows' keys, `top_k`, gather, absorbed read) / `latent_prefill_attend` (chunk-write, blocked scores, the selection as a mask, blocked explicit read: on a TPU one Pallas kernel, `ops.latent_chunk_attention`, over the rows in place with a block's scores in fast memory, elsewhere a loop; the `kernel_dispatch` event `latent_chunk_attention` says which) | `models.dots3` (`full_attention` layers) |

@@ -10,7 +10,10 @@ Nothing runs and no weight is made: each engine is built on one dummy leaf
 and its jitted programs are lowered on shapes - the cell's cache, the
 configuration's parameters, every prefill bucket - once as the CPU traces
 them (every ``jax.numpy`` reference) and once as the chip does (kernels
-dispatched, shapes placed on a described v5e)."""
+dispatched, shapes placed on a described v5e).  A Pallas kernel's serialized
+body carries its source's path: compare the ``tpu`` files of two checkouts
+after ``sed -E 's/\\22body\\22: \\22[^\\]*\\22/BODY/g'`` (or with both trees
+unpacked at one path in turn)."""
 
 import importlib
 import json
@@ -62,13 +65,19 @@ def main(root: str, out: str) -> None:
                 l.shape, wdtype if len(l.shape) >= 2 else jnp.float32),
                 shapes)
         sizes = traffic["engine"]
-        engine = sv.DecodeEngine(
-            model, {"w": jnp.zeros((1,), wdtype)}, slots=2,
-            max_len=sizes["prefill_len"], prefill_len=sizes["prefill_len"])
         cache = jax.eval_shape(lambda: init_cache(
             model.cache_layers(), slots=sizes["slots"],
             max_len=sizes["max_len"], dtype=wdtype))
         for where in ("cpu", "tpu"):
+            # an engine of its own each way: jit keeps a program's trace by
+            # the shapes it was handed, not by where they are placed, and a
+            # second lowering of one engine is the first one's trace again
+            # (until PR 34 the "tpu" files held the CPU's references)
+            engine = sv.DecodeEngine(
+                model, {"w": jnp.zeros((1,), wdtype)}, slots=2,
+                max_len=sizes["prefill_len"],
+                prefill_len=sizes["prefill_len"])
+
             def place(l):
                 return jax.ShapeDtypeStruct(
                     l.shape, l.dtype,

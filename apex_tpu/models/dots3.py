@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.normalization import FusedRMSNorm
+from apex_tpu.obs.scopes import ATTN_PROJ, EMBED, HEAD, MLP, NORM, component
 from apex_tpu.ops.rope import fused_apply_rotary_pos_emb
 from apex_tpu.transformer.moe import MOE_COUNTERS, GatedMoE
 from apex_tpu.transformer.parallel_state import TENSOR_PARALLEL_AXIS
@@ -169,7 +170,7 @@ class LatentAttention(nn.Module):
     params_dtype: Any = jnp.float32
 
     @nn.compact
-    @jax.named_scope("latent_attention")
+    @component(ATTN_PROJ)
     def __call__(self, x, *, kv_cache=None, layer_idx=None, position=None,
                  slot=None, length=None):
         cfg = self.config
@@ -322,6 +323,7 @@ class GatedMLP(nn.Module):
     params_dtype: Any = jnp.float32
 
     @nn.compact
+    @component(MLP)
     def __call__(self, x):
         hid = (jax.nn.silu(_dense(self.width, x, self.params_dtype,
                                   "gate_proj"))
@@ -342,8 +344,9 @@ class Dots3NoteLayer(nn.Module):
         cfg, i = self.config, self.layer
 
         def norm(t, name):
-            return FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
-                                param_dtype=jnp.float32, name=name)(t)
+            with component(NORM):
+                return FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
+                                    param_dtype=jnp.float32, name=name)(t)
 
         out, kv_cache = LatentAttention(
             cfg, cfg.layer_types[i], params_dtype=self.params_dtype,
@@ -351,11 +354,16 @@ class Dots3NoteLayer(nn.Module):
             norm(x, "input_layernorm"), kv_cache=kv_cache,
             layer_idx=cfg.index_among(i), position=position, slot=slot,
             length=length)
-        x = x + out.astype(x.dtype)
+        # a residual add is the root of the fusion XLA makes of it and the
+        # product before it: it counts with the branch it closes
+        with component(ATTN_PROJ):
+            x = x + out.astype(x.dtype)
         h = norm(x, "post_attention_layernorm")
         if i < cfg.first_k_dense_replace:
-            return x + GatedMLP(cfg.intermediate_size, self.params_dtype,
-                                name="mlp")(h).astype(x.dtype), kv_cache
+            with component(MLP):
+                return x + GatedMLP(
+                    cfg.intermediate_size, self.params_dtype,
+                    name="mlp")(h).astype(x.dtype), kv_cache
         s, lanes, _ = x.shape
         decode = kv_cache is not None and s == 1
         # rows are s-major: a decode step's are its lanes, a chunk's (one
@@ -378,7 +386,8 @@ class Dots3NoteLayer(nn.Module):
             from apex_tpu.serving.kv_cache import add_counts
 
             kv_cache = add_counts(kv_cache, cfg.expert_index(i), counts)
-        return x + out.reshape(s, lanes, -1).astype(x.dtype), kv_cache
+        with component(MLP):
+            return x + out.reshape(s, lanes, -1).astype(x.dtype), kv_cache
 
 
 class Dots3NoteForCausalLM(nn.Module):
@@ -429,19 +438,23 @@ class Dots3NoteForCausalLM(nn.Module):
             if s > 1 and length is None:
                 raise ValueError("a prefill chunk needs length= (its real "
                                  "rows: a window ring keeps no padding)")
-        x = VocabParallelEmbedding(
-            cfg.vocab_size, cfg.hidden_size, params_dtype=self.params_dtype,
-            axis_name=self.axis_name, name="embed_tokens")(input_ids)
-        x = x.transpose(1, 0, 2)                           # [s, b, h]
+        with component(EMBED):
+            x = VocabParallelEmbedding(
+                cfg.vocab_size, cfg.hidden_size,
+                params_dtype=self.params_dtype, axis_name=self.axis_name,
+                name="embed_tokens")(input_ids)
+            x = x.transpose(1, 0, 2)                       # [s, b, h]
         for i in range(cfg.num_hidden_layers):
             x, kv_cache = Dots3NoteLayer(
                 cfg, i, params_dtype=self.params_dtype, name=f"layers_{i}")(
                 x, kv_cache=kv_cache, position=position, slot=slot,
                 length=length, active=active)
-        x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
-                         param_dtype=jnp.float32, name="norm")(x)
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.vocab_size, cfg.hidden_size),
-                          self.params_dtype)
-        logits = parallel_lm_logits(x, head.astype(x.dtype), self.axis_name)
+        with component(HEAD):
+            x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
+                             param_dtype=jnp.float32, name="norm")(x)
+            head = self.param("lm_head", nn.initializers.normal(0.02),
+                              (cfg.vocab_size, cfg.hidden_size),
+                              self.params_dtype)
+            logits = parallel_lm_logits(x, head.astype(x.dtype),
+                                        self.axis_name)
         return logits if kv_cache is None else (logits, kv_cache)

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from apex_tpu.normalization import FusedRMSNorm
+from apex_tpu.obs.scopes import ATTN_PROJ, EMBED, HEAD, MLP, NORM, component
 from apex_tpu.ops.flash_attention import flash_attention
 from apex_tpu.ops.rope import fused_apply_rotary_pos_emb
 from apex_tpu.transformer.parallel_state import TENSOR_PARALLEL_AXIS
@@ -177,7 +178,7 @@ class LlamaMLP(nn.Module):
     axis_name: str = TENSOR_PARALLEL_AXIS
 
     @nn.compact
-    @jax.named_scope("llama_mlp")
+    @component(MLP)
     def __call__(self, x):
         cfg = self.config
         common = dict(sequence_parallel_enabled=self.sequence_parallel_enabled,
@@ -213,7 +214,7 @@ class LlamaAttention(nn.Module):
     axis_name: str = TENSOR_PARALLEL_AXIS
 
     @nn.compact
-    @jax.named_scope("llama_attention")
+    @component(ATTN_PROJ)
     def __call__(self, x, deterministic: bool = True, *, kv_cache=None,
                  layer_idx: Optional[int] = None, position=None, slot=None):
         """Causal self-attention; optionally reading/writing a KV cache.
@@ -335,9 +336,10 @@ class LlamaDecoderLayer(nn.Module):
     def __call__(self, x, deterministic: bool = True, *, kv_cache=None,
                  layer_idx: Optional[int] = None, position=None, slot=None):
         cfg = self.config
-        h = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
-                         param_dtype=self.params_dtype,
-                         name="input_layernorm")(x)
+        with component(NORM):
+            h = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
+                             param_dtype=self.params_dtype,
+                             name="input_layernorm")(x)
         attn = LlamaAttention(
             cfg, sequence_parallel_enabled=self.sequence_parallel_enabled,
             params_dtype=self.params_dtype, axis_name=self.axis_name,
@@ -348,14 +350,20 @@ class LlamaDecoderLayer(nn.Module):
                                slot=slot)
         else:
             a = attn(h, deterministic)
-        x = x + a
-        h = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
-                         param_dtype=self.params_dtype,
-                         name="post_attention_layernorm")(x)
-        out = x + LlamaMLP(
-            cfg, sequence_parallel_enabled=self.sequence_parallel_enabled,
-            params_dtype=self.params_dtype, axis_name=self.axis_name,
-            name="mlp")(h)
+        # a residual add is the root of the fusion XLA makes of it and the
+        # product before it: it counts with the branch it closes
+        with component(ATTN_PROJ):
+            x = x + a
+        with component(NORM):
+            h = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
+                             param_dtype=self.params_dtype,
+                             name="post_attention_layernorm")(x)
+        with component(MLP):
+            out = x + LlamaMLP(
+                cfg,
+                sequence_parallel_enabled=self.sequence_parallel_enabled,
+                params_dtype=self.params_dtype, axis_name=self.axis_name,
+                name="mlp")(h)
         if kv_cache is not None:
             return out, kv_cache
         return out
@@ -410,10 +418,12 @@ class LlamaForCausalLM(nn.Module):
         if kv_cache is not None and labels is not None:
             raise ValueError("kv_cache is a serving-mode argument; "
                              "labels is training-only")
-        x = VocabParallelEmbedding(
-            cfg.vocab_size, cfg.hidden_size, params_dtype=self.params_dtype,
-            axis_name=self.axis_name, name="embed_tokens")(input_ids)
-        x = x.transpose(1, 0, 2)  # [s, b, h]
+        with component(EMBED):
+            x = VocabParallelEmbedding(
+                cfg.vocab_size, cfg.hidden_size,
+                params_dtype=self.params_dtype, axis_name=self.axis_name,
+                name="embed_tokens")(input_ids)
+            x = x.transpose(1, 0, 2)  # [s, b, h]
         if self.sequence_parallel_enabled:
             from apex_tpu.transformer.tensor_parallel import (
                 scatter_to_sequence_parallel_region,
@@ -438,28 +448,30 @@ class LlamaForCausalLM(nn.Module):
                                     slot=slot)
             else:
                 x = layer(x, deterministic)
-        x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
-                         param_dtype=self.params_dtype, name="norm")(x)
+        with component(HEAD):
+            x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
+                             param_dtype=self.params_dtype, name="norm")(x)
 
-        if cfg.tie_word_embeddings:
-            head = self.variables["params"]["embed_tokens"]["embedding"]
-        else:
-            # vocab-sharded like the embedding table ([vocab/tp, h] per rank)
-            head = self.param(
-                "lm_head",
-                shard_init(nn.initializers.normal(0.02), self.axis_name),
-                (divide(cfg.vocab_size, tp_world_size(self.axis_name)),
-                 cfg.hidden_size), self.params_dtype)
+            if cfg.tie_word_embeddings:
+                head = self.variables["params"]["embed_tokens"]["embedding"]
+            else:
+                # vocab-sharded like the embedding table ([vocab/tp, h]
+                # per rank)
+                head = self.param(
+                    "lm_head",
+                    shard_init(nn.initializers.normal(0.02), self.axis_name),
+                    (divide(cfg.vocab_size, tp_world_size(self.axis_name)),
+                     cfg.hidden_size), self.params_dtype)
 
-        if (labels is not None and tp_world_size(self.axis_name) == 1
-                and not self.sequence_parallel_enabled):
-            from apex_tpu.ops.fused_lm_head import fused_lm_head_loss
+            if (labels is not None and tp_world_size(self.axis_name) == 1
+                    and not self.sequence_parallel_enabled):
+                from apex_tpu.ops.fused_lm_head import fused_lm_head_loss
 
-            loss = fused_lm_head_loss(x, head.astype(x.dtype), labels.T)
-            return loss.T
-        logits = parallel_lm_logits(
-            x, head.astype(x.dtype), self.axis_name,
-            sequence_parallel_enabled=self.sequence_parallel_enabled)
+                loss = fused_lm_head_loss(x, head.astype(x.dtype), labels.T)
+                return loss.T
+            logits = parallel_lm_logits(
+                x, head.astype(x.dtype), self.axis_name,
+                sequence_parallel_enabled=self.sequence_parallel_enabled)
         if kv_cache is not None:
             return logits, kv_cache
         if labels is None:

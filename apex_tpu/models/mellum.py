@@ -37,6 +37,14 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.normalization import FusedRMSNorm
+from apex_tpu.obs.scopes import (
+    ATTN_PROJ,
+    EMBED,
+    EXPERTS,
+    HEAD,
+    NORM,
+    component,
+)
 from apex_tpu.ops.rope import (
     fused_apply_rotary_pos_emb_cached,
     yarn_inv_freq,
@@ -172,7 +180,7 @@ class MellumAttention(nn.Module):
     params_dtype: Any = jnp.float32
 
     @nn.compact
-    @jax.named_scope("mellum_attention")
+    @component(ATTN_PROJ)
     def __call__(self, x, *, kv_cache=None, layer_idx=None, position=None,
                  slot=None, length=None):
         cfg = self.config
@@ -249,8 +257,9 @@ class MellumLayer(nn.Module):
         cfg, i = self.config, self.layer
 
         def norm(t, name):
-            return FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
-                                param_dtype=jnp.float32, name=name)(t)
+            with component(NORM):
+                return FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
+                                    param_dtype=jnp.float32, name=name)(t)
 
         out, kv_cache = MellumAttention(
             cfg, cfg.layer_types[i], params_dtype=self.params_dtype,
@@ -258,7 +267,10 @@ class MellumLayer(nn.Module):
             norm(x, "input_layernorm"), kv_cache=kv_cache,
             layer_idx=cfg.index_among(i), position=position, slot=slot,
             length=length)
-        x = x + out.astype(x.dtype)
+        # a residual add is the root of the fusion XLA makes of it and the
+        # product before it: it counts with the branch it closes
+        with component(ATTN_PROJ):
+            x = x + out.astype(x.dtype)
         h = norm(x, "post_attention_layernorm")
         s, lanes, _ = x.shape
         decode = kv_cache is not None and s == 1
@@ -280,7 +292,8 @@ class MellumLayer(nn.Module):
             from apex_tpu.serving.kv_cache import add_counts
 
             kv_cache = add_counts(kv_cache, i, counts)
-        return x + out.reshape(s, lanes, -1).astype(x.dtype), kv_cache
+        with component(EXPERTS):
+            return x + out.reshape(s, lanes, -1).astype(x.dtype), kv_cache
 
 
 class MellumForCausalLM(nn.Module):
@@ -327,19 +340,23 @@ class MellumForCausalLM(nn.Module):
             if s > 1 and length is None:
                 raise ValueError("a prefill chunk needs length= (its real "
                                  "rows: a window ring keeps no padding)")
-        x = VocabParallelEmbedding(
-            cfg.vocab_size, cfg.hidden_size, params_dtype=self.params_dtype,
-            axis_name=self.axis_name, name="embed_tokens")(input_ids)
-        x = x.transpose(1, 0, 2)                           # [s, b, h]
+        with component(EMBED):
+            x = VocabParallelEmbedding(
+                cfg.vocab_size, cfg.hidden_size,
+                params_dtype=self.params_dtype, axis_name=self.axis_name,
+                name="embed_tokens")(input_ids)
+            x = x.transpose(1, 0, 2)                       # [s, b, h]
         for i in range(cfg.num_hidden_layers):
             x, kv_cache = MellumLayer(
                 cfg, i, params_dtype=self.params_dtype, name=f"layers_{i}")(
                 x, kv_cache=kv_cache, position=position, slot=slot,
                 length=length, active=active)
-        x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
-                         param_dtype=jnp.float32, name="norm")(x)
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.vocab_size, cfg.hidden_size),
-                          self.params_dtype)
-        logits = parallel_lm_logits(x, head.astype(x.dtype), self.axis_name)
+        with component(HEAD):
+            x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
+                             param_dtype=jnp.float32, name="norm")(x)
+            head = self.param("lm_head", nn.initializers.normal(0.02),
+                              (cfg.vocab_size, cfg.hidden_size),
+                              self.params_dtype)
+            logits = parallel_lm_logits(x, head.astype(x.dtype),
+                                        self.axis_name)
         return logits if kv_cache is None else (logits, kv_cache)

@@ -38,6 +38,15 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.normalization import FusedRMSNorm
+from apex_tpu.obs.scopes import (
+    ATTN_PROJ,
+    EMBED,
+    HEAD,
+    MLP,
+    NORM,
+    STATE,
+    component,
+)
 from apex_tpu.transformer.moe import MOE_COUNTERS, LatentMoE
 from apex_tpu.transformer.parallel_state import TENSOR_PARALLEL_AXIS
 from apex_tpu.transformer.tensor_parallel import (
@@ -213,7 +222,7 @@ class Mamba2Mixer(nn.Module):
     params_dtype: Any = jnp.float32
 
     @nn.compact
-    @jax.named_scope("mamba2_mixer")
+    @component(STATE)
     def __call__(self, u, *, kv_cache=None, layer_idx=None, position=None,
                  slot=None, length=None, active=None):
         cfg = self.config
@@ -228,9 +237,12 @@ class Mamba2Mixer(nn.Module):
                 name, lambda key, sh, d: mamba2_init(key, sh, d, what=name),
                 (n_head,), jnp.float32)
 
-        proj = nn.Dense(d_inner + conv_dim + n_head, use_bias=False,
-                        dtype=u.dtype, param_dtype=self.params_dtype,
-                        kernel_init=normal, name="in_proj")(u)
+        # the mixer's two products count with the other projections; what
+        # is left under ``apex.state`` is the recurrence and its state
+        with component(ATTN_PROJ):
+            proj = nn.Dense(d_inner + conv_dim + n_head, use_bias=False,
+                            dtype=u.dtype, param_dtype=self.params_dtype,
+                            kernel_init=normal, name="in_proj")(u)
         z, xbc, dt = jnp.split(proj, [d_inner, d_inner + conv_dim], axis=-1)
         conv = self.param("conv1d", lambda key, sh, d: {
             "kernel": normal(key, sh, d), "bias": jnp.zeros(sh[1:], d)},
@@ -301,9 +313,10 @@ class Mamba2Mixer(nn.Module):
         scale = self.param("norm", lambda key, sh, d: {
             "scale": jnp.ones(sh, d)}, (d_inner,), jnp.float32)["scale"]
         y = (y.reshape(s, lanes, d_inner) * scale).astype(u.dtype)
-        out = nn.Dense(cfg.hidden_size, use_bias=False, dtype=u.dtype,
-                       param_dtype=self.params_dtype, kernel_init=normal,
-                       name="out_proj")(y)
+        with component(ATTN_PROJ):
+            out = nn.Dense(cfg.hidden_size, use_bias=False, dtype=u.dtype,
+                           param_dtype=self.params_dtype, kernel_init=normal,
+                           name="out_proj")(y)
         return out, kv_cache
 
 
@@ -318,7 +331,7 @@ class NemotronHAttention(nn.Module):
     axis_name: str = TENSOR_PARALLEL_AXIS
 
     @nn.compact
-    @jax.named_scope("nemotron_h_attention")
+    @component(ATTN_PROJ)
     def __call__(self, x, *, kv_cache=None, layer_idx=None, position=None,
                  slot=None):
         cfg = self.config
@@ -377,8 +390,9 @@ class NemotronHLayer(nn.Module):
     def __call__(self, x, *, kv_cache=None, layer_idx=None, position=None,
                  slot=None, length=None, active=None):
         cfg = self.config
-        h = FusedRMSNorm((cfg.hidden_size,), eps=cfg.layer_norm_epsilon,
-                         param_dtype=jnp.float32, name="norm")(x)
+        with component(NORM):
+            h = FusedRMSNorm((cfg.hidden_size,), eps=cfg.layer_norm_epsilon,
+                             param_dtype=jnp.float32, name="norm")(x)
         s, lanes, _ = x.shape
         decode = kv_cache is not None and s == 1
         if self.kind == "M":
@@ -416,7 +430,10 @@ class NemotronHLayer(nn.Module):
                 from apex_tpu.serving.kv_cache import add_counts
 
                 kv_cache = add_counts(kv_cache, layer_idx, counts)
-        return x + out.astype(x.dtype), kv_cache
+        # a residual add is the root of the fusion XLA makes of it and the
+        # product before it: it counts with the branch it closes
+        with component(MLP if self.kind == "E" else ATTN_PROJ):
+            return x + out.astype(x.dtype), kv_cache
 
 
 class NemotronHForCausalLM(nn.Module):
@@ -465,20 +482,24 @@ class NemotronHForCausalLM(nn.Module):
                 raise ValueError("a prefill chunk needs length= (its real "
                                  "rows: padding must not advance a "
                                  "recurrent state)")
-        x = VocabParallelEmbedding(
-            cfg.vocab_size, cfg.hidden_size, params_dtype=self.params_dtype,
-            axis_name=self.axis_name, name="embed_tokens")(input_ids)
-        x = x.transpose(1, 0, 2)                           # [s, b, h]
+        with component(EMBED):
+            x = VocabParallelEmbedding(
+                cfg.vocab_size, cfg.hidden_size,
+                params_dtype=self.params_dtype, axis_name=self.axis_name,
+                name="embed_tokens")(input_ids)
+            x = x.transpose(1, 0, 2)                       # [s, b, h]
         for i, kind in enumerate(cfg.hybrid_override_pattern):
             x, kv_cache = NemotronHLayer(
                 cfg, kind, params_dtype=self.params_dtype,
                 axis_name=self.axis_name, name=f"layers_{i}")(
                 x, kv_cache=kv_cache, layer_idx=cfg.index_among(i),
                 position=position, slot=slot, length=length, active=active)
-        x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.layer_norm_epsilon,
-                         param_dtype=jnp.float32, name="norm_f")(x)
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.vocab_size, cfg.hidden_size),
-                          self.params_dtype)
-        logits = parallel_lm_logits(x, head.astype(x.dtype), self.axis_name)
+        with component(HEAD):
+            x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.layer_norm_epsilon,
+                             param_dtype=jnp.float32, name="norm_f")(x)
+            head = self.param("lm_head", nn.initializers.normal(0.02),
+                              (cfg.vocab_size, cfg.hidden_size),
+                              self.params_dtype)
+            logits = parallel_lm_logits(x, head.astype(x.dtype),
+                                        self.axis_name)
         return logits if kv_cache is None else (logits, kv_cache)

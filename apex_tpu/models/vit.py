@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from apex_tpu.normalization import FusedLayerNorm
+from apex_tpu.obs.scopes import ATTN_PROJ, component
 from apex_tpu.transformer.parallel_state import TENSOR_PARALLEL_AXIS
 from apex_tpu.transformer.tensor_parallel import (
     ColumnParallelLinear,
@@ -72,7 +73,7 @@ class ViTSelfAttention(nn.Module):
     axis_name: str = TENSOR_PARALLEL_AXIS
 
     @nn.compact
-    @jax.named_scope("vit_attention")
+    @component(ATTN_PROJ)
     def __call__(self, x):
         cfg = self.config
         world = tp_world_size(self.axis_name)

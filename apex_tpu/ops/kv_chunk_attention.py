@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from apex_tpu.obs.scopes import CACHE_READ, component
 from apex_tpu.ops._dispatch import use_interpret
 from apex_tpu.ops.flash_attention import _NEG_INF
 
@@ -165,8 +166,11 @@ def kv_chunk_attention(q, k, v, offset, blocks, *, block: int,
 # a function of its own under ``jit``: a program calls the kernel once a
 # layer with the same shapes, and traces and lowers it once - sixteen traces
 # of the unrolled body a program, six programs an engine, were 9 s of a
-# warm start-up (PERF.md section 6, PR 34)
+# warm start-up (PERF.md section 6, PR 34).  Lowered once, its instructions
+# carry the first call site's scope path: the component is opened in here as
+# well as around the call, so that it never depends on who called first
 @functools.partial(jax.jit, static_argnames=("block", "window", "interpret"))
+@component(CACHE_READ)
 def _call(q, k, v, offset, blocks, first, *, block: int, window: int,
           interpret: bool):
     from jax.experimental.pallas import tpu as pltpu

@@ -73,6 +73,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from apex_tpu._logging import emit_event, get_logger
 from apex_tpu.obs import trace as obs_trace
+from apex_tpu.obs.scopes import CACHE_WRITE, HEAD, SAMPLE, component
 from apex_tpu.serving.kv_cache import (
     CallCounters,
     KVCache,
@@ -151,6 +152,7 @@ def tp_param_shardings(params, mesh) -> "jax.tree_util.PyTreeDef":
             path, SERVING_TP_AXIS)), params)
 
 
+@component(SAMPLE)
 def _sample_one(logits, base_key, index, temperature, top_k):
     """One token from one ``[vocab]`` logits row — fully traced, so the
     vmapped form never retraces on per-request sampling params.
@@ -497,9 +499,10 @@ class DecodeEngine:
                                         slot=slot, position=offset,
                                         length=length)
             cache = commit_slot_length(cache, slot, offset + length)
-            last = lax.dynamic_index_in_dim(logits[:, 0, :], length - 1,
-                                            axis=0, keepdims=False)
-            return last.astype(jnp.float32), cache
+            with component(HEAD):
+                last = lax.dynamic_index_in_dim(logits[:, 0, :], length - 1,
+                                                axis=0, keepdims=False)
+                return last.astype(jnp.float32), cache
 
         def _decode(params, cache, tokens, active):
             # tokens [slots] int32 (last sampled per slot); active [slots]
@@ -514,10 +517,12 @@ class DecodeEngine:
             logits, cache = model.apply(dq(params), tokens[:, None],
                                         kv_cache=cache, position=position,
                                         active=active)
-            cache = dataclasses.replace(
-                cache,
-                lengths=cache.lengths + active.astype(jnp.int32))
-            return logits[0].astype(jnp.float32), cache
+            with component(CACHE_WRITE):
+                cache = dataclasses.replace(
+                    cache,
+                    lengths=cache.lengths + active.astype(jnp.int32))
+            with component(HEAD):
+                return logits[0].astype(jnp.float32), cache
 
         def _verify(params, cache, ids, slot, offset, length):
             # ids [1, W] where W = draft_bucket + 1: the slot's PENDING

@@ -81,6 +81,14 @@ from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from apex_tpu.amp.quant import dequantize_int8, quantize_int8
+from apex_tpu.obs.scopes import (
+    CACHE_READ,
+    CACHE_WRITE,
+    ROUTER,
+    SELECT,
+    STATE,
+    component,
+)
 from apex_tpu.ops._dispatch import record_choice, record_dispatch
 from apex_tpu.ops.cached_decode_attention import (
     block_rows,
@@ -420,6 +428,7 @@ def write_slot_region(cache, slot, start, k_region, v_region):
     return _write(cache, (slice(None), s, rows), k_region, v_region)
 
 
+@component(CACHE_WRITE)
 def commit_slot_length(cache, slot, length):
     """Set one slot's valid-token count (``slot``/``length`` may be
     traced scalars) — the single length-commit primitive both write
@@ -615,15 +624,17 @@ def decode_attend(cache, layer: int, q, k, v, position):
     and every layout and format hands back identical values at every
     unmasked position over identical reduction extents - hence
     bit-identical logits dense against paged."""
-    cache = append_token(cache, layer, k[0], v[0], jnp.asarray(position))
-    if _reads_in_place(cache, q):
-        return cached_decode_attention(q.transpose(1, 2, 0, 3), cache.k,
-                                       cache.v, layer, position), cache
-    kc, vc = decode_read(cache, layer)
-    kc = kc.astype(q.dtype)
-    vc = vc.astype(q.dtype)
-    qt = q.transpose(1, 2, 0, 3)                    # [lanes, heads, 1, hd]
-    return decode_attention(qt, kc, vc, position), cache
+    with component(CACHE_WRITE):
+        cache = append_token(cache, layer, k[0], v[0], jnp.asarray(position))
+    with component(CACHE_READ):
+        if _reads_in_place(cache, q):
+            return cached_decode_attention(q.transpose(1, 2, 0, 3), cache.k,
+                                           cache.v, layer, position), cache
+        kc, vc = decode_read(cache, layer)
+        kc = kc.astype(q.dtype)
+        vc = vc.astype(q.dtype)
+        qt = q.transpose(1, 2, 0, 3)                # [lanes, heads, 1, hd]
+        return decode_attention(qt, kc, vc, position), cache
 
 
 # the float32 scores of one full-extent chunk read, ``heads x chunk x max_len
@@ -706,20 +717,23 @@ def prefill_attend(cache, layer: int, slot, q, k, v, offset):
     if b != 1:
         raise ValueError(
             f"prefill expects one slot per call (b=1), got b={b}")
-    cache = prefill_into_slot(cache, layer, slot, k[:, 0], v[:, 0],
-                              start=offset)
+    with component(CACHE_WRITE):
+        cache = prefill_into_slot(cache, layer, slot, k[:, 0], v[:, 0],
+                                  start=offset)
     read = _prefill_read(cache, q)
-    if read == "kernel":
-        return _chunk_kernel(cache, layer, slot, q[:, 0], offset)[None], cache
-    if read == "loop":
-        return _kv_chunk_read(q[:, 0].transpose(1, 0, 2), cache.k, cache.v,
-                              layer, slot, offset)[None], cache
-    kc, vc = slot_read(cache, layer, slot)
-    kc = kc.astype(q.dtype)                         # [max, kv_heads, hd]
-    vc = vc.astype(q.dtype)
-    qt = q.transpose(1, 2, 0, 3)                    # [1, heads, s, hd]
-    bounds = (offset + jnp.arange(s, dtype=jnp.int32))[None]      # [1, s]
-    return cached_attention(qt, kc[None], vc[None], bounds), cache
+    with component(CACHE_READ):
+        if read == "kernel":
+            return _chunk_kernel(cache, layer, slot, q[:, 0],
+                                 offset)[None], cache
+        if read == "loop":
+            return _kv_chunk_read(q[:, 0].transpose(1, 0, 2), cache.k,
+                                  cache.v, layer, slot, offset)[None], cache
+        kc, vc = slot_read(cache, layer, slot)
+        kc = kc.astype(q.dtype)                     # [max, kv_heads, hd]
+        vc = vc.astype(q.dtype)
+        qt = q.transpose(1, 2, 0, 3)                # [1, heads, s, hd]
+        bounds = (offset + jnp.arange(s, dtype=jnp.int32))[None]  # [1, s]
+        return cached_attention(qt, kc[None], vc[None], bounds), cache
 
 
 def _chunk_kernel(cache, layer: int, slot, q, offset):
@@ -1067,6 +1081,7 @@ def init_cache(layers, *, slots: int, max_len: int, dtype=jnp.float32,
         counters=jnp.zeros((n_cnt, len(cnt.names) if cnt else 0), jnp.int32))
 
 
+@component(STATE)
 def slot_state(cache: HybridCache, layer: int, slot, offset):
     """One slot's ``(ssm, conv)`` for one recurrent layer as a chunk at
     ``offset`` must see it: zeros at offset 0 - a slot's next request never
@@ -1088,6 +1103,7 @@ def slot_state(cache: HybridCache, layer: int, slot, offset):
     return one(cache.state.ssm), one(cache.state.conv)
 
 
+@component(CACHE_WRITE)
 def write_slot_state(cache: HybridCache, layer: int, slot, ssm,
                      conv) -> HybridCache:
     """Store one slot's state after a prefill chunk."""
@@ -1098,6 +1114,7 @@ def write_slot_state(cache: HybridCache, layer: int, slot, ssm,
         conv=st.conv.at[layer, s].set(conv.astype(st.conv.dtype))))
 
 
+@component(CACHE_WRITE)
 def write_lane_state(cache: HybridCache, layer: int, ssm, conv,
                      active) -> HybridCache:
     """Store every slot's state after a decode step; lanes not ``active``
@@ -1113,6 +1130,7 @@ def write_lane_state(cache: HybridCache, layer: int, ssm, conv,
         conv=st.conv.at[layer].set(keep(conv, st.conv[layer]))))
 
 
+@component(ROUTER)
 def add_counts(cache, layer: int, counts):
     """Add one call's counts to a counting layer's row."""
     return dataclasses.replace(
@@ -1185,24 +1203,26 @@ def window_decode_attend(cache, layer: int, q, k, v, position, *,
     step reads at most the ring, never ``max_len`` rows."""
     position = jnp.asarray(position, jnp.int32)
     ring = cache.ring_k.shape[2]
-    at = (layer, jnp.arange(position.shape[0], dtype=jnp.int32),
-          position % ring)
-    cache = dataclasses.replace(
-        cache,
-        ring_k=cache.ring_k.at[at].set(k[0].astype(cache.ring_k.dtype)),
-        ring_v=cache.ring_v.at[at].set(v[0].astype(cache.ring_v.dtype)))
-    qt = q.transpose(1, 2, 0, 3)                    # [lanes, heads, 1, hd]
-    if _ring_reads_in_place(cache, q, window):
-        return cached_decode_attention(
-            qt, cache.ring_k, cache.ring_v, layer,
-            jnp.minimum(position, ring - 1)), cache
-    # ring row r holds the last position <= position that is r mod rows
-    row = jnp.arange(ring, dtype=jnp.int32)
-    held = position[:, None] - (position[:, None] - row[None]) % ring
-    seen = (held >= 0) & (held > position[:, None] - window)
-    return _masked_read(qt, cache.ring_k[layer].astype(q.dtype),
-                        cache.ring_v[layer].astype(q.dtype),
-                        seen[:, None]), cache
+    with component(CACHE_WRITE):
+        at = (layer, jnp.arange(position.shape[0], dtype=jnp.int32),
+              position % ring)
+        cache = dataclasses.replace(
+            cache,
+            ring_k=cache.ring_k.at[at].set(k[0].astype(cache.ring_k.dtype)),
+            ring_v=cache.ring_v.at[at].set(v[0].astype(cache.ring_v.dtype)))
+    with component(CACHE_READ):
+        qt = q.transpose(1, 2, 0, 3)                # [lanes, heads, 1, hd]
+        if _ring_reads_in_place(cache, q, window):
+            return cached_decode_attention(
+                qt, cache.ring_k, cache.ring_v, layer,
+                jnp.minimum(position, ring - 1)), cache
+        # ring row r holds the last position <= position that is r mod rows
+        row = jnp.arange(ring, dtype=jnp.int32)
+        held = position[:, None] - (position[:, None] - row[None]) % ring
+        seen = (held >= 0) & (held > position[:, None] - window)
+        return _masked_read(qt, cache.ring_k[layer].astype(q.dtype),
+                            cache.ring_v[layer].astype(q.dtype),
+                            seen[:, None]), cache
 
 
 def window_prefill_attend(cache, layer: int, slot, q, k, v, offset, length,
@@ -1223,42 +1243,44 @@ def window_prefill_attend(cache, layer: int, slot, q, k, v, offset, length,
     before = -(-(window - 1) // 8) * 8
     p_before = offset - before + jnp.arange(before, dtype=jnp.int32)
     mine = offset + jnp.arange(s, dtype=jnp.int32)
-    kc, vc = (jnp.concatenate(
-        [buf[layer, slot, p_before % ring].astype(q.dtype), rows[:, 0]])
-        for buf, rows in ((cache.ring_k, k), (cache.ring_v, v)))
-    heads, hd = q.shape[2:]
-    block = min(512, -(-(before + s) // 128) * 128)
-    shape = dict(m=s, hd=hd, block=block, max_len=block)
-    if record_dispatch("kv_chunk_attention", chunk_kernel_takes(**shape),
-                       heads=heads, kv_heads=k.shape[2], window=window,
-                       **shape):
-        # the same walk as a full layer's chunk, over this short extent in
-        # whole blocks: key j holds position offset - before + j
-        pad = -(before + s) % block
-        kt, vt = (jnp.pad(rows, ((0, pad), (0, 0), (0, 0))).transpose(1, 0, 2)
-                  for rows in (kc, vc))
-        ctx = kv_chunk_attention(
-            q[:, 0], kt, vt, before, (before + s + pad) // block,
-            block=block, window=window,
-            first=jnp.maximum(before - offset, 0))
-        ctx = ctx.astype(q.dtype).transpose(1, 0, 2)[None]
-    else:
-        at = jnp.concatenate([p_before, mine])
-        seen = ((at[None] >= 0) & (at[None] <= mine[:, None])
-                & (at[None] > mine[:, None] - window))
-        ctx = _masked_read(q.transpose(1, 2, 0, 3), kc[None], vc[None],
-                           seen[None])
+    with component(CACHE_READ):
+        kc, vc = (jnp.concatenate(
+            [buf[layer, slot, p_before % ring].astype(q.dtype), rows[:, 0]])
+            for buf, rows in ((cache.ring_k, k), (cache.ring_v, v)))
+        heads, hd = q.shape[2:]
+        block = min(512, -(-(before + s) // 128) * 128)
+        shape = dict(m=s, hd=hd, block=block, max_len=block)
+        if record_dispatch("kv_chunk_attention", chunk_kernel_takes(**shape),
+                           heads=heads, kv_heads=k.shape[2], window=window,
+                           **shape):
+            # the same walk as a full layer's chunk, over this short extent
+            # in whole blocks: key j holds position offset - before + j
+            pad = -(before + s) % block
+            kt, vt = (jnp.pad(rows, ((0, pad), (0, 0), (0, 0))).transpose(
+                1, 0, 2) for rows in (kc, vc))
+            ctx = kv_chunk_attention(
+                q[:, 0], kt, vt, before, (before + s + pad) // block,
+                block=block, window=window,
+                first=jnp.maximum(before - offset, 0))
+            ctx = ctx.astype(q.dtype).transpose(1, 0, 2)[None]
+        else:
+            at = jnp.concatenate([p_before, mine])
+            seen = ((at[None] >= 0) & (at[None] <= mine[:, None])
+                    & (at[None] > mine[:, None] - window))
+            ctx = _masked_read(q.transpose(1, 2, 0, 3), kc[None], vc[None],
+                               seen[None])
     # only real rows, and of more than a ring's worth only the last: one
     # scatter writes no ring row twice
-    n = jnp.arange(s, dtype=jnp.int32)
-    keep = (n < length) & (n >= length - ring)
-    to = (layer, slot, jnp.where(keep, mine % ring, ring))
-    return ctx, dataclasses.replace(
-        cache,
-        ring_k=cache.ring_k.at[to].set(k[:, 0].astype(cache.ring_k.dtype),
-                                       mode="drop"),
-        ring_v=cache.ring_v.at[to].set(v[:, 0].astype(cache.ring_v.dtype),
-                                       mode="drop"))
+    with component(CACHE_WRITE):
+        n = jnp.arange(s, dtype=jnp.int32)
+        keep = (n < length) & (n >= length - ring)
+        to = (layer, slot, jnp.where(keep, mine % ring, ring))
+        return ctx, dataclasses.replace(
+            cache,
+            ring_k=cache.ring_k.at[to].set(
+                k[:, 0].astype(cache.ring_k.dtype), mode="drop"),
+            ring_v=cache.ring_v.at[to].set(
+                v[:, 0].astype(cache.ring_v.dtype), mode="drop"))
 
 
 # ---- latent rows: what a latent-attention layer keeps, and its seam --------
@@ -1437,9 +1459,10 @@ def latent_decode_attend(cache, layer: int, q, row, position, *,
     keys (blocks up to the longest lane's length) and one float32 score a
     row; the latent rows it reads are the ``top_k`` it gathers."""
     position = jnp.asarray(position, jnp.int32)
-    cache = dataclasses.replace(
-        cache, latent=_lane_write(cache.latent, layer, position, row),
-        index=_lane_write(cache.index, layer, position, select["key"]))
+    with component(CACHE_WRITE):
+        cache = dataclasses.replace(
+            cache, latent=_lane_write(cache.latent, layer, position, row),
+            index=_lane_write(cache.index, layer, position, select["key"]))
     lanes, max_len = position.shape[0], cache.max_len
     block = _key_block(max_len)
     width = cache.index.shape[-1]
@@ -1452,19 +1475,22 @@ def latent_decode_attend(cache, layer: int, q, row, position, *,
                              select["scale"])[:, 0]
         return lax.dynamic_update_slice(scores, part, (0, i * block))
 
-    scores = lax.fori_loop(
-        0, jnp.minimum(jnp.max(position) // block + 1, max_len // block),
-        score,
-        jnp.full((lanes, max_len), -jnp.inf, jnp.float32))
-    col = jnp.arange(max_len, dtype=jnp.int32)
-    index, chosen = _select(scores, col[None] <= position[:, None],
-                            select["top_k"])
-    rows = cache.latent[layer, jnp.arange(lanes)[:, None], index]
-    rows = rows.astype(q.dtype)[:, :, None]          # [lanes, k, 1, stored]
-    # the query takes the stored row's zeros rather than the rows a slice
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, rows.shape[-1] - q.shape[-1])))
-    ctx = _attend(q[:, None], rows, rows[..., :rank], chosen[:, None], scale)
-    return ctx[:, 0], cache
+    with component(SELECT):
+        scores = lax.fori_loop(
+            0, jnp.minimum(jnp.max(position) // block + 1, max_len // block),
+            score,
+            jnp.full((lanes, max_len), -jnp.inf, jnp.float32))
+        col = jnp.arange(max_len, dtype=jnp.int32)
+        index, chosen = _select(scores, col[None] <= position[:, None],
+                                select["top_k"])
+        rows = cache.latent[layer, jnp.arange(lanes)[:, None], index]
+    with component(CACHE_READ):
+        rows = rows.astype(q.dtype)[:, :, None]      # [lanes, k, 1, stored]
+        # the query takes the stored row's zeros rather than the rows a slice
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, rows.shape[-1] - q.shape[-1])))
+        ctx = _attend(q[:, None], rows, rows[..., :rank], chosen[:, None],
+                      scale)
+        return ctx[:, 0], cache
 
 
 def ring_decode_attend(cache, layer: int, q, row, position, *, scale: float,
@@ -1476,16 +1502,19 @@ def ring_decode_attend(cache, layer: int, q, row, position, *, scale: float,
     cache)``."""
     position = jnp.asarray(position, jnp.int32)
     ring = cache.ring.shape[2]
-    cache = dataclasses.replace(
-        cache, ring=_lane_write(cache.ring, layer, position % ring, row))
-    lanes = position.shape[0]
-    n = -(-window // 8) * 8
-    at = position[:, None] - (n - 1) + jnp.arange(n, dtype=jnp.int32)
-    seen = (at >= 0) & (at > position[:, None] - window)
-    rows = cache.ring[layer, jnp.arange(lanes)[:, None], at % ring]
-    rows = rows.astype(q.dtype)[:, :, None]
-    ctx = _attend(q[:, None], rows, rows[..., :rank], seen[:, None], scale)
-    return ctx[:, 0], cache
+    with component(CACHE_WRITE):
+        cache = dataclasses.replace(
+            cache, ring=_lane_write(cache.ring, layer, position % ring, row))
+    with component(CACHE_READ):
+        lanes = position.shape[0]
+        n = -(-window // 8) * 8
+        at = position[:, None] - (n - 1) + jnp.arange(n, dtype=jnp.int32)
+        seen = (at >= 0) & (at > position[:, None] - window)
+        rows = cache.ring[layer, jnp.arange(lanes)[:, None], at % ring]
+        rows = rows.astype(q.dtype)[:, :, None]
+        ctx = _attend(q[:, None], rows, rows[..., :rank], seen[:, None],
+                      scale)
+        return ctx[:, 0], cache
 
 
 def _expand(expand: dict, stored):
@@ -1549,12 +1578,13 @@ def latent_prefill_attend(cache, layer: int, slot, q, rows, offset, *,
     slot = jnp.asarray(slot, jnp.int32)
     offset = jnp.asarray(offset, jnp.int32)
     at = offset + jnp.arange(s, dtype=jnp.int32)
-    cache = dataclasses.replace(
-        cache,
-        latent=cache.latent.at[layer, slot, at].set(
-            _as_stored(rows, cache.latent), mode="drop"),
-        index=cache.index.at[layer, slot, at].set(
-            select["key"].astype(cache.index.dtype), mode="drop"))
+    with component(CACHE_WRITE):
+        cache = dataclasses.replace(
+            cache,
+            latent=cache.latent.at[layer, slot, at].set(
+                _as_stored(rows, cache.latent), mode="drop"),
+            index=cache.index.at[layer, slot, at].set(
+                select["key"].astype(cache.index.dtype), mode="drop"))
     max_len = cache.max_len
     block = _key_block(max_len)
     blocks = jnp.minimum((offset + s - 1) // block + 1, max_len // block)
@@ -1569,19 +1599,21 @@ def latent_prefill_attend(cache, layer: int, slot, q, rows, offset, *,
                              select["scale"])
         return lax.dynamic_update_slice(scores, part, (0, i * block))
 
-    scores = lax.fori_loop(0, blocks, score,
-                           jnp.full((s, max_len), -jnp.inf, jnp.float32))
-    col = jnp.arange(max_len, dtype=jnp.int32)
-    selected = _select_mask(scores, col[None] <= at[:, None],
-                            select["top_k"])
-    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    if _reads_chunk_in_place(cache, q, expand, block):
-        ctx = latent_chunk_attention(qs, cache.latent, selected, expand["w"],
-                                     layer, slot, blocks,
-                                     nope=expand["nope"], block=block)
-    else:
-        ctx = _chunk_read(qs, cache.latent, selected, expand, layer, slot,
-                          blocks, block=block, width=rows.shape[-1])
+    with component(SELECT):
+        scores = lax.fori_loop(0, blocks, score,
+                               jnp.full((s, max_len), -jnp.inf, jnp.float32))
+        col = jnp.arange(max_len, dtype=jnp.int32)
+        selected = _select_mask(scores, col[None] <= at[:, None],
+                                select["top_k"])
+    with component(CACHE_READ):
+        qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        if _reads_chunk_in_place(cache, q, expand, block):
+            ctx = latent_chunk_attention(
+                qs, cache.latent, selected, expand["w"], layer, slot, blocks,
+                nope=expand["nope"], block=block)
+        else:
+            ctx = _chunk_read(qs, cache.latent, selected, expand, layer, slot,
+                              blocks, block=block, width=rows.shape[-1])
     return ctx, cache
 
 
@@ -1636,18 +1668,20 @@ def ring_prefill_attend(cache, layer: int, slot, q, rows, offset, length, *,
     ring = cache.ring.shape[2]
     before = -(-(window - 1) // 8) * 8
     p_before = offset - before + jnp.arange(before, dtype=jnp.int32)
-    old = cache.ring[layer, slot, p_before % ring].astype(q.dtype)
     mine = offset + jnp.arange(s, dtype=jnp.int32)
-    at = jnp.concatenate([p_before, mine])
-    seen = ((at[None] >= 0) & (at[None] <= mine[:, None])
-            & (at[None] > mine[:, None] - window))
-    k, v = _expand(expand, jnp.concatenate([old, rows.astype(q.dtype)]))
-    ctx = _attend(q[None], k[None], v[None], seen[None], scale)[0]
+    with component(CACHE_READ):
+        old = cache.ring[layer, slot, p_before % ring].astype(q.dtype)
+        at = jnp.concatenate([p_before, mine])
+        seen = ((at[None] >= 0) & (at[None] <= mine[:, None])
+                & (at[None] > mine[:, None] - window))
+        k, v = _expand(expand, jnp.concatenate([old, rows.astype(q.dtype)]))
+        ctx = _attend(q[None], k[None], v[None], seen[None], scale)[0]
     # only real rows, and of more than a ring's worth only the last: one
     # scatter writes no ring row twice
-    n = jnp.arange(s, dtype=jnp.int32)
-    keep = (n < length) & (n >= length - ring)
-    cache = dataclasses.replace(cache, ring=cache.ring.at[
-        layer, slot, jnp.where(keep, mine % ring, ring)].set(
-        rows.astype(cache.ring.dtype), mode="drop"))
+    with component(CACHE_WRITE):
+        n = jnp.arange(s, dtype=jnp.int32)
+        keep = (n < length) & (n >= length - ring)
+        cache = dataclasses.replace(cache, ring=cache.ring.at[
+            layer, slot, jnp.where(keep, mine % ring, ring)].set(
+            rows.astype(cache.ring.dtype), mode="drop"))
     return ctx, cache

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 import flax.linen as nn
 
+from apex_tpu.obs.scopes import EXPERTS, MLP, ROUTER, component
 from apex_tpu.ops._dispatch import (
     lane_aligned,
     record_dispatch,
@@ -318,7 +319,6 @@ class LatentMoE(nn.Module):
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    @jax.named_scope("latent_moe")
     def __call__(self, x, valid=None) -> Tuple[jax.Array, jax.Array]:
         h = x.shape[1]
         held = _held_inside(self.experts_held, self.num_experts)
@@ -341,21 +341,23 @@ class LatentMoE(nn.Module):
                         (held, self.expert_width, self.latent_size),
                         self.param_dtype)
 
-        chosen, weights = topk_sigmoid_route(
-            x, router_kernel, router_bias, k, self.routed_scaling_factor)
-        pairs = held_pairs(chosen, self.experts_held, valid)
-        latent = dense("latent_down", self.latent_size)(x)
-        hid = grouped_matmul(latent[pairs.token_of], w1.astype(x.dtype),
-                             pairs.sizes)
-        hid = jnp.square(jax.nn.relu(hid)).astype(x.dtype)
-        out = grouped_matmul(hid, w2.astype(x.dtype), pairs.sizes)
-        routed = pairs.combine(out, weights)
-        routed = dense("latent_up", h)(routed.astype(x.dtype))
-
-        shared = jnp.square(jax.nn.relu(
-            dense("shared_up", self.shared_width)(x)))
-        shared = dense("shared_down", h)(shared)
-        return routed + shared, pairs.counts
+        with component(ROUTER):
+            chosen, weights = topk_sigmoid_route(
+                x, router_kernel, router_bias, k, self.routed_scaling_factor)
+            pairs = held_pairs(chosen, self.experts_held, valid)
+        with component(EXPERTS):
+            latent = dense("latent_down", self.latent_size)(x)
+            hid = grouped_matmul(latent[pairs.token_of], w1.astype(x.dtype),
+                                 pairs.sizes)
+            hid = jnp.square(jax.nn.relu(hid)).astype(x.dtype)
+            out = grouped_matmul(hid, w2.astype(x.dtype), pairs.sizes)
+            routed = pairs.combine(out, weights)
+            routed = dense("latent_up", h)(routed.astype(x.dtype))
+        with component(MLP):
+            shared = jnp.square(jax.nn.relu(
+                dense("shared_up", self.shared_width)(x)))
+            shared = dense("shared_down", h)(shared)
+            return routed + shared, pairs.counts
 
 
 class GatedMoE(nn.Module):
@@ -385,7 +387,6 @@ class GatedMoE(nn.Module):
     scoring: str = "sigmoid"
 
     @nn.compact
-    @jax.named_scope("gated_moe")
     def __call__(self, x, valid=None) -> Tuple[jax.Array, jax.Array]:
         h = x.shape[1]
         held = _held_inside(self.experts_held, self.num_experts)
@@ -410,23 +411,26 @@ class GatedMoE(nn.Module):
         w_down = self.param("experts_down", normal,
                             (held, self.expert_width, h), self.param_dtype)
 
-        if self.scoring == "sigmoid":
-            chosen, weights = topk_sigmoid_route(
-                x, router_kernel, router_bias, self.top_k,
-                self.routed_scaling_factor)
-        else:
-            chosen, weights = topk_softmax_route(x, router_kernel,
-                                                 self.top_k)
-        pairs = held_pairs(chosen, self.experts_held, valid)
-        rows = x[pairs.token_of]                              # [t k, hidden]
-        gate = grouped_matmul(rows, w_gate.astype(x.dtype), pairs.sizes)
-        up = grouped_matmul(rows, w_up.astype(x.dtype), pairs.sizes)
-        hid = (jax.nn.silu(gate) * up).astype(x.dtype)
-        out = grouped_matmul(hid, w_down.astype(x.dtype), pairs.sizes)
-        routed = pairs.combine(out, weights).astype(x.dtype)
+        with component(ROUTER):
+            if self.scoring == "sigmoid":
+                chosen, weights = topk_sigmoid_route(
+                    x, router_kernel, router_bias, self.top_k,
+                    self.routed_scaling_factor)
+            else:
+                chosen, weights = topk_softmax_route(x, router_kernel,
+                                                     self.top_k)
+            pairs = held_pairs(chosen, self.experts_held, valid)
+        with component(EXPERTS):
+            rows = x[pairs.token_of]                          # [t k, hidden]
+            gate = grouped_matmul(rows, w_gate.astype(x.dtype), pairs.sizes)
+            up = grouped_matmul(rows, w_up.astype(x.dtype), pairs.sizes)
+            hid = (jax.nn.silu(gate) * up).astype(x.dtype)
+            out = grouped_matmul(hid, w_down.astype(x.dtype), pairs.sizes)
+            routed = pairs.combine(out, weights).astype(x.dtype)
         if not self.shared_width:
             return routed, pairs.counts
 
-        shared = (jax.nn.silu(dense("shared_gate", self.shared_width)(x))
-                  * dense("shared_up", self.shared_width)(x))
-        return routed + dense("shared_down", h)(shared), pairs.counts
+        with component(MLP):
+            shared = (jax.nn.silu(dense("shared_gate", self.shared_width)(x))
+                      * dense("shared_up", self.shared_width)(x))
+            return routed + dense("shared_down", h)(shared), pairs.counts

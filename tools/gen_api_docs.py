@@ -110,7 +110,7 @@ PAGES = {
     "observability": ("Observability (metrics, spans, exporters)", [
         "apex_tpu.obs", "apex_tpu.obs.metrics", "apex_tpu.obs.trace",
         "apex_tpu.obs.request_trace", "apex_tpu.obs.slo",
-        "apex_tpu.obs.bridge",
+        "apex_tpu.obs.bridge", "apex_tpu.obs.scopes",
     ]),
     "utils": ("Utilities", [
         "apex_tpu.utils.packing",
@@ -1536,6 +1536,58 @@ span the host was in (`benchmark/lib/program_spans.py` is such a
 reader).  Without a profiler, `obs.install_recorder()` (or
 `with obs.recording() as rec:`) records the same spans as a Chrome
 trace, host clock only: `rec.export("step.json")`.
+
+### Device time by component (`obs.scopes`)
+
+The spans above are the host's side of a step.  The device's side is
+named by `apex_tpu.obs.scopes`: one vocabulary of `jax.named_scope`s,
+`component(name)` = the scope `apex.<name>` (a context manager and a
+decorator; a name outside the vocabulary raises), opened around each
+part of every served model, of the cache's seam and of the expert
+layers.  XLA keeps the scope path as each instruction's `op_name`
+(a fused instruction carries its root's), and a `jax.profiler` capture
+carries the compiled modules, so the device's own `XLA Ops` line reads
+back by component.  A scope is metadata: paid when a program is traced,
+never when it runs; the lowered program is the same text with and
+without it; nothing turns it on.  Scopes nest under flax's module path
+and the innermost `apex.<name>` of an instruction is its component.
+
+| scope | what is inside it | opened in |
+|---|---|---|
+| `apex.embed` | token embedding | each served model's `__call__` |
+| `apex.norm` | a layer's input / post-attention norms | each decoder layer |
+| `apex.attn_proj` | q, k, v (or the latent down / up projections, a selector's or a Mamba mixer's two products), rope / YaRN, gates, the output projection, the residual add that closes the branch | `LlamaAttention`, `NemotronHAttention`, `MellumAttention`, `LatentAttention`, `ViTSelfAttention`, `Mamba2Mixer`'s `in_proj` / `out_proj` |
+| `apex.cache_write` | append / chunk-write, ring writes, `write_slot_state` / `write_lane_state`, a slot's length | `serving/kv_cache.py`: inside every `*_attend` of the seam; `commit_slot_length` |
+| `apex.cache_read` | the read whichever path was chosen: the Pallas call **with its glue** (slot cut, head-major cut, casts, expansion), `cached_attention`, the blocked loops, the masked reads | the same functions; inside the chunk kernel's own `jit` too |
+| `apex.select` | a selector's scores, thresholds / `lax.top_k`, the gather of the selected rows | `latent_decode_attend`, `latent_prefill_attend` |
+| `apex.state` | Mamba-2: convolution tail, chunked scan, one-token state update, gated norm, `slot_state` | `Mamba2Mixer` |
+| `apex.mlp` | dense MLP, a shared expert, the residual add that closes the branch | `LlamaMLP`, `GatedMLP`, `LatentMoE` / `GatedMoE` |
+| `apex.router` | router logits, top-k routing, `held_pairs` (the dispatch sort), `add_counts` | `transformer/moe.py`, `kv_cache.add_counts` |
+| `apex.experts` | the grouped products (`grouped_matmul`), activation, weighting and combine, `LatentMoE`'s latent down / up | `transformer/moe.py` |
+| `apex.head` | final norm, the LM-head product, the logits the engine hands back | each served model's `__call__`, `DecodeEngine`'s programs |
+| `apex.sample` | the sampler | `serving/engine.py::_sample_one` |
+
+**Where a running engine's device time goes.**  Capture a few steps -
+`obs.start_jax_profiler(logdir)` ... `obs.stop_jax_profiler()`, or
+`StepWatchdog(on_stall=obs.profile_on_stall(logdir))` for the first
+stall - then
+
+    python3 benchmark/tools/scope_table.py <logdir>
+
+prints program x component: ms an execution (self time: a `while` and
+the ops of its body count once) and share, the scoped share of the
+decode and prefill programs, and what is under no scope by op name
+(`benchmark/lib/device_scopes.py` is the reader; it needs `jax` to read
+the file and no device).  Three things bound what the split can mean: a
+fused instruction carries its root's path, so a norm fused into the
+product after it counts with the product; an instruction XLA made
+itself (the prefetch of the next matrices: `copy-start` / `slice-start`
+and their `-done`) has no path and is given to what consumes it; a
+callee under a `jit` of its own is lowered once and carries the first
+call site's path - read the component, never `layers_N`.  A persistent
+compilation cache is keyed without metadata by default: a program it
+kept from before the scopes (or from before a scope moved) is served
+with the names it was compiled with.
 
 ## The event bridge
 

@@ -165,6 +165,7 @@ from apex_tpu.serving.engine import (
     default_draft_buckets,
     default_prefill_buckets,
     request_key,
+    request_key_bits,
     sample_tokens,
     token_key,
     tp_param_shardings,
@@ -280,6 +281,7 @@ __all__ = [
     "default_prefill_buckets",
     "propose",
     "request_key",
+    "request_key_bits",
     "sample_tokens",
     "token_key",
     "ContinuousBatchingScheduler",

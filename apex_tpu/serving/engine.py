@@ -109,9 +109,15 @@ from apex_tpu.utils.compat import (
 
 __all__ = ["DecodeEngine", "TPConfig", "default_prefill_buckets",
            "default_draft_buckets", "sample_tokens", "request_key",
-           "token_key", "tp_param_shardings"]
+           "request_key_bits", "token_key", "tp_param_shardings"]
 
 logger = get_logger("serving.engine")
+
+#: dtypes of the decode program's ``[slots]`` operands behind params and
+#: cache, in order: the kept last-sampled vector, the host's tokens, which
+#: lanes take the kept one, which lanes are active (what a test or a tool
+#: that lowers ``engine._decode`` for shapes hands it)
+DECODE_VECTORS = (jnp.int32, jnp.int32, jnp.bool_, jnp.bool_)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -233,6 +239,20 @@ def default_draft_buckets(max_draft: int) -> tuple:
 def request_key(seed: int) -> jax.Array:
     """Base PRNG key for one request (explicit, replayable)."""
     return jax.random.PRNGKey(seed)
+
+
+def request_key_bits(seed: int) -> np.ndarray:
+    """:func:`request_key`'s two ``uint32`` words, made on the host: the same
+    bits as ``np.asarray(request_key(seed))`` (the seed's high and low 32
+    bits; the high word is 0 unless 64-bit mode is on) without the eager
+    device program and the read that waits behind whatever the device has
+    queued.  What the scheduler's admission calls.  Another default PRNG
+    than threefry has another key layout and takes the device's path."""
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        return np.asarray(request_key(seed))
+    seed = int(np.int64(seed))       # out of range raises, as PRNGKey does
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], np.uint32)
 
 
 def token_key(base: jax.Array, index) -> jax.Array:
@@ -469,6 +489,11 @@ class DecodeEngine:
         # decode hot path (the cache's scatters drop out-of-range rows
         # silently — overflow must be an error, not a lost token)
         self._lengths_host = np.zeros((self.slots,), np.int64)
+        # each slot's last sampled token, kept on the device (keep_sampled)
+        # so that the next decode step can be enqueued before the host has
+        # read it; committed like the cache, for the same one-program reason
+        self._last = jax.device_put(np.zeros((self.slots,), np.int32),
+                                    self._host_target)
         # monotonic weight-buffer generation: bumped by swap_params so
         # host layers (the prefix cache's version tags, the reloader's
         # rollback bookkeeping) can tell which weights produced a byte
@@ -504,12 +529,16 @@ class DecodeEngine:
                                                 axis=0, keepdims=False)
                 return last.astype(jnp.float32), cache
 
-        def _decode(params, cache, tokens, active):
+        def _decode(params, cache, last, tokens, on_device, active):
             # tokens [slots] int32 (last sampled per slot); active [slots]
             # bool — inactive lanes still compute (shape stability) but
             # never advance their length, so their writes are unreadable.
             # Where an idle lane's write goes is the layout's to say
-            # (its own masked rows, or nowhere)
+            # (its own masked rows, or nowhere).  A lane in on_device
+            # [slots] bool takes its token from last [slots] int32, the
+            # sampled tokens kept on the device (keep_sampled), not from
+            # the host's vector: the host may not have read it yet
+            tokens = jnp.where(on_device, last, tokens)
             position = cache.decode_positions(active)
             # the model is told the active lanes too: an idle lane's K/V
             # write is hidden by its length, its recurrent state must not
@@ -570,6 +599,11 @@ class DecodeEngine:
             cache = write_slot_region(cache, slot, start, k_blk, v_blk)
             return commit_slot_length(cache, slot, start + length)
 
+        def _keep(last, sampled, lanes):
+            # sampled [slots], or [1] for the one lane of a prompt's first
+            # token; lanes [slots] bool
+            return jnp.where(lanes, sampled, last)
+
         def _cow(cache, src, dst):
             # copy-on-write block copy: pool block src -> dst across
             # every layer, ONE compiled program for every (src, dst)
@@ -622,6 +656,7 @@ class DecodeEngine:
             self._verify = jax.jit(_verify, donate_argnums=(1,))
             self._restore = jax.jit(_restore, donate_argnums=(0,))
             self._cow = jax.jit(_cow, donate_argnums=(0,))
+            self._keep = jax.jit(_keep)
             # NOT donated: a region read must leave the cache intact,
             # and its outputs are fresh owned buffers the prefix cache
             # keeps alive across later (donating) engine calls
@@ -655,8 +690,12 @@ class DecodeEngine:
                 smap(_prefill, (pspec, cspec, S, S, S, S),
                      (P(TP), cspec)), donate_argnums=(1,))
             self._decode = jax.jit(
-                smap(_decode, (pspec, cspec, S, S),
+                smap(_decode, (pspec, cspec, S, S, S, S),
                      (P(None, TP), cspec)), donate_argnums=(1,))
+            # replicated like the host's vectors, whatever the sampler's
+            # output was laid out as: another placement of `last` would be
+            # another decode program
+            self._keep = jax.jit(_keep, out_shardings=self._host_target)
             # verify's greedy/rows/accepted leave replicated: the body
             # all_gathers the vocab shards before the argmax decides
             self._verify = jax.jit(
@@ -1387,15 +1426,24 @@ class DecodeEngine:
         self._lengths_host[slot] = length
         self._restored[slot] = length
 
-    def decode(self, tokens, active) -> jax.Array:
+    def decode(self, tokens, active, *, on_device=None) -> jax.Array:
         """One batched decode step: append ``tokens[slot]`` to every
         active slot, return per-slot next-token logits ``[slots, vocab]``
         (f32).  Inactive lanes return garbage rows — callers mask by
         ``active``.  Raises when an active slot is already at
         ``max_len`` (the append would silently clobber the last cached
-        token otherwise)."""
+        token otherwise).
+
+        A lane set in ``on_device`` (``[slots]`` bool; none by default)
+        appends the token :meth:`keep_sampled` last kept for it on the
+        device in place of ``tokens[slot]``: the scheduler enqueues a step
+        on tokens the host has not read yet.  The kept vector is an operand
+        of the one compiled program on every call, so host-fed and
+        device-fed lanes, mixed in any way, share it."""
         with obs_trace.span("engine.decode") as sp:
             act = np.asarray(active, bool)
+            fed = (np.zeros((self.slots,), bool) if on_device is None
+                   else np.asarray(on_device, bool))
             if sp is not None:
                 # the cached tokens this step's attention reads: what a
                 # roofline of the decode program counts as KV bytes
@@ -1427,8 +1475,8 @@ class DecodeEngine:
                      for s in np.flatnonzero(act)])
             if self._tp_cfg is None:
                 logits, self._cache = self._decode(
-                    self.params, self._cache,
-                    np.asarray(tokens, np.int32), act)
+                    self.params, self._cache, self._last,
+                    np.asarray(tokens, np.int32), fed, act)
             else:
                 # time the step wall-to-wall and publish it as
                 # serving_tp_step: an honest UPPER BOUND on the per-step
@@ -1439,8 +1487,8 @@ class DecodeEngine:
                 # emits nothing: the default-off event stream is identical.
                 t0 = time.perf_counter()
                 logits, self._cache = self._decode(
-                    self.params, self._cache,
-                    np.asarray(tokens, np.int32), act)
+                    self.params, self._cache, self._last,
+                    np.asarray(tokens, np.int32), fed, act)
                 jax.block_until_ready(logits)
                 # a fleet scheduler stamps its replica name onto the engine
                 # (anonymous engines splat nothing — byte-identical stream)
@@ -1515,6 +1563,21 @@ class DecodeEngine:
             return a, np.asarray(greedy), rows
 
     # ---- sampling --------------------------------------------------------
+    def keep_sampled(self, sampled, lanes) -> None:
+        """Keep ``sampled`` (a decode step's ``[slots]`` tokens, or the
+        ``[1]`` first token of the one lane set) as the last sampled token
+        of ``lanes`` (``[slots]`` bool), on the device: what
+        :meth:`decode` appends for a lane in ``on_device``.  One small
+        program behind the sampler; nothing is read back."""
+        self._last = self._keep(self._last, sampled,
+                                np.asarray(lanes, bool))
+
+    @property
+    def last_sampled(self) -> jax.Array:
+        """``[slots] int32`` on the device: each slot's last kept token
+        (whatever a slot held before, where nothing was kept since)."""
+        return self._last
+
     @staticmethod
     def sample(logits, base_keys, indices, temperatures,
                top_ks) -> jax.Array:

@@ -227,6 +227,10 @@ class FleetRouter:
         self._failovers_total = 0
         self._resumed_total = 0
         self._shed_total = 0
+        # rids a dead replica's export finished (their last token was
+        # still on its device): it is never stepped again, so the next
+        # fleet step reports them
+        self._finished_at_failover: List[str] = []
         # canary traffic pin (rolling rollout): (name, fraction, seed)
         # while active, plus the window's rid -> replica log
         self._pin: Optional[tuple] = None
@@ -515,6 +519,7 @@ class FleetRouter:
         capture = capture and r.scheduler.engine.paged is None
         now = self._clock()
         exports = r.scheduler.export_streams(capture=capture)
+        self._finished_at_failover.extend(r.scheduler.pop_finished())
         # a drained scheduler closes cleanly: the prefix cache drops
         # its entries (paged: derefs the pool blocks) and the reclaim
         # hook unhooks — a killed replica must never leak pins
@@ -720,7 +725,8 @@ class FleetRouter:
         Returns rids that reached a terminal state, fleet-wide."""
         self._check_health()
         self._place_pending()
-        finished: List[str] = []
+        finished, self._finished_at_failover = (
+            self._finished_at_failover, [])
         for r in self._replicas.values():
             if r.state is ReplicaState.DEAD or r.wedged:
                 continue                 # a wedged step never returns

@@ -26,6 +26,33 @@ boundaries:
 - **Per-request state machine**: QUEUED → PREFILL → DECODE → DONE, with
   eviction on EOS or ``max_new_tokens`` and *immediate* slot reuse at
   the same step boundary.
+- **Decode-ahead** (the step's contract: enqueue all, read once): a
+  step enqueues admission's restores, its prefill chunks AND its decode,
+  and only then waits for the device, once, for what was enqueued
+  *before* that decode — this step's first tokens and the previous
+  step's decoded tokens — so the device runs the decode while the host
+  appends, finishes, publishes and enqueues the next step.  Sampled
+  tokens stay on the device (the engine keeps each slot's last one and
+  the decode program reads it there; a lane whose newest token only the
+  host has — resumed, adopted, settled — is fed from the host through
+  the same program), a request's PRNG key words are made on the host,
+  and a lane is issued no further than its ``max_new_tokens``.  What a
+  caller sees: a decoded token is delivered one step after the step
+  that computed it, a request is reported finished by the step that
+  reads its last token, ``ttft_s`` is stamped in the step that sampled
+  the first token, and no token of any stream changes.  A stream that
+  ends on EOS has one more lane in flight; its token is dropped when
+  read.  **Settle points** read everything in flight first, because
+  they need a stream's newest token or its slot's rows on the host:
+  speculation (drafting reads the history: such a scheduler settles
+  before drafting and after its decode, every step), preemption (the
+  victim is captured whole), ``cancel`` of an active request (the
+  partial output is all that was computed), ``export_streams`` (a stream
+  moves with its tokens), ``swap_weights`` (the displaced buffer goes
+  back to the caller idle), ``close`` and the end of ``run()`` (what is
+  left belongs to ended streams).  :meth:`ContinuousBatchingScheduler.
+  overlap_stats` counts steps, steps ahead, settles by reason and
+  dropped tokens.
 - **Exact-greedy speculation** (opt-in via
   ``speculation=SpeculationConfig(...)``): greedy requests draft up to
   k tokens per step by prompt lookup (:mod:`apex_tpu.serving.draft`)
@@ -117,7 +144,7 @@ from apex_tpu._logging import emit_event, get_logger
 from apex_tpu.obs import bridge as obs_bridge
 from apex_tpu.obs import trace as obs_trace
 from apex_tpu.serving.draft import SpeculationConfig, adapt_k, propose
-from apex_tpu.serving.engine import DecodeEngine, request_key
+from apex_tpu.serving.engine import DecodeEngine, request_key_bits
 from apex_tpu.serving.paged_kv_cache import blocks_per_slot
 from apex_tpu.serving.paged_kv_cache import (
     bytes_per_block as pkv_bytes_per_block,
@@ -220,10 +247,29 @@ class _Active:
     pinned: List = dataclasses.field(default_factory=list)
     preemptions: int = 0     # lossless suspend/resume cycles survived
     wv: int = 0              # engine weights_version at admission
+    # tokens whose computation has been enqueued: the sampler's index for
+    # the next one.  ``issued - len(tokens)`` of them are still on the
+    # device (0 or 1 when a decode step is enqueued: 0 = the host holds the
+    # stream's newest token and feeds it, 1 = the engine's kept vector does)
+    issued: int = 0
 
     @property
     def prompt_remaining(self) -> int:
         return len(self.request.prompt) - self.prompt_pos
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """Sampled tokens the device holds and the host has not read: one
+    prompt's first token (``sampled`` is ``[1]``) or one decode step's
+    vector (``[slots]``), with the streams they belong to as
+    ``(index into sampled, stream)``.  The stream, not its slot: a lane
+    whose stream ended meanwhile (an EOS the host saw a step late) is
+    dropped when read, whoever holds the slot by then."""
+
+    sampled: object
+    lanes: List[tuple]
+    first: bool
 
 
 @dataclasses.dataclass
@@ -432,6 +478,15 @@ class ContinuousBatchingScheduler:
         # HotReloader at construction; rides every routed/finished
         # event so a mixed-version fleet mid-rollout is observable.
         self.weights_step: Optional[int] = None
+        # decode-ahead: what the device holds unread, in enqueue order, the
+        # rids that finished when a settle point read it between steps (the
+        # next step() reports them), and the counts of overlap_stats()
+        self._flight: List[_InFlight] = []
+        self._unreported: List[str] = []
+        self._decode_steps = 0
+        self._steps_ahead = 0
+        self._settled_early: Dict[str, int] = {}
+        self._dropped_tokens = 0
 
     def _emit(self, kind: str, **fields) -> None:
         """Every serving event this scheduler emits, replica-stamped
@@ -613,7 +668,7 @@ class ContinuousBatchingScheduler:
                    if self.speculation is not None
                    and request.temperature <= 0 else 0)
         st = _Active(request=request, slot=slot, seq=self._admit_seq,
-                     base_key=np.asarray(request_key(request.seed)),
+                     base_key=request_key_bits(request.seed),
                      tokens=[], t_submit=t_submit, t_first=0.0,
                      draft_k=draft_k,
                      wv=int(getattr(self.engine, "weights_version", 0)))
@@ -815,6 +870,14 @@ class ContinuousBatchingScheduler:
                     break
             free = [s for s in self.engine.free_slots()
                     if s not in self._active]
+            if (not free and policy.preemption and self._flight
+                    and self._pick_victim(best) is not None):
+                # a victim is captured with its newest token and its
+                # slot's rows: read what is in flight first.  That may
+                # finish streams (the victim among them) and free a slot
+                self._settle("preempt")
+                free = [s for s in self.engine.free_slots()
+                        if s not in self._active]
             if not free:
                 victim = (self._pick_victim(best)
                           if policy.preemption else None)
@@ -952,6 +1015,11 @@ class ContinuousBatchingScheduler:
                            phase="suspended",
                            new_tokens=len(st.tokens))
                 return True
+        if any(st.request.rid == rid for st in self._active.values()):
+            # the partial output is what was really produced: a token
+            # still on the device is one (and may be the stream's last,
+            # which finishes it: too late to cancel)
+            self._settle("cancel")
         for slot, st in list(self._active.items()):
             if st.request.rid == rid:
                 if self._prefix is not None:
@@ -1026,6 +1094,15 @@ class ContinuousBatchingScheduler:
         Records come back in original admission/arrival order so a
         router re-placing them preserves FIFO fairness within a
         priority class."""
+        if capture:
+            # a stream moves with every token it was given and the rows
+            # that go with them (one that finishes on its token in flight
+            # stays here, as a result)
+            self._settle("export")
+        else:
+            # the device is gone, and what it held unread with it: every
+            # stream replays from its request
+            self._flight.clear()
         out: List[StreamExport] = []
         dense = not self._paged
         # active streams, admission order (DECODE streams carry their
@@ -1114,11 +1191,12 @@ class ContinuousBatchingScheduler:
         slot = free[0]
         self.engine.restore_prefix(slot, exp.kv, exp.length)
         st = _Active(request=request, slot=slot, seq=self._admit_seq,
-                     base_key=np.asarray(request_key(request.seed)),
+                     base_key=request_key_bits(request.seed),
                      tokens=list(exp.tokens), t_submit=exp.t_submit,
                      t_first=exp.t_first,
                      prompt_pos=len(request.prompt),
                      phase=RequestPhase.DECODE,
+                     issued=len(exp.tokens),
                      draft_k=(self.speculation.max_draft
                               if self.speculation is not None
                               and request.temperature <= 0 else 0),
@@ -1142,6 +1220,9 @@ class ContinuousBatchingScheduler:
         abandoned paged cache otherwise pins its blocks forever and
         the allocator keeps reclaiming into the dead store.  Refuses
         while work is in flight; idempotent once drained."""
+        # what is left on the device now belongs to streams that ended (an
+        # EOS seen a step late): read and dropped
+        self._settle("close")
         if self._active or self._queue or self._suspended:
             raise RuntimeError(
                 f"close() with {len(self._active)} active stream(s), "
@@ -1186,6 +1267,9 @@ class ContinuousBatchingScheduler:
         provenance is unknown, and a stale step on a routed/finished
         event would lie about what served the request.
         """
+        # every token the displaced weights computed is delivered before
+        # the buffer goes back to the caller, who may free it
+        self._settle("swap_weights")
         old = self.engine.swap_params(params)
         self.weights_step = None if step is None else int(step)
         if self._prefix is not None:
@@ -1328,15 +1412,14 @@ class ContinuousBatchingScheduler:
             self._prefix.release(st.pinned)
             st.pinned = []
 
-    def _prefill_work(self) -> List[str]:
+    def _prefill_work(self) -> None:
         """Spend up to ``prefill_budget`` prompt tokens on chunks,
         oldest admitted request first (FIFO: a request's first token
         never waits on a later arrival).  When a prompt completes, its
         first token is sampled from the final chunk's logits — TTFT
-        includes its prefill chunks + zero decode steps.  Returns rids
-        that finished already at prefill completion (one-token
-        requests, instant EOS)."""
-        finished: List[str] = []
+        includes its prefill chunks + zero decode steps — and stays on
+        the device: the lane joins this step's decode, and the token is
+        read with the step's one readback (:meth:`_deliver`)."""
         with obs_trace.span("serving.prefill") as sp:
             budget = self.prefill_budget
             chunks = 0
@@ -1371,26 +1454,95 @@ class ContinuousBatchingScheduler:
                             logits[None], st.base_key[None], np.int32([0]),
                             np.float32([st.request.temperature]),
                             np.int32([st.request.top_k]))
-                        with obs_trace.span("serving.readback",
-                                            what="first_token",
-                                            rid=st.request.rid):
-                            tok = int(sampled[0])
-                        st.t_first = self._clock()
-                        st.tokens.append(tok)
+                        lane = np.zeros((self.engine.slots,), bool)
+                        lane[st.slot] = True
+                        self._leave_on_device(sampled, lane, [(0, st)],
+                                              first=True)
+                        st.issued = 1
                         st.phase = RequestPhase.DECODE
                         if self._prefix is not None:
                             # the prompt is fully cached: the chain it was
                             # matching/extending no longer needs protection
                             self._release_pins(st)
-                        self._emit("serving_first_token", rid=st.request.rid,
-                                   ttft_s=round(st.t_first - st.t_submit, 6))
-                        if self._finish_if_done(st):
-                            finished.append(st.request.rid)
                 if budget <= 0:
                     break
             if sp is not None:
                 sp.set_attribute("chunks", chunks)
-        return finished
+
+    # ---- decode-ahead: what is in flight, and the one place it is read ----
+    def _leave_on_device(self, sampled, lanes: np.ndarray,
+                         streams: List[tuple], *, first: bool) -> None:
+        """Leave freshly sampled tokens on the device: the engine keeps
+        them as the ``lanes``' next input, the copy to the host starts
+        now, and the record of what is in flight grows by one entry."""
+        self.engine.keep_sampled(sampled, lanes)
+        sampled.copy_to_host_async()
+        self._flight.append(_InFlight(sampled, streams, first))
+
+    def _deliver(self, upto: int) -> None:
+        """Read the oldest ``upto`` entries in flight — THE blocking read —
+        and hand each stream its token: stamp ``t_first`` on a first token,
+        finish a stream on EOS or its budget (its rid goes to
+        ``_unreported``, which :meth:`step` returns), drop a token whose
+        stream already ended.  ``lag`` on the span is 1 when a newer decode
+        step was enqueued before this one's tokens were read."""
+        entries, self._flight = self._flight[:upto], self._flight[upto:]
+        if not entries:
+            return
+        kinds = {"first_token" if e.first else "decode" for e in entries}
+        lag = int("decode" in kinds
+                  and any(not e.first for e in self._flight))
+        # the span wraps the host read and nothing else, so device idle
+        # under it is "device done, host not yet resumed" and idle under
+        # any other span is the host working
+        with obs_trace.span("serving.readback",
+                            what="+".join(sorted(kinds)), lag=lag):
+            read = [np.asarray(e.sampled) for e in entries]
+        # a first token's arrival is this read's return, whatever the host
+        # does before it reaches that stream below
+        t_read = self._clock()
+        with obs_trace.span("serving.finish") as sp:
+            n_done = len(self._unreported)
+            for entry, values in zip(entries, read):
+                for index, st in entry.lanes:
+                    if st.phase is RequestPhase.DONE:
+                        self._dropped_tokens += 1
+                        continue
+                    if entry.first:
+                        st.t_first = t_read
+                        self._emit("serving_first_token",
+                                   rid=st.request.rid,
+                                   ttft_s=round(st.t_first - st.t_submit,
+                                                6))
+                    st.tokens.append(int(values[index]))
+                    if self._finish_if_done(st):
+                        self._unreported.append(st.request.rid)
+            if sp is not None:
+                sp.set_attribute("finished",
+                                 len(self._unreported) - n_done)
+
+    def _settle(self, reason: str) -> None:
+        """A settle point: read everything in flight now, because the
+        caller needs a stream's newest token or its slot's rows on the
+        host (``reason`` names it in :meth:`overlap_stats`).  Nothing in
+        flight: nothing happens."""
+        if self._flight:
+            self._settled_early[reason] = (
+                self._settled_early.get(reason, 0) + 1)
+            self._deliver(len(self._flight))
+
+    def overlap_stats(self) -> Dict[str, object]:
+        """How often a step ran ahead of the host's reads: ``steps`` that
+        enqueued a decode, ``steps_ahead`` of them enqueued while the
+        previous step's tokens were still unread, ``settled_early``
+        ``{reason: times}`` something in flight was read at a settle point
+        instead of at its step's end, and ``dropped_tokens`` computed for
+        a stream that had already ended (one a stream that ends on EOS
+        while decoding)."""
+        return {"steps": self._decode_steps,
+                "steps_ahead": self._steps_ahead,
+                "settled_early": dict(self._settled_early),
+                "dropped_tokens": self._dropped_tokens}
 
     def _finish_if_done(self, st: _Active) -> bool:
         request = st.request
@@ -1431,15 +1583,13 @@ class ContinuousBatchingScheduler:
                    weights_step=self.weights_step)
         return True
 
-    def _spec_work(self, decoding: Dict[int, "_Active"]
-                   ) -> tuple[List[str], set]:
+    def _spec_work(self, decoding: Dict[int, "_Active"]) -> set:
         """Run one speculative verify per eligible decoding slot: draft
         by prompt lookup over the request's own prompt + generated
         history, verify all candidates in one multi-token dispatch,
         emit the accepted prefix plus the bonus token, and adapt the
-        next draft length.  Returns ``(finished rids, slots consumed)``
-        — consumed slots already advanced this step and must not ride
-        the batched decode.
+        next draft length.  Returns the slots consumed: they already
+        advanced this step and must not ride the batched decode.
 
         A slot falls back to the plain decode step whenever drafting
         cannot help: sampled-temperature request (``draft_k == 0`` —
@@ -1450,7 +1600,6 @@ class ContinuousBatchingScheduler:
         speculation is pure scheduling — pinned by
         ``tests/test_serving_spec.py``.
         """
-        finished: List[str] = []
         consumed: set = set()
         cfg = self.speculation
         lengths = self.engine.lengths()
@@ -1490,14 +1639,15 @@ class ContinuousBatchingScheduler:
                 st.tokens.append(int(tok))
                 n_emitted += 1
                 if self._finish_if_done(st):
-                    finished.append(request.rid)
+                    self._unreported.append(request.rid)
                     break
+            st.issued = len(st.tokens)
             self._spec_emitted += n_emitted
             self._emit("serving_spec_verify", rid=request.rid,
                        bucket=self.engine.draft_bucket_for(len(draft)),
                        drafted=len(draft), accepted=accepted,
                        emitted=n_emitted, duration_s=round(dt, 6))
-        return finished, consumed
+        return consumed
 
     @property
     def prefill_backlog(self) -> int:
@@ -1512,19 +1662,27 @@ class ContinuousBatchingScheduler:
         """One step boundary: (with a policy) shed expired deadlines,
         then admit into free slots — possibly preempting — spend the
         prefill budget on prompt chunks, then one shared decode step
-        for every decoding slot.  Returns rids that reached a terminal
-        state at this boundary (finished or shed)."""
-        finished: List[str] = []
+        for every decoding slot; all of it enqueued, and then ONE wait
+        for the device: the read of this step's first tokens and of the
+        PREVIOUS step's decoded tokens, while this step's decode runs.
+        Returns rids that reached a terminal state since the last call
+        (finished or shed)."""
         with obs_trace.span("serving.step", step=self._step_index + 1,
                             active=len(self._active),
                             queued=len(self._queue)):
             with obs_trace.span("serving.admit"):
                 if self.policy is not None and self.policy.deadline_shedding:
-                    finished.extend(self._shed_expired())
+                    self._unreported.extend(self._shed_expired())
                 self._admit()
-            finished.extend(self._prefill_work())
-            decoding = {slot: st for slot, st in self._active.items()
-                        if st.phase is RequestPhase.DECODE}
+            self._prefill_work()
+            if self.speculation is not None:
+                # drafting reads each stream's newest token on the host:
+                # this step's first tokens now, the decode's below
+                self._settle("speculation")
+            decoding = {
+                slot: st for slot, st in self._active.items()
+                if st.phase is RequestPhase.DECODE
+                and st.issued < st.request.max_new_tokens}
             if decoding and self.speculation is not None:
                 # speculative verifies run between the prefill budget and
                 # the shared decode step; slots they advanced are excluded
@@ -1532,50 +1690,66 @@ class ContinuousBatchingScheduler:
                 # else — sampled requests, no-match streams, mid-prefill
                 # lanes — proceeds exactly as before
                 with obs_trace.span("serving.spec"):
-                    spec_finished, consumed = self._spec_work(decoding)
-                finished.extend(spec_finished)
+                    consumed = self._spec_work(decoding)
                 decoding = {slot: st for slot, st in decoding.items()
                             if slot not in consumed}
+            before = len(self._flight)
             if decoding:
-                with obs_trace.span("serving.decode", lanes=len(decoding)):
+                ahead = any(not e.first for e in self._flight)
+                self._decode_steps += 1
+                self._steps_ahead += ahead
+                with obs_trace.span("serving.decode", lanes=len(decoding),
+                                    ahead=int(ahead)):
                     slots = self.engine.slots
                     tokens = np.zeros((slots,), np.int32)
+                    on_device = np.zeros((slots,), bool)
                     active = np.zeros((slots,), bool)
                     base_keys = np.zeros((slots, 2), np.uint32)
                     indices = np.zeros((slots,), np.int32)
                     temps = np.zeros((slots,), np.float32)
                     top_ks = np.zeros((slots,), np.int32)
                     for slot, st in decoding.items():
-                        tokens[slot] = st.tokens[-1]
+                        if st.issued == len(st.tokens):
+                            tokens[slot] = st.tokens[-1]
+                        else:       # sampled, kept on the device, unread
+                            on_device[slot] = True
                         active[slot] = True
                         base_keys[slot] = st.base_key
-                        indices[slot] = len(st.tokens)
+                        indices[slot] = st.issued
                         temps[slot] = st.request.temperature
                         top_ks[slot] = st.request.top_k
+                        st.issued += 1
                     # per-step device work: ONE decode dispatch + ONE sampler
-                    # dispatch (keys fold inside the sampler) + one readback;
-                    # mid-prefill slots ride as inactive lanes (their lengths
-                    # never advance, and the next chunk overwrites the lane's
-                    # masked garbage write)
-                    logits = self.engine.decode(tokens, active)
+                    # dispatch (keys fold inside the sampler) + the kept
+                    # vector's update; mid-prefill slots ride as inactive
+                    # lanes (their lengths never advance, and the next chunk
+                    # overwrites the lane's masked garbage write)
+                    logits = self.engine.decode(tokens, active,
+                                                on_device=on_device)
                     sampled = self.engine.sample(
                         logits, base_keys, indices, temps, top_ks)
-                # the one place a decoding step WAITS on the device: the span
-                # wraps the host read and nothing else, so device idle under
-                # it is "device done, host not yet resumed" and idle under
-                # any other span is the host working
-                with obs_trace.span("serving.readback", what="decode"):
-                    sampled = np.asarray(sampled)
-                with obs_trace.span("serving.finish") as sp:
-                    n_done = len(finished)
-                    for slot, st in list(decoding.items()):
-                        st.tokens.append(int(sampled[slot]))
-                        if self._finish_if_done(st):
-                            finished.append(st.request.rid)
-                    if sp is not None:
-                        sp.set_attribute("finished", len(finished) - n_done)
+                    self._leave_on_device(
+                        sampled, active, list(decoding.items()), first=False)
+            if self.speculation is not None:
+                self._settle("speculation")
+            else:
+                # the one place a step WAITS on the device, for what was
+                # enqueued before its decode: the device runs the decode
+                # while the host appends, finishes, publishes and enqueues
+                # the next step
+                self._deliver(before)
             with obs_trace.span("serving.publish"):
                 self._publish_step()
+        return self.pop_finished()
+
+    def pop_finished(self) -> List[str]:
+        """The rids that reached a terminal state since this was last
+        asked: what :meth:`step` returns.  A settle point between steps
+        (``cancel``, ``export_streams``, ``swap_weights``) can finish a
+        stream whose last token was still on the device; the next step
+        reports it, and a caller that will not step this scheduler again
+        (a router failing a replica over) asks here."""
+        finished, self._unreported = self._unreported, []
         return finished
 
     def _publish_step(self) -> None:
@@ -1710,6 +1884,8 @@ class ContinuousBatchingScheduler:
                     f"finishing")
             self.step()
             steps += 1
+        # drained: tokens still on the device are lanes of ended streams
+        self._settle("drain")
         return dict(self._results)
 
     @property

@@ -27,6 +27,7 @@ if ROOT not in sys.path:
 
 from apex_tpu import serving as sv  # noqa: E402
 from apex_tpu.obs import scopes  # noqa: E402
+from apex_tpu.serving.engine import DECODE_VECTORS  # noqa: E402
 from benchmark.lib import device_scopes as ds  # noqa: E402
 
 EVERY = {"embed", "norm", "attn_proj", "cache_write", "cache_read", "head"}
@@ -76,8 +77,8 @@ def lowered(family):
     scalar = arg((), jnp.int32)
     return {
         "decode": engine._decode.lower(
-            params, cache, arg((sizes["slots"],), jnp.int32),
-            arg((sizes["slots"],), bool)),
+            params, cache, *(arg((sizes["slots"],), dtype)
+                             for dtype in DECODE_VECTORS)),
         "prefill": engine._prefill.lower(
             params, cache, arg((1, engine.prefill_buckets[-1]), jnp.int32),
             scalar, scalar, scalar)}

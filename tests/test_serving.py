@@ -886,7 +886,8 @@ def test_cached_read_builds_no_expanded_or_upcast_cache_view(bf16_engine,
     if program == "decode":
         lanes = eng.slots
         jaxpr = jax.make_jaxpr(eng._decode)(
-            eng.params, eng._cache, jnp.zeros((lanes,), jnp.int32),
+            eng.params, eng._cache, eng.last_sampled,
+            jnp.zeros((lanes,), jnp.int32), jnp.zeros((lanes,), bool),
             jnp.ones((lanes,), bool))
     else:
         lanes = 1                     # a prefill call reads one slot

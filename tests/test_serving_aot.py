@@ -32,12 +32,14 @@ from unittest import mock
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from apex_tpu import serving as sv
 from apex_tpu.models import LlamaConfig, LlamaForCausalLM
 from apex_tpu.ops import _dispatch
+from apex_tpu.serving.engine import DECODE_VECTORS
 from apex_tpu.serving.kv_cache import init_cache
 
 # the serving cell's attention geometry (benchmark/configs/
@@ -118,14 +120,19 @@ def _compiled_text(engine, one_chip, program):
     return lowered.compile().as_text()
 
 
+def _decode_vectors(arg, slots):
+    """The decode program's ``[slots]`` operands behind params and cache."""
+    return [arg((slots,), dtype) for dtype in DECODE_VECTORS]
+
+
 def _lower_decode_as_on_the_chip(engine, params, cache, arg):
     """``engine._decode`` lowered with every kernel's dispatch answering
     as on a TPU backend (the described chip is no backend: left alone, the
     trace takes each ``jax.numpy`` reference)."""
     slots = cache.lengths.shape[0]
     with mock.patch.object(_dispatch, "on_tpu", lambda: True):
-        return engine._decode.lower(
-            params, cache, arg((slots,), jnp.int32), arg((slots,), bool))
+        return engine._decode.lower(params, cache,
+                                    *_decode_vectors(arg, slots))
 
 
 def _entry_ops(text, dtype="bf16"):
@@ -181,6 +188,82 @@ def test_compiled_program_copies_no_cache_slab(compiled_text, program):
         f"{program}: the compiled program copies the cache into another "
         f"layout ({len(copies)} slab-sized copies, e.g. {copies[:3]}): "
         "the cached read no longer takes it as it is stored")
+
+
+def test_decode_takes_the_kept_vector_as_an_operand_and_donates_the_cache(
+        engine, one_chip):
+    """Decode-ahead (ISSUE 36): each slot's last sampled token is an operand
+    the device already holds, chosen against the host's vector by a mask
+    inside the one program; the cache, and only the cache, is donated."""
+    on_chip, arg = _placed(one_chip)
+    cache = on_chip(jax.eval_shape(
+        lambda: init_cache(engine.model.cache_layers(), slots=SLOTS,
+                           max_len=MAX_LEN, dtype=jnp.bfloat16)))
+    lowered = _lower_decode_as_on_the_chip(engine, on_chip(engine.params),
+                                           cache, arg)
+    (params, cache_info, last, tokens, on_device, active), _ = (
+        lowered.args_info)
+    assert all(leaf.donated for leaf in jax.tree.leaves(cache_info))
+    assert not any(leaf.donated for leaf in jax.tree.leaves(
+        (params, last, tokens, on_device, active)))
+    text = lowered.as_text()
+    signature = text[text.index("@main("):text.index(") -> ")]
+    vectors = [
+        (kind, "tf.aliasing_output" in arg_text)
+        for arg_text in re.split(r", (?=%arg\d+:)", signature)
+        for kind in re.findall(r": tensor<%dx(i32|i1)>" % SLOTS, arg_text)]
+    # the cache's lengths (aliased to their output), then the kept vector,
+    # the host's tokens, which lanes take which, which lanes are active
+    assert vectors == [("i32", True), ("i32", False), ("i32", False),
+                       ("i1", False), ("i1", False)]
+    assert re.search(r"stablehlo\.select.*tensor<%dxi32>" % SLOTS, text)
+
+
+def test_one_decode_program_serves_kept_and_host_fed_lanes():
+    """``decode_compiles() == 1`` after a drain that mixes lanes fed from
+    the kept vector with lanes fed from the host (a settle between steps
+    leaves every lane host-fed for a step; a lane joining from prefill is
+    device-fed beside them) and after a host caller's
+    ``engine.decode(tokens, active)``."""
+    cfg = LlamaConfig(vocab_size=128, hidden_size=64, intermediate_size=128,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      num_key_value_heads=2, max_position_embeddings=256)
+    model = LlamaForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+    eng = sv.DecodeEngine(model, params, slots=3, max_len=64, prefill_len=16)
+    sched = sv.ContinuousBatchingScheduler(eng)
+    for i, n in enumerate((5, 9, 20, 7)):
+        sched.submit(sv.Request(f"r{i}", list(range(1, n + 1)), 6 + i))
+    fed = set()
+    decode = eng.decode
+
+    def spy(tokens, active, *, on_device=None):
+        fed.add((bool(on_device[active].any()),
+                 bool((~on_device[active]).any())))
+        return decode(tokens, active, on_device=on_device)
+
+    eng.decode = spy
+    steps = 0
+    while sched.queue_depth or sched.active_count:
+        sched.step()
+        steps += 1
+        if steps == 2:
+            sched._settle("test")
+        if steps == 5:
+            sched.cancel("r1")
+    # every lane kept, or kept and host-fed lanes in one call; the host
+    # caller below feeds every lane itself
+    assert fed == {(True, False), (True, True)}
+    assert eng.decode_compiles() == 1
+    eng.decode = decode
+    logits = eng.prefill(0, [3, 4, 5])
+    active = np.zeros((3,), bool)
+    active[0] = True
+    tokens = np.zeros((3,), np.int32)
+    tokens[0] = int(jnp.argmax(logits))
+    eng.decode(tokens, active)
+    assert eng.decode_compiles() == 1
+    sched.close()
 
 
 def test_decode_append_moves_rows_not_slabs(compiled_text):
@@ -434,8 +517,7 @@ def latent_compiled(latent_engine, one_chip):
         with mock.patch.object(_dispatch, "on_tpu", lambda: True):
             if program == "decode":
                 lowered = latent_engine._decode.lower(
-                    params, cache, arg((LATENT_SLOTS,), jnp.int32),
-                    arg((LATENT_SLOTS,), bool))
+                    params, cache, *_decode_vectors(arg, LATENT_SLOTS))
             else:
                 lowered = latent_engine._prefill.lower(
                     params, cache, arg((1, LATENT_CHUNK), jnp.int32),
@@ -552,8 +634,7 @@ def window_compiled(window_engine, one_chip):
         with mock.patch.object(_dispatch, "on_tpu", lambda: True):
             if program == "decode":
                 lowered = window_engine._decode.lower(
-                    params, cache, arg((WINDOW_SLOTS,), jnp.int32),
-                    arg((WINDOW_SLOTS,), bool))
+                    params, cache, *_decode_vectors(arg, WINDOW_SLOTS))
             else:
                 lowered = window_engine._prefill.lower(
                     params, cache, arg((1, WINDOW_CHUNK), jnp.int32),

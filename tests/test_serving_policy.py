@@ -842,9 +842,14 @@ class TestChaosAcceptance:
 
     def _workload(self):
         prompts = [_prompt(100 + i) for i in range(self.N)]
+        # deadline 5.0 (4.0 before ISSUE 36): a stream's last token is
+        # delivered a step after the step that computed it, so every
+        # request takes one virtual step more and the preempted victims,
+        # which came back inside 4.0 with steps to spare, would now be
+        # shed while suspended instead of resuming
         return sv.make_workload(
             prompts, sv.burst_arrivals(self.N, burst=5, period_s=2.0),
-            max_new_tokens=6, deadline_s=4.0,
+            max_new_tokens=6, deadline_s=5.0,
             priorities=self.PRIORITIES, tenants=self.TENANTS,
             rid_prefix="cx")
 

@@ -177,10 +177,11 @@ class TestVirtualClockTiming:
     def test_exact_latency_arithmetic(self, engine):
         """On a shared VirtualClock every latency is an exact multiple
         of the virtual step: a one-chunk prompt admits, prefills,
-        samples its first token AND rides the same step's decode
-        (2 tokens inside step 1, TTFT exactly 0.0), then one token per
-        step — 3 tokens finish one step later (total exactly 0.25,
-        TPOT exactly 0.125)."""
+        samples its first token and delivers it inside step 1 (TTFT
+        exactly 0.0) while the same step's decode runs; each later step
+        delivers the token the step before it computed (ISSUE 36) — 3
+        tokens finish two steps later (total exactly 0.5, TPOT exactly
+        0.25)."""
         clk = sv.VirtualClock()
         sched = _sched(engine, clk)
         rec = rt.RequestTraceRecorder(clock=clk).install()
@@ -192,19 +193,19 @@ class TestVirtualClockTiming:
             rec.uninstall()
         res = out.results["lg0"]
         assert res.ttft_s == 0.0
-        assert res.total_s == 0.25
+        assert res.total_s == 0.5
         (record,) = rec.records()
         assert record.complete
         assert record.queue_wait_s == 0.0
         assert record.prefill_s == 0.0
-        assert record.decode_s == 0.25
-        assert record.total_s == 0.25
-        assert record.tpot_s == 0.125
+        assert record.decode_s == 0.5
+        assert record.total_s == 0.5
+        assert record.tpot_s == 0.25
         # the recorder's view and the scheduler's event measurements
         # agree exactly — one shared clock, one timeline
         assert record.scheduler_ttft_s == res.ttft_s
         assert record.scheduler_queue_wait_s == 0.0
-        assert out.goodput == 1.0 and out.duration_s == 0.5
+        assert out.goodput == 1.0 and out.duration_s == 0.75
 
     def test_chunked_prompt_ttft_spans_steps(self, engine):
         """A prompt needing two budgeted chunks takes two steps to
@@ -486,20 +487,21 @@ class TestDeadlineFromArrival:
         """A request due MID-step is submitted at the next boundary —
         the submit lag must come out of its deadline budget, not
         silently extend it.  Arrival at t=0.1, submitted at t=0.25,
-        finished at t=0.5: submit-relative elapsed is 0.25 (under a
-        0.3 deadline) but arrival-relative is 0.4 — a miss."""
+        finished at t=0.75 (its third token is delivered by the third
+        step): submit-relative elapsed is 0.5 (under a 0.6 deadline)
+        but arrival-relative is 0.65 — a miss."""
         clk = sv.VirtualClock()
         sched = _sched(engine, clk)
         rec = rt.RequestTraceRecorder(clock=clk).install()
         try:
             wl = sv.make_workload([[1, 2, 3]], (0.1,),
-                                  max_new_tokens=3, deadline_s=0.3)
+                                  max_new_tokens=3, deadline_s=0.6)
             out = sv.LoadGenerator(sched, wl, step_time_s=0.25).run()
         finally:
             rec.uninstall()
         res = out.results["lg0"]
         assert out.arrivals["lg0"] == 0.1
-        assert res.total_s == 0.25           # submit-relative: "meets"
+        assert res.total_s == 0.5            # submit-relative: "meets"
         assert out.met_deadline["lg0"] is False
         assert out.goodput == 0.0
         # the report agrees when given the arrivals, and documents the

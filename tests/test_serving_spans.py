@@ -102,12 +102,19 @@ def test_three_steps_record_exactly_the_tables_spans(engine):
             if e["name"] == "engine.sample"} == {"serving.prefill",
                                                   "serving.decode"}
 
-    # one wait per decoding step, one per finished prompt, nothing else
-    reads = [e["args"] for e in evs if e["name"] == "serving.readback"]
-    assert [r["what"] for r in reads].count("decode") == 3
-    first = [r for r in reads if r["what"] == "first_token"]
-    assert sorted(r["rid"] for r in first) == ["r0", "r1"]
-    assert len(reads) == 5
+    # one wait a step, after everything the step enqueues: the first
+    # step's for both prompts' first tokens (its decode runs meanwhile),
+    # each later one for the decode enqueued a step before (ISSUE 36)
+    reads = [e for e in evs if e["name"] == "serving.readback"]
+    assert [(r["args"]["what"], r["args"]["lag"]) for r in reads] == [
+        ("first_token", 0), ("decode", 1), ("decode", 1)]
+    for step, read in zip(steps, reads):
+        assert _inside(read, step)
+        assert read["ts"] >= max(
+            e["ts"] + e["dur"] for e in evs
+            if e["name"].startswith("engine.") and _inside(e, step))
+    assert [e["args"]["ahead"] for e in evs
+            if e["name"] == "serving.decode"] == [0, 1, 1]
     assert sorted(e["args"]["rid"] for e in evs
                   if e["name"] == "serving.submit") == ["r0", "r1"]
 
@@ -163,7 +170,9 @@ def test_off_path_yields_none_and_builds_no_span(engine, monkeypatch):
         "NoAnnotation", (), {"is_enabled": staticmethod(lambda: False),
                              "__init__": no_span}))
     sched, _, tokens = _three_steps(engine)
-    assert sched.steps_run == 3 and set(tokens.values()) == {4}
+    # three steps deliver three tokens: the first, and two of the three
+    # decoded ones (the third is read by the fourth step)
+    assert sched.steps_run == 3 and set(tokens.values()) == {3}
 
 
 _WALL = ("time",)
@@ -236,8 +245,8 @@ def test_under_jax_profiler_the_spans_are_on_the_profiles_host_plane(
     assert [st["chunks"] for st in stats["serving.prefill"]] == [2, 0, 0]
     assert [st["kv_tokens"] for st in stats["engine.decode"]] == [
         int(n.sum()) - 2 for n in lengths]
-    assert [st["what"] for st in stats["serving.readback"]].count(
-        "decode") == 3
+    assert [st["what"] for st in stats["serving.readback"]] == [
+        "first_token", "decode", "decode"]
     assert not jax.profiler.TraceAnnotation.is_enabled()
 
 

@@ -680,6 +680,63 @@ only ever sees the compiled programs, and the decode step compiles
 no per-request retraces, the recompile tax the slotted cache exists to
 eliminate).
 
+## The step's contract: enqueue all, read once (decode-ahead)
+
+A step enqueues **all** of its device work and then waits for the
+device **once**, at its end, for what the device finished *before* this
+step's decode:
+
+1. admit (no device read: a request's PRNG key words are made on the
+   host, `request_key_bits`, the same bits as `PRNGKey(seed)`);
+2. prefill chunks; a completed prompt's first token is sampled on the
+   device and **stays there** (`DecodeEngine.keep_sampled` writes it into
+   the engine's `[slots] int32` vector of last sampled tokens), and the
+   lane joins this step's decode;
+3. the shared decode step for every decoding lane whose *issued* count
+   (tokens whose computation has been enqueued) is below
+   `max_new_tokens`.  The program reads each lane's input token from the
+   kept vector, or from the host's vector for a lane whose newest token
+   only the host has (`engine.decode(tokens, active, on_device=mask)`;
+   one compiled program either way).  The sampler's result replaces the
+   active lanes of the kept vector; keys and indices follow the issued
+   count, so sampled streams are bit-identical to a serial scheduler's;
+4. **one read** (`serving.readback`): this step's first tokens and the
+   *previous* step's decoded tokens.  The host blocks at most until this
+   step's prefill is done; the decode is queued behind it, so the device
+   stays busy while the host appends tokens, finishes requests,
+   publishes, and enqueues the next step.
+
+What a caller can observe: **a decoded token is delivered one step after
+the step that computed it**, and a request is reported finished by the
+step that reads its last token (one step later than a serial scheduler
+would; `ttft_s` is stamped in the same step as before).  A stream that
+ends on EOS has one more lane in flight: its token is dropped when read
+(the slot was released in order behind it) and counted in
+`overlap_stats()["dropped_tokens"]`.  No token of any stream changes.
+
+**Settle points.**  Anything that needs a stream's newest token or its
+slot's rows on the host reads everything in flight first (one
+`_settle(reason)`; `overlap_stats()["settled_early"]` counts them by
+reason):
+
+| where | why |
+|---|---|
+| `speculation=` configured: after the prefill budget and again after the decode, every step (`speculation`) | drafting reads each stream's token history on the host, so such a scheduler is the serial one |
+| `policy=` preemption, before a victim is chosen (`preempt`) | the victim is captured with its newest token and its slot's rows; the read may finish it instead |
+| `cancel` of an active request (`cancel`) | the partial output is every token that was computed; the one in flight may be the last, and then it is too late to cancel |
+| `export_streams(capture=True)` (`export`) | a stream moves with its tokens and rows (`capture=False`, a killed replica, discards what is in flight unread) |
+| `swap_weights` (`swap_weights`) | every token of the displaced weights is delivered before the buffer goes back to the caller |
+| `close` (`close`), and the end of `run()` (`drain`) | what is left belongs to streams that ended: read and dropped |
+
+`adopt_stream` settles nothing: the adopted stream's newest token is
+the host's, and its lane is host-fed beside the lanes the device feeds.
+`overlap_stats()` returns `steps` (steps that enqueued a decode),
+`steps_ahead` (of them, enqueued while the previous step's tokens were
+still unread), `settled_early` and `dropped_tokens`; the
+`serving.decode` span carries `ahead` and `serving.readback` carries
+`lag` (1 when a newer decode had been enqueued before this one's tokens
+were read).
+
 ## Speculative decoding (exact-greedy prompt lookup)
 
 Plain decode pays one full weight read and one full-`max_len`-extent
@@ -1508,7 +1565,9 @@ while nothing records).  Nesting is by interval; `rid` is the
 identifier a request's spans share.  `serving.readback` wraps the
 blocking device read and nothing else, so it is the one span under
 which the host *waits*; everything else under `serving.step` is the
-host *working*.
+host *working*.  A step holds one `serving.readback`, after everything
+it enqueues (a scheduler with `speculation=` holds two: it settles
+before drafting and again after its decode).
 
 | span | where | attributes |
 |---|---|---|
@@ -1517,9 +1576,9 @@ host *working*.
 | `serving.admit` | deadline shedding + admission (policy path included) | — |
 | `serving.prefill` | the prefill budget's chunks | `chunks` (set at exit) |
 | `serving.spec` | speculative verifies, only when speculation is on | — |
-| `serving.decode` | building the step's inputs through the return of `engine.sample` | `lanes` |
-| `serving.readback` | each blocking device read: the step's sampled tokens, and a prompt's first token | `what` = `decode` / `first_token`; `rid` for the latter |
-| `serving.finish` | token append + finish checks of the decoding lanes | `finished` (set at exit) |
+| `serving.decode` | building the step's inputs through the enqueue of decode, sampler and the kept vector's update | `lanes`; `ahead` = 1 when the previous step's tokens were still unread |
+| `serving.readback` | THE blocking device read: this step's first tokens and the previous step's decoded tokens (everything in flight, at a settle point) | `what` = `decode` / `first_token` / `decode+first_token`; `lag` = 1 when a newer decode had already been enqueued |
+| `serving.finish` | token append + finish checks of what was just read | `finished` (set at exit) |
 | `serving.publish` | step counter, gauges, the `serving_step` event | — |
 | `engine.prefill_chunk` | `DecodeEngine.prefill_chunk`: input build and enqueue | `slot`, `bucket`, `tokens` |
 | `engine.decode` | all of `DecodeEngine.decode`: checks, paging, enqueue | `lanes`, `kv_tokens` (cached tokens the active lanes attend, before the append) |

@@ -41,6 +41,7 @@ def main(root: str, out: str) -> None:
     import apex_tpu
     from apex_tpu import serving as sv
     from apex_tpu.ops import _dispatch
+    from apex_tpu.serving.engine import DECODE_VECTORS
     from apex_tpu.serving.kv_cache import init_cache
 
     if not apex_tpu.__file__.startswith(os.path.abspath(root)):
@@ -90,8 +91,8 @@ def main(root: str, out: str) -> None:
             with mock.patch.object(_dispatch, "on_tpu",
                                    lambda: where == "tpu"):
                 texts = {"decode": engine._decode.lower(
-                    p, c, arg((sizes["slots"],), jnp.int32),
-                    arg((sizes["slots"],), bool)).as_text()}
+                    p, c, *(arg((sizes["slots"],), dtype)
+                            for dtype in DECODE_VECTORS)).as_text()}
                 for b in engine.prefill_buckets:
                     texts[f"prefill_{b}"] = engine._prefill.lower(
                         p, c, arg((1, b), jnp.int32), arg((), jnp.int32),

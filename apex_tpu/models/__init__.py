@@ -21,6 +21,10 @@ optimizers).  This package holds the production-shaped model definitions:
   grouped-query attention under a window of K/V rows on the
   ``sliding_attention`` layers and at full extent under YaRN on the
   ``full_attention`` ones, softmax-routed gated experts in every layer.
+- :mod:`apex_tpu.models.xing` — Xing4.0 decoder, serving only: latent
+  attention over every earlier row under YaRN, a residual stream of
+  ``hc_mult`` streams mixed around each sublayer by manifold-constrained
+  hyper-connections, sigmoid-routed gated experts beside a shared expert.
 """
 
 from apex_tpu.models.dots3 import Dots3NoteConfig, Dots3NoteForCausalLM
@@ -35,9 +39,11 @@ from apex_tpu.models.llama_pipeline import (
 from apex_tpu.models.mellum import MellumConfig, MellumForCausalLM
 from apex_tpu.models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
 from apex_tpu.models.vit import ViTConfig, ViTForImageClassification
+from apex_tpu.models.xing import XingConfig, XingForCausalLM
 
 __all__ = ["Dots3NoteConfig", "Dots3NoteForCausalLM", "LlamaConfig", "LlamaForCausalLM", "LlamaPipeConfig",
            "build_llama_pipeline", "init_llama_pipeline_params",
            "make_llama_3d_train_step", "MellumConfig", "MellumForCausalLM",
            "NemotronHConfig",
-           "NemotronHForCausalLM", "ViTConfig", "ViTForImageClassification"]
+           "NemotronHForCausalLM", "ViTConfig", "ViTForImageClassification",
+           "XingConfig", "XingForCausalLM"]

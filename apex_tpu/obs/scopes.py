@@ -41,6 +41,12 @@ scope               what is inside it
 ``apex.experts``    the grouped products, activation, weighting, combine
 ``apex.head``       final norm, the LM-head product, the logits handed back
 ``apex.sample``     the sampler
+``apex.residual``   a changed residual path around the sublayers
+                    (manifold-constrained hyper-connections): the streams'
+                    norm, the mappings' projection, sigmoids and Sinkhorn
+                    iterations, the mix a sublayer reads, the combine it
+                    writes back through, the expansion after the embedding
+                    and the read-out before the final norm
 ==================  ======================================================
 """
 
@@ -52,7 +58,7 @@ import jax
 
 __all__ = ["PREFIX", "VOCABULARY", "component", "EMBED", "NORM", "ATTN_PROJ",
            "CACHE_WRITE", "CACHE_READ", "SELECT", "STATE", "MLP", "ROUTER",
-           "EXPERTS", "HEAD", "SAMPLE"]
+           "EXPERTS", "HEAD", "SAMPLE", "RESIDUAL"]
 
 PREFIX = "apex."
 
@@ -68,9 +74,10 @@ ROUTER = "router"
 EXPERTS = "experts"
 HEAD = "head"
 SAMPLE = "sample"
+RESIDUAL = "residual"
 
 VOCABULARY = (EMBED, NORM, ATTN_PROJ, CACHE_WRITE, CACHE_READ, SELECT, STATE,
-              MLP, ROUTER, EXPERTS, HEAD, SAMPLE)
+              MLP, ROUTER, EXPERTS, HEAD, SAMPLE, RESIDUAL)
 
 
 def _open(name: str):

@@ -46,8 +46,9 @@ twin, and one seam a model's attention calls through:
   nothing about int8.
 - **What a model keeps a slot** - ``model.cache_layers()``: one of
   :class:`KVRows`, :class:`KVWindowRows`, :class:`RecurrentRows`,
-  :class:`LatentRows`, :class:`RingRows`, :class:`CallCounters` or None a
-  layer; :func:`init_cache` builds every cache from that.
+  :class:`LatentRows` (with selector keys, or :class:`FullLatentRows`
+  without), :class:`RingRows`, :class:`CallCounters` or None a layer;
+  :func:`init_cache` builds every cache from that.
 - **The seam**: :func:`decode_attend` and :func:`prefill_attend` are the
   whole step an attention layer needs - append or chunk-write, then the
   read: on a TPU a dense float cache's rows where they lie, through the
@@ -114,7 +115,7 @@ __all__ = ["KVCache", "QuantKVCache", "FloatRows", "Int8Rows", "DenseLayout",
            "LatentCache", "latent_decode_attend", "latent_prefill_attend",
            "ring_decode_attend", "ring_prefill_attend", "other_state",
            "KVWindowRows", "WindowKVCache", "window_decode_attend",
-           "window_prefill_attend"]
+           "window_prefill_attend", "FullLatentRows"]
 
 
 # ---- storage format: how a row is stored -----------------------------------
@@ -894,6 +895,22 @@ class LatentRows:
 
 
 @dataclasses.dataclass(frozen=True)
+class FullLatentRows(LatentRows):
+    """A latent-attention layer without a selector: ``[max_len, width]``
+    latent rows a slot, no selector keys, and a query attends every row at
+    or before its position (DeepSeek-V3's MLA)."""
+
+    index_width: int = 0
+    top_k: int = 0
+    what = "latent rows read whole"
+
+    def rows_read(self, live) -> dict:
+        """What one decode step of this layer reads: every live row of the
+        active lanes (the appended one among them)."""
+        return {"latent_rows": int(live.sum())}
+
+
+@dataclasses.dataclass(frozen=True)
 class RingRows:
     """A latent-attention layer that sees ``window`` positions, the query's
     own among them: a ring of ``rows`` latent rows a slot, position ``p`` at
@@ -1457,7 +1474,14 @@ def latent_decode_attend(cache, layer: int, q, row, position, *,
 
     What the step touches in proportion to ``max_len`` is the selector's
     keys (blocks up to the longest lane's length) and one float32 score a
-    row; the latent rows it reads are the ``top_k`` it gathers."""
+    row; the latent rows it reads are the ``top_k`` it gathers.
+
+    ``select`` None (a layer that declared :class:`FullLatentRows`): append
+    the row, then read every row ``idx <= position`` of each lane
+    (:func:`_latent_full_read`)."""
+    if select is None:
+        return _full_decode_attend(cache, layer, q, row, position,
+                                   scale=scale, rank=rank)
     position = jnp.asarray(position, jnp.int32)
     with component(CACHE_WRITE):
         cache = dataclasses.replace(
@@ -1491,6 +1515,69 @@ def latent_decode_attend(cache, layer: int, q, row, position, *,
         ctx = _attend(q[:, None], rows, rows[..., :rank], chosen[:, None],
                       scale)
         return ctx[:, 0], cache
+
+
+def _full_decode_attend(cache, layer: int, q, row, position, *,
+                        scale: float, rank: int):
+    """:func:`latent_decode_attend` of a layer without a selector."""
+    position = jnp.asarray(position, jnp.int32)
+    with component(CACHE_WRITE):
+        cache = dataclasses.replace(
+            cache, latent=_lane_write(cache.latent, layer, position, row))
+    with component(CACHE_READ):
+        return _latent_full_read(q, cache.latent, layer, position,
+                                 scale=scale, rank=rank), cache
+
+
+def _read_block(max_len: int) -> int:
+    """Rows a step of :func:`_latent_full_read` takes of every lane: 2,048
+    (10.5 MB of 640-wide bfloat16 rows at 16 lanes), or a quarter of a
+    short cache (the tests' sizes walk several blocks too)."""
+    block = min(2048, max(8, max_len // 4))
+    return block if max_len % block == 0 else max_len
+
+
+def _latent_full_read(q, latent, layer: int, bound, *, scale: float,
+                      rank: int):
+    """The absorbed read of every row ``idx <= bound[lane]`` of each lane
+    of ``latent[layer]``: ``q [lanes, H, width]`` (scaled here, in the
+    rows' dtype, zeros up to the stored width) against the rows as stored,
+    values their first ``rank`` columns.  The flash recurrence - running
+    max, sum and values, float32 - over blocks of :func:`_read_block` rows
+    up to the longest lane's bound, sliced from the buffer where it lies:
+    no block past that is read and the cache is never copied.  A row past a
+    lane's bound is zeroed before either product (garbage by contract, and
+    ``0 * nan`` is not ``0``).  Returns ``[lanes, H, rank]`` float32."""
+    lanes, heads = q.shape[:2]
+    max_len, stored = latent.shape[2], latent.shape[3]
+    block = _read_block(max_len)
+    qs = (q.astype(jnp.float32) * scale).astype(latent.dtype)
+    qs = jnp.pad(qs, ((0, 0), (0, 0), (0, stored - qs.shape[-1])))
+
+    def read(i, carry):
+        top, total, acc = carry
+        rows = lax.dynamic_slice(latent, (layer, 0, i * block, 0),
+                                 (1, lanes, block, stored))[0]
+        seen = (i * block + jnp.arange(block, dtype=jnp.int32))[None] <= (
+            bound[:, None])                                # [lanes, block]
+        rows = jnp.where(seen[..., None], rows, jnp.zeros_like(rows))
+        sc = jnp.einsum("bhd,bnd->bhn", qs, rows,
+                        preferred_element_type=jnp.float32)
+        sc = jnp.where(seen[:, None], sc, _NEG_INF)
+        new_top = jnp.maximum(top, sc.max(-1))
+        e = jnp.exp(sc - new_top[..., None])
+        keep = jnp.exp(top - new_top)
+        acc = acc * keep[..., None] + jnp.einsum(
+            "bhn,bnr->bhr", e.astype(rows.dtype), rows[..., :rank],
+            preferred_element_type=jnp.float32)
+        return new_top, total * keep + e.sum(-1), acc
+
+    _, total, acc = lax.fori_loop(
+        0, jnp.minimum(jnp.max(bound) // block + 1, max_len // block), read,
+        (jnp.full((lanes, heads), _NEG_INF, jnp.float32),
+         jnp.zeros((lanes, heads), jnp.float32),
+         jnp.zeros((lanes, heads, rank), jnp.float32)))
+    return acc / total[..., None]
 
 
 def ring_decode_attend(cache, layer: int, q, row, position, *, scale: float,
@@ -1573,7 +1660,14 @@ def latent_prefill_attend(cache, layer: int, slot, q, rows, offset, *,
     and dense over the visible blocks: one recurrence, two implementations
     (:func:`_reads_chunk_in_place` chooses) - on a TPU the kernel that
     takes the stored rows whole and keeps a block's scores in fast memory,
-    everywhere else the loop below."""
+    everywhere else the loop below.
+
+    ``select`` None (a layer that declared :class:`FullLatentRows`): write
+    the rows, then the same read under the causal mask ``idx <= offset +
+    row`` (:func:`_full_prefill_attend`)."""
+    if select is None:
+        return _full_prefill_attend(cache, layer, slot, q, rows, offset,
+                                    scale=scale, expand=expand)
     s = q.shape[0]
     slot = jnp.asarray(slot, jnp.int32)
     offset = jnp.asarray(offset, jnp.int32)
@@ -1613,6 +1707,35 @@ def latent_prefill_attend(cache, layer: int, slot, q, rows, offset, *,
                 nope=expand["nope"], block=block)
         else:
             ctx = _chunk_read(qs, cache.latent, selected, expand, layer, slot,
+                              blocks, block=block, width=rows.shape[-1])
+    return ctx, cache
+
+
+def _full_prefill_attend(cache, layer: int, slot, q, rows, offset, *,
+                         scale: float, expand: dict):
+    """:func:`latent_prefill_attend` of a layer without a selector: the
+    chunk's rows written, then each query reads every row up to its own
+    position - the kernel or the loop of a selecting layer's chunk, handed
+    the causal mask for a selection."""
+    s = q.shape[0]
+    slot = jnp.asarray(slot, jnp.int32)
+    offset = jnp.asarray(offset, jnp.int32)
+    at = offset + jnp.arange(s, dtype=jnp.int32)
+    with component(CACHE_WRITE):
+        cache = dataclasses.replace(cache, latent=cache.latent.at[
+            layer, slot, at].set(_as_stored(rows, cache.latent), mode="drop"))
+    max_len = cache.max_len
+    block = _key_block(max_len)
+    blocks = jnp.minimum((offset + s - 1) // block + 1, max_len // block)
+    with component(CACHE_READ):
+        causal = jnp.arange(max_len, dtype=jnp.int32)[None] <= at[:, None]
+        qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        if _reads_chunk_in_place(cache, q, expand, block):
+            ctx = latent_chunk_attention(
+                qs, cache.latent, causal, expand["w"], layer, slot, blocks,
+                nope=expand["nope"], block=block)
+        else:
+            ctx = _chunk_read(qs, cache.latent, causal, expand, layer, slot,
                               blocks, block=block, width=rows.shape[-1])
     return ctx, cache
 

@@ -1,6 +1,6 @@
 """The scopes of ``apex_tpu/obs/scopes.py`` in the engine's compiled programs.
 
-Each of the five tiny serving configurations - the four families of the
+Each of the six tiny serving configurations - the five families of the
 benchmark's tests and the Llama family behind a block table - has its
 ``_decode`` and its largest ``_prefill`` compiled here on the CPU, and the
 compiled text is read the way ``benchmark/lib/device_scopes.py`` reads a
@@ -43,6 +43,8 @@ FAMILIES = {
               EVERY | {"select", "mlp", "router", "experts"}),
     "mellum": ("tiny-mellum", "tiny-repo-closed", {},
                EVERY | {"router", "experts"}),
+    "xing": ("tiny-xing", "tiny-mladoc-closed", {},
+             EVERY | {"mlp", "router", "experts", "residual"}),
 }
 TIMED = ("dot", "fusion", "custom-call", "while", "sort")
 
@@ -146,3 +148,19 @@ def test_the_scopes_leave_the_lowered_program_as_it_was(family, monkeypatch):
     assert with_scopes == without
     # the patch took: a compiled program of the patched trace names none
     assert "apex." not in lowered(family)["decode"].compile().as_text()
+
+
+def test_the_residual_scope_is_opened_and_leaves_nothing_unscoped():
+    """``apex.residual`` (the hyper-connections around every sublayer) in
+    both of the mHC family's programs, and at least 99 % of their timed
+    instructions under some component: what ``scope_coverage.serve`` reads
+    off the chip's trace."""
+    for program, low in lowered("xing").items():
+        text = low.compile().as_text()
+        computations = ds.text_computations(text)
+        component = ds.resolve(computations)
+        timed = [i.name for instrs, _ in computations.values()
+                 for i in instrs if i.opcode in TIMED]
+        named = [component[n] for n in timed]
+        assert named.count("residual") > 0.1 * len(timed), program
+        assert named.count(ds.UNSCOPED) <= 0.01 * len(timed), program

@@ -366,3 +366,40 @@ def test_engine_prefill_through_the_kernel_is_the_loops(dispatched,
     for step, (got, want) in enumerate(zip(got_logits, want_logits)):
         np.testing.assert_allclose(got, want, rtol=0, atol=ENGINE_ATOL,
                                    err_msg=f"call {step}")
+
+
+def test_engine_without_a_selector_prefills_through_the_kernel(dispatched,
+                                                               monkeypatch):
+    """A model whose latent layers read every row (Xing4.0's MLA,
+    ``FullLatentRows``) at the kernel's widths: its chunks hand the kernel
+    the causal mask for a selection, and prefill and decode agree with the
+    loop's."""
+    from apex_tpu.models.xing import XingConfig, XingForCausalLM
+
+    model = XingForCausalLM(XingConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=96,
+        num_hidden_layers=2, first_k_dense_replace=1, num_attention_heads=2,
+        q_lora_rank=16, kv_lora_rank=RANK, qk_nope_head_dim=NOPE,
+        qk_rope_head_dim=ROPE, v_head_dim=DV, n_routed_experts=8,
+        experts_held=(0, 8), num_experts_per_tok=2, moe_intermediate_size=24))
+    params = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    params = jax.tree.map(lambda l: l if l.ndim == 1 else 5 * l, params)
+
+    def engine():
+        return sv.DecodeEngine(model, params, slots=3, max_len=MAX_LEN,
+                               prefill_len=64)
+
+    with monkeypatch.context() as mp:
+        mp.setenv("APEX_TPU_KERNELS", "0")
+        want_streams, want_logits = _drive(engine())
+    assert not dispatched
+    eng = engine()
+    got_streams, got_logits = _drive(eng)
+    assert {e["m"] for e in dispatched} == {16, 32, 64}
+    assert all(e["path"] == "pallas" and e["stored"] == 256
+               for e in dispatched)
+    assert len(dispatched) == 2 * eng.prefill_compiles()
+    assert got_streams == want_streams
+    for step, (got, want) in enumerate(zip(got_logits, want_logits)):
+        np.testing.assert_allclose(got, want, rtol=0, atol=ENGINE_ATOL,
+                                   err_msg=f"call {step}")

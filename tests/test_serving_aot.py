@@ -717,3 +717,99 @@ def test_window_chunk_walks_in_the_kernel_and_keeps_no_block_of_scores(
         f"float32 arrays as large as a block's scores over a KV head's 8 x "
         f"1,024 query rows in the prefill program: {large}")
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+# -- latent rows read whole: multi-head latent attention without a selector
+# (Xing4.0) -------------------------------------------------------------------
+# the mladoc cell's attention geometry (benchmark/configs/xing4-29b-l6.json,
+# traffic/mladoc-closed.json): two layers at published attention widths (32
+# heads, rank 512 + rope 64 stored 640 wide, a query LoRA of 768), 16 slots
+# of 24,576 rows, a 1,024-row chunk.  Dense and narrow MLPs: they keep no
+# rows
+
+MLA_SLOTS, MLA_MAX_LEN, MLA_CHUNK, MLA_LAYERS = 16, 24576, 1024, 2
+
+
+@pytest.fixture(scope="module")
+def mla_compiled(one_chip):
+    """``mla_compiled(program)``: the program compiled for the cell-sized
+    cache on the path the chip takes, once for the module."""
+    from apex_tpu.models.xing import XingConfig, XingForCausalLM
+
+    model = XingForCausalLM(XingConfig(
+        vocab_size=256, intermediate_size=256, num_hidden_layers=MLA_LAYERS,
+        first_k_dense_replace=MLA_LAYERS), params_dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    params = jax.tree.map(lambda l: jnp.zeros(l.shape, l.dtype), shapes)
+    engine = sv.DecodeEngine(model, params, slots=2, max_len=64,
+                             prefill_len=64, cache_dtype=jnp.bfloat16)
+
+    def compiled(program):
+        on_chip, arg = _placed(one_chip)
+        cache = on_chip(jax.eval_shape(lambda: init_cache(
+            model.cache_layers(), slots=MLA_SLOTS, max_len=MLA_MAX_LEN,
+            dtype=jnp.bfloat16)))
+        assert cache.latent.shape == (MLA_LAYERS, MLA_SLOTS, MLA_MAX_LEN,
+                                      640)
+        with mock.patch.object(_dispatch, "on_tpu", lambda: True):
+            if program == "decode":
+                lowered = engine._decode.lower(
+                    on_chip(params), cache,
+                    *_decode_vectors(arg, MLA_SLOTS))
+            else:
+                lowered = engine._prefill.lower(
+                    on_chip(params), cache, arg((1, MLA_CHUNK), jnp.int32),
+                    arg((), jnp.int32), arg((), jnp.int32),
+                    arg((), jnp.int32))
+        return lowered.compile()
+
+    return functools.cache(compiled)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_latent_rows_read_whole_are_never_copied_or_cut(mla_compiled,
+                                                        program):
+    """Every live row is read where it lies: the buffer stays row-major, no
+    copy or cut of its size is made, and what the program keeps beside its
+    arguments is far below one layer's rows (0.5 GB here)."""
+    compiled = mla_compiled(program)
+    text = compiled.as_text()
+    rows = "bf16[%d,%d,%d,640]" % (MLA_LAYERS, MLA_SLOTS, MLA_MAX_LEN)
+    assert rows + "{3,2,1,0" in text, "the latent rows are not row-major"
+    slab = MLA_SLOTS * MLA_MAX_LEN * 640
+    found = _slab_sized_layout_copies(text, slab) + _slab_sized_cuts(
+        text, slab)
+    assert not found, f"{program} copies or cuts the latent rows: {found}"
+    limit = 64e6 if program == "decode" else 0.4e9
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
+
+
+def test_decode_reads_whole_rows_in_a_loop_bounded_by_the_lanes(
+        mla_compiled):
+    """The decode step's read of each layer is a loop over blocks of rows
+    that takes the whole buffer as it lies and stops at a bound computed
+    from the lanes' lengths (its condition compares the counter with a
+    value of the loop's state, not a constant: no block past the longest
+    lane is read)."""
+    text = mla_compiled("decode").as_text()
+    rows = "bf16[%d,%d,%d,640]" % (MLA_LAYERS, MLA_SLOTS, MLA_MAX_LEN)
+    loops = [line for line in text.splitlines()
+             if re.match(r"\s*%while[\w.]* = \(", line) and rows in
+             line.split(" while(")[0]]
+    assert len(loops) == MLA_LAYERS, len(loops)
+    for line in loops:
+        cond = re.search(r"condition=%([\w.\-]+)", line).group(1)
+        body = text[text.index(f"\n%{cond} "):]
+        body = body[:body.index("\n}")]
+        root = next(l for l in body.splitlines() if "ROOT" in l)
+        assert "compare(" in root and root.count("get-tuple-element") == 2, (
+            root[:200])
+
+
+def test_mla_chunk_reads_through_the_kernel_under_a_causal_mask(
+        mla_compiled):
+    """A chunk of a layer without a selector is read by the chunk kernel,
+    once a layer, on the stored rows."""
+    text = mla_compiled("prefill").as_text()
+    assert len(_kernel_calls(text, "latent_chunk_attention")) == MLA_LAYERS

@@ -35,7 +35,7 @@ SEAM = {"decode_attend", "prefill_attend", "window_decode_attend",
         "latent_prefill_attend", "ring_decode_attend", "ring_prefill_attend",
         "slot_state", "write_slot_state", "write_lane_state", "add_counts",
         "KVRows", "KVWindowRows", "RecurrentRows", "LatentRows", "RingRows",
-        "CallCounters"}
+        "CallCounters", "FullLatentRows"}
 
 
 # ---- (a) the source: who knows what ---------------------------------------

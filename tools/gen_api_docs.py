@@ -72,7 +72,7 @@ PAGES = {
         "apex_tpu.models", "apex_tpu.models.llama",
         "apex_tpu.models.llama_pipeline", "apex_tpu.models.vit",
         "apex_tpu.models.nemotron_h", "apex_tpu.models.dots3",
-        "apex_tpu.models.mellum",
+        "apex_tpu.models.mellum", "apex_tpu.models.xing",
     ]),
     "contrib": ("Contrib extensions", [
         "apex_tpu.contrib.xentropy", "apex_tpu.contrib.focal_loss",
@@ -579,6 +579,7 @@ layers reach it through the seam's functions and name no cache class:
 | `KVWindowRows(kv_heads, head_dim, window)` | a ring of `window` K and V rows in whole 16-row tiles, position `p` at row `p mod rows`, whatever `max_len` | `window_decode_attend` (append at `position mod rows`, then the in-place decode kernel on the ring buffers where the ring is exactly the window, else the ring under a mask) / `window_prefill_attend` (the `window - 1` rows before the chunk from the ring, the chunk's own from the ones in hand, then the chunk's last real rows into the ring) | `models.mellum` (`sliding_attention` layers) |
 | `RecurrentRows(ssm, conv)` | a float32 state and a convolution tail of fixed size | `slot_state` / `write_slot_state` / `write_lane_state` | `models.nemotron_h` (`M` layers) |
 | `LatentRows(width, index_width, top_k)` | `[max_len, width]` latent rows (the compressed K/V and the shared rope key, stored in whole lane tiles) and `[max_len, index_width]` selector keys | `latent_decode_attend` (append, score the live rows' keys, `top_k`, gather, absorbed read) / `latent_prefill_attend` (chunk-write, blocked scores, the selection as a mask, blocked explicit read: on a TPU one Pallas kernel, `ops.latent_chunk_attention`, over the rows in place with a block's scores in fast memory, elsewhere a loop; the `kernel_dispatch` event `latent_chunk_attention` says which) | `models.dots3` (`full_attention` layers) |
+| `FullLatentRows(width)` | `[max_len, width]` latent rows as `LatentRows` stores them, no selector keys | `latent_decode_attend` / `latent_prefill_attend` with `select=None` (append, then every live row of each lane in the absorbed form, a block of 2,048 rows at a time up to the longest lane's length, in place; a chunk: chunk-write, then the read a selecting layer's chunk takes - kernel or loop - under the causal mask) | `models.xing` (every layer) |
 | `RingRows(width, window)` | a ring of `window` rows in whole 16-row tiles, position `p` at row `p mod rows`, whatever `max_len` | `ring_decode_attend` / `ring_prefill_attend` | `models.dots3` (`sliding_attention` layers) |
 | `CallCounters(names)` | int32 counts a decode step adds (`engine.moe_stats()`) | `add_counts` | both routed-expert layers (`transformer.moe.LatentMoE`, `GatedMoE`, the latter sigmoid- or softmax-routed) |
 
@@ -1599,7 +1600,7 @@ trace, host clock only: `rec.export("step.json")`.
 ### Device time by component (`obs.scopes`)
 
 The spans above are the host's side of a step.  The device's side is
-named by `apex_tpu.obs.scopes`: one vocabulary of `jax.named_scope`s,
+named by `apex_tpu.obs.scopes`: one vocabulary of thirteen `jax.named_scope`s,
 `component(name)` = the scope `apex.<name>` (a context manager and a
 decorator; a name outside the vocabulary raises), opened around each
 part of every served model, of the cache's seam and of the expert
@@ -1625,6 +1626,7 @@ and the innermost `apex.<name>` of an instruction is its component.
 | `apex.experts` | the grouped products (`grouped_matmul`), activation, weighting and combine, `LatentMoE`'s latent down / up | `transformer/moe.py` |
 | `apex.head` | final norm, the LM-head product, the logits the engine hands back | each served model's `__call__`, `DecodeEngine`'s programs |
 | `apex.sample` | the sampler | `serving/engine.py::_sample_one` |
+| `apex.residual` | a changed residual path (manifold-constrained hyper-connections): the streams' norm, the mappings' projection, sigmoids and Sinkhorn iterations, the mix a sublayer reads and the combine it writes back through, the expansion after the embedding, the read-out before the final norm | `models/xing.py` |
 
 **Where a running engine's device time goes.**  Capture a few steps -
 `obs.start_jax_profiler(logdir)` ... `obs.stop_jax_profiler()`, or

@@ -1,7 +1,7 @@
-"""Lowered text of the accepted K/V engines' ``_decode`` and ``_prefill``
-programs at their cells' shapes, to compare two checkouts bit for bit (the
-check PRs 29, 30 and 33 made: a change to ``serving/kv_cache.py`` that is
-not meant for the Mistral and Nemotron-H cells leaves every file the same).
+"""Lowered text of the accepted engines' ``_decode`` and ``_prefill``
+programs at their cells' shapes, to compare two checkouts bit for bit (a
+change to ``serving/kv_cache.py`` that is not meant for the Mistral,
+Nemotron-H, dots3-note or Mellum cells leaves every file the same).
 
     python tools/lowered_programs.py <checkout> <out dir>     # once a tree
     diff -rq <out dir of the parent> <out dir of the change>
@@ -25,7 +25,11 @@ CELLS = {
     "mistral": ("benchmark/configs/mistral-7b-l16.json",
                 "benchmark/traffic/chat-closed.json"),
     "nemotron": ("benchmark/configs/nemotron3-super-ep4-l11.json",
-                 "benchmark/traffic/chat-closed-64.json")}
+                 "benchmark/traffic/chat-closed-64.json"),
+    "dots3": ("benchmark/configs/dots3-note-ep8-l5.json",
+              "benchmark/traffic/longdoc-closed.json"),
+    "mellum": ("benchmark/configs/mellum2-12b-l8.json",
+               "benchmark/traffic/repo-closed.json")}
 
 
 def main(root: str, out: str) -> None:

@@ -45,9 +45,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.models.mellum import RopeParameters
 from apex_tpu.normalization import FusedRMSNorm
 from apex_tpu.obs.scopes import ATTN_PROJ, EMBED, HEAD, MLP, NORM, component
-from apex_tpu.ops.rope import fused_apply_rotary_pos_emb
+from apex_tpu.ops.rope import (
+    fused_apply_rotary_pos_emb,
+    fused_apply_rotary_pos_emb_cached,
+)
 from apex_tpu.transformer.moe import MOE_COUNTERS, GatedMoE
 from apex_tpu.transformer.parallel_state import TENSOR_PARALLEL_AXIS
 from apex_tpu.transformer.tensor_parallel import (
@@ -58,6 +62,9 @@ from apex_tpu.transformer.tensor_parallel import (
 __all__ = ["Dots3NoteConfig", "Dots3NoteForCausalLM"]
 
 FULL, WINDOW = "full_attention", "sliding_attention"
+# a latent layer of another family that attends every earlier row: no
+# selector, no window (:class:`LatentAttention`'s third kind)
+EVERY_ROW = "every_row"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,15 +126,19 @@ class Dots3NoteConfig:
         return layer - self.first_k_dense_replace
 
     def attention(self, kind: str) -> dict:
-        """The sizes of one kind of attention layer, under one set of
-        names: the ``swa_`` keys for a window layer."""
+        """What :class:`LatentAttention` reads of one kind of layer, under
+        one set of names: the ``swa_`` keys for a window layer; a plain
+        rope base, the plain softmax scale, the head gate, the LoRA
+        rescale as ``apply_mla_qkv_lora_rescale`` says."""
         pre = "swa_" if kind == WINDOW else ""
         get = lambda key: getattr(self, pre + key)   # noqa: E731
         return dict(
             heads=get("num_attention_heads"), q_rank=get("q_lora_rank"),
             rank=get("kv_lora_rank"), nope=get("qk_nope_head_dim"),
             rope=get("qk_rope_head_dim"), v=get("v_head_dim"),
-            theta=get("rope_theta"))
+            theta=get("rope_theta"),
+            scale=(get("qk_nope_head_dim") + get("qk_rope_head_dim")) ** -0.5,
+            gate=True, lora_rescale=self.apply_mla_qkv_lora_rescale)
 
 
 def _dense(features, x, params_dtype, name):
@@ -136,12 +147,22 @@ def _dense(features, x, params_dtype, name):
                     kernel_init=nn.initializers.normal(0.02), name=name)(x)
 
 
-def _rope(t, theta: float, position, width: int):
+def _rope(t, theta, position, width: int):
     """Rotate the first ``width`` channels of ``t [s, b, h, d]`` at
-    positions ``position .. position + s`` (a scalar, or one a lane)."""
+    positions ``position .. position + s`` (a scalar, or one a lane).
+    ``theta`` is a plain base, or :class:`RopeParameters` whose table gives
+    the frequencies (YaRN's) and the factor on cos and sin."""
+    table = isinstance(theta, RopeParameters)
+    if table:
+        inv, factor = theta.table(width)
     offset = jnp.asarray(0 if position is None else position, jnp.float32)
     at = jnp.arange(t.shape[0], dtype=jnp.float32)[:, None] + offset.reshape(
         1, -1)                                             # [s, 1 | b]
+    if table:
+        angles = at[..., None] * inv
+        angles = jnp.concatenate([angles, angles], axis=-1)[:, :, None]
+        return fused_apply_rotary_pos_emb_cached(
+            t, factor * jnp.cos(angles), factor * jnp.sin(angles))
     inv = 1.0 / theta ** (jnp.arange(0, width, 2, dtype=jnp.float32) / width)
     angles = at[..., None] * inv
     return fused_apply_rotary_pos_emb(
@@ -149,12 +170,12 @@ def _rope(t, theta: float, position, width: int):
 
 
 class LatentAttention(nn.Module):
-    """Multi-head latent attention of one ``kind``, gated by head::
+    """Multi-head latent attention of one ``kind``::
 
         c_q = r_q RMSNorm(x W_qa);  q_h = c_q W_qb = [nope | rope], rope on it
         [c_kv | k_r] = x W_kva;  c_kv <- r_kv RMSNorm(c_kv);  rope on k_r
         [k_h^nope | v_h] = c_kv W_kvb;  k_h = [k_h^nope | k_r]
-        o_h = sigmoid(x W_g)_h softmax_s(q_h . k_{h,s} / sqrt(nope + rope)) v_s
+        o_h = g_h softmax_s(q_h . k_{h,s} scale) v_s
         y = concat_h(o_h) W_o
 
     over the keys ``s`` the kind allows: a ``full_attention`` layer the
@@ -162,10 +183,16 @@ class LatentAttention(nn.Module):
     k^I_s)`` of ``s <= t`` (``q^I = c_q W^I_q`` by head, ``k^I =
     LayerNorm(x W^I_k)``, both with rope on their first ``qk_rope_head_dim``
     channels, ``w = x W^I_w``), a ``sliding_attention`` layer ``t -
-    sliding_window_size < s <= t``.  A slot keeps ``[c_kv | k_r]`` a token
-    (and ``k^I`` where keys are selected)."""
+    sliding_window_size < s <= t``, an ``every_row`` layer every ``s <= t``.
+    ``config.attention(kind)`` gives the sizes, the rope (a base or
+    :class:`RopeParameters`), ``scale``, whether ``g_h = sigmoid(x W_g)_h``
+    gates the heads (else 1) and whether ``r_q = (hidden / q_rank)^1/2``,
+    ``r_kv = (hidden / rank)^1/2`` rescale the LoRA norms (else 1).  A slot
+    keeps ``[c_kv | k_r]`` a token (and ``k^I`` where keys are selected)."""
 
-    config: Dots3NoteConfig
+    # Dots3NoteConfig, or another family's with ``attention(kind)`` and
+    # ``rms_norm_eps`` (XingConfig)
+    config: Any
     kind: str
     params_dtype: Any = jnp.float32
 
@@ -179,23 +206,24 @@ class LatentAttention(nn.Module):
                                      a["rope"], a["v"])
         s, b, hidden = x.shape
         dt = self.params_dtype
-        scale = (nope + dr) ** -0.5
-        r_q = r_kv = 1.0
-        if cfg.apply_mla_qkv_lora_rescale:
-            r_q, r_kv = ((hidden / a["q_rank"]) ** 0.5,
-                         (hidden / rank) ** 0.5)
+        scale = a["scale"]
 
-        def norm(t, name):
-            return FusedRMSNorm((t.shape[-1],), eps=cfg.rms_norm_eps,
-                                param_dtype=jnp.float32, name=name)(t)
+        def norm(t, name, rescale):
+            """RMSNorm, times ``(hidden / width)^1/2`` where the kind
+            rescales its LoRA norms."""
+            out = FusedRMSNorm((t.shape[-1],), eps=cfg.rms_norm_eps,
+                               param_dtype=jnp.float32, name=name)(t)
+            if a["lora_rescale"]:
+                out = rescale * out
+            return out.astype(x.dtype)
 
-        c_q = (r_q * norm(_dense(a["q_rank"], x, dt, "q_a_proj"),
-                          "q_a_norm")).astype(x.dtype)
+        c_q = norm(_dense(a["q_rank"], x, dt, "q_a_proj"), "q_a_norm",
+                   (hidden / a["q_rank"]) ** 0.5)
         q = _dense(heads * (nope + dr), c_q, dt, "q_b_proj").reshape(
             s, b, heads, nope + dr)
         q_rope = _rope(q[..., nope:], a["theta"], position, dr)
         kv_a = _dense(rank + dr, x, dt, "kv_a_proj")
-        c_kv = (r_kv * norm(kv_a[..., :rank], "kv_a_norm")).astype(x.dtype)
+        c_kv = norm(kv_a[..., :rank], "kv_a_norm", (hidden / rank) ** 0.5)
         k_r = _rope(kv_a[..., None, rank:], a["theta"], position, dr)[:, :, 0]
         rows = jnp.concatenate([c_kv, k_r], axis=-1)       # [s, b, rank + dr]
         w_kvb = self.param("kv_b_proj", nn.initializers.normal(0.02),
@@ -240,7 +268,7 @@ class LatentAttention(nn.Module):
                                w_kvb[..., :nope],
                                preferred_element_type=jnp.float32)
             q_full = jnp.concatenate([q_abs.astype(x.dtype), q_rope[0]], -1)
-            if select is None:
+            if self.kind == WINDOW:
                 ctx, kv_cache = ring_decode_attend(
                     kv_cache, layer_idx, q_full, rows[0], position,
                     scale=scale, rank=rank, window=cfg.sliding_window_size)
@@ -248,7 +276,8 @@ class LatentAttention(nn.Module):
                 ctx, kv_cache = latent_decode_attend(
                     kv_cache, layer_idx, q_full, rows[0], position,
                     scale=scale, rank=rank,
-                    select=dict(select, q=q_i[0], w=w_i[0], key=k_i[0]))
+                    select=None if select is None else
+                    dict(select, q=q_i[0], w=w_i[0], key=k_i[0]))
             ctx = jnp.einsum("bhr,rhd->bhd", ctx.astype(x.dtype),
                              w_kvb[..., nope:],
                              preferred_element_type=jnp.float32)[None]
@@ -266,7 +295,7 @@ class LatentAttention(nn.Module):
             q_full = jnp.concatenate([q[..., :nope], q_rope], -1)[:, 0]
             # what ``expand`` is made of: the seam reads through it itself
             expansion = {"w": w_kvb, "nope": nope}
-            if select is None:
+            if self.kind == WINDOW:
                 ctx, kv_cache = ring_prefill_attend(
                     kv_cache, layer_idx, slot, q_full, rows[:, 0], offset,
                     length, scale=scale, expand=expansion,
@@ -275,12 +304,14 @@ class LatentAttention(nn.Module):
                 ctx, kv_cache = latent_prefill_attend(
                     kv_cache, layer_idx, slot, q_full, rows[:, 0], offset,
                     scale=scale, expand=expansion,
-                    select=dict(select, q=q_i[:, 0], w=w_i[:, 0],
-                                key=k_i[:, 0]))
+                    select=None if select is None else
+                    dict(select, q=q_i[:, 0], w=w_i[:, 0], key=k_i[:, 0]))
             ctx = ctx[:, None]                             # [s, 1, H, dv]
-        gate = jax.nn.sigmoid(
-            _dense(heads, x, dt, "gate_proj").astype(jnp.float32))
-        ctx = (ctx * gate[..., None]).astype(x.dtype).reshape(s, b, heads * dv)
+        if a["gate"]:
+            gate = jax.nn.sigmoid(
+                _dense(heads, x, dt, "gate_proj").astype(jnp.float32))
+            ctx = ctx * gate[..., None]
+        ctx = ctx.astype(x.dtype).reshape(s, b, heads * dv)
         return _dense(hidden, ctx, dt, "o_proj"), kv_cache
 
     def _uncached(self, q_nope, q_rope, rows, expand, scale, select):
@@ -294,9 +325,11 @@ class LatentAttention(nn.Module):
         out = []
         for lane in range(b):
             k, v = expand(rows[:, lane])
-            if select is None:
+            if self.kind == WINDOW:
                 mask = seen & (at[None] > at[:, None]
                                - self.config.sliding_window_size)
+            elif select is None:
+                mask = seen
             else:
                 dots = jnp.einsum("tjd,sd->tjs", select["q"][:, lane],
                                   select["key"][:, lane],

@@ -1,7 +1,9 @@
 """Xing4.0 causal LM (``model_type`` ``xing4_0``): DeepSeek-V3's multi-head
 latent attention in every layer - K and V of all heads expanded from one
 compressed row a token beside one rope key all heads share, a query LoRA,
-YaRN, every earlier row attended (no selector, no window) - and a residual
+YaRN, every earlier row attended (no selector, no window, no head gate:
+dots3's :class:`~apex_tpu.models.dots3.LatentAttention` of kind
+``every_row``, sized by :meth:`XingConfig.attention`) - and a residual
 stream ``hc_mult`` streams wide, mixed around each sublayer by
 manifold-constrained hyper-connections (mHC, arXiv:2512.24880).  The first
 ``first_k_dense_replace`` layers have a SwiGLU MLP, the others sigmoid-routed
@@ -46,7 +48,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.models.dots3 import GatedMLP
+from apex_tpu.models.dots3 import EVERY_ROW, GatedMLP, LatentAttention
 from apex_tpu.models.mellum import RopeParameters
 from apex_tpu.normalization import FusedRMSNorm
 from apex_tpu.obs.scopes import (
@@ -58,7 +60,6 @@ from apex_tpu.obs.scopes import (
     RESIDUAL,
     component,
 )
-from apex_tpu.ops.rope import fused_apply_rotary_pos_emb_cached
 from apex_tpu.transformer.moe import MOE_COUNTERS, GatedMoE
 from apex_tpu.transformer.parallel_state import TENSOR_PARALLEL_AXIS
 from apex_tpu.transformer.tensor_parallel import (
@@ -121,24 +122,16 @@ class XingConfig:
             yarn_mscale(self.rope_factor, self.mscale)
             / yarn_mscale(self.rope_factor, self.mscale_all_dim))
 
-
-def _dense(features, x, params_dtype, name):
-    return nn.Dense(features, use_bias=False, dtype=x.dtype,
-                    param_dtype=params_dtype,
-                    kernel_init=nn.initializers.normal(0.02), name=name)(x)
-
-
-def _rope(t, rope: RopeParameters, position):
-    """Rotate all channels of ``t [s, b, h, d]`` (rotate-half) at positions
-    ``position .. position + s`` (a scalar, or one a lane)."""
-    inv, factor = rope.table(t.shape[-1])
-    offset = jnp.asarray(0 if position is None else position, jnp.float32)
-    at = jnp.arange(t.shape[0], dtype=jnp.float32)[:, None] + offset.reshape(
-        1, -1)                                             # [s, 1 | b]
-    angles = at[..., None] * inv
-    angles = jnp.concatenate([angles, angles], axis=-1)[:, :, None]
-    return fused_apply_rotary_pos_emb_cached(
-        t, factor * jnp.cos(angles), factor * jnp.sin(angles))
+    def attention(self, kind: str) -> dict:
+        """What :class:`~apex_tpu.models.dots3.LatentAttention` reads of
+        every layer (kind ``every_row``): DeepSeek-V3's latent attention
+        with YaRN's rope and softmax scale, no head gate, no LoRA
+        rescale."""
+        return dict(
+            heads=self.num_attention_heads, q_rank=self.q_lora_rank,
+            rank=self.kv_lora_rank, nope=self.qk_nope_head_dim,
+            rope=self.qk_rope_head_dim, v=self.v_head_dim, theta=self.rope(),
+            scale=softmax_scale(self), gate=False, lora_rescale=False)
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -233,109 +226,6 @@ class HyperConnection(nn.Module):
         return fn(x, phi, alpha, bias, self.config)
 
 
-class XingAttention(nn.Module):
-    """DeepSeek-V3's multi-head latent attention::
-
-        c_q = RMSNorm(x W_qa);  q_h = c_q W_qb = [nope | rope], rope on it
-        [c_kv | k_r] = x W_kva;  c_kv <- RMSNorm(c_kv);  rope on k_r
-        [k_h^nope | v_h] = c_kv W_kvb;  k_h = [k_h^nope | k_r]
-        o_h = softmax_{s <= t}(q_h . k_{h,s} softmax_scale) v_s
-        y = concat_h(o_h) W_o
-
-    rope under YaRN; a slot keeps ``[c_kv | k_r]`` a token."""
-
-    config: XingConfig
-    params_dtype: Any = jnp.float32
-
-    @nn.compact
-    @component(ATTN_PROJ)
-    def __call__(self, x, *, kv_cache=None, layer_idx=None, position=None,
-                 slot=None, length=None):
-        cfg = self.config
-        heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
-        nope, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                        cfg.v_head_dim)
-        s, b, hidden = x.shape
-        dt = self.params_dtype
-        scale = softmax_scale(cfg)
-        rope = cfg.rope()
-
-        def norm(t, name):
-            return FusedRMSNorm((t.shape[-1],), eps=cfg.rms_norm_eps,
-                                param_dtype=jnp.float32, name=name)(t)
-
-        c_q = norm(_dense(cfg.q_lora_rank, x, dt, "q_a_proj"),
-                   "q_a_norm").astype(x.dtype)
-        q = _dense(heads * (nope + dr), c_q, dt, "q_b_proj").reshape(
-            s, b, heads, nope + dr)
-        q_rope = _rope(q[..., nope:], rope, position)
-        kv_a = _dense(rank + dr, x, dt, "kv_a_proj")
-        c_kv = norm(kv_a[..., :rank], "kv_a_norm").astype(x.dtype)
-        k_r = _rope(kv_a[..., None, rank:], rope, position)[:, :, 0]
-        rows = jnp.concatenate([c_kv, k_r], axis=-1)       # [s, b, rank + dr]
-        w_kvb = self.param("kv_b_proj", nn.initializers.normal(0.02),
-                           (rank, heads, nope + dv), dt).astype(x.dtype)
-
-        if kv_cache is None:
-            ctx = _uncached(jnp.concatenate([q[..., :nope], q_rope], -1),
-                            rows, w_kvb, nope, scale)
-        elif s == 1:
-            from apex_tpu.serving.kv_cache import latent_decode_attend
-
-            # absorbed: the query through W_kvb's key half, then against
-            # the rows as stored; W_kvb's value half after the sum
-            q_abs = jnp.einsum("bhd,rhd->bhr", q[0, ..., :nope],
-                               w_kvb[..., :nope],
-                               preferred_element_type=jnp.float32)
-            q_full = jnp.concatenate([q_abs.astype(x.dtype), q_rope[0]], -1)
-            ctx, kv_cache = latent_decode_attend(
-                kv_cache, layer_idx, q_full, rows[0], position, scale=scale,
-                rank=rank, select=None)
-            ctx = jnp.einsum("bhr,rhd->bhd", ctx.astype(x.dtype),
-                             w_kvb[..., nope:],
-                             preferred_element_type=jnp.float32)[None]
-        else:
-            from apex_tpu.serving.kv_cache import latent_prefill_attend
-
-            if b != 1:
-                raise ValueError(f"prefill expects one slot per call "
-                                 f"(b=1), got b={b}")
-            offset = jnp.asarray(0 if position is None else position,
-                                 jnp.int32)
-            q_full = jnp.concatenate([q[..., :nope], q_rope], -1)[:, 0]
-            ctx, kv_cache = latent_prefill_attend(
-                kv_cache, layer_idx, slot, q_full, rows[:, 0], offset,
-                scale=scale, expand={"w": w_kvb, "nope": nope}, select=None)
-            ctx = ctx[:, None]                             # [s, 1, H, dv]
-        ctx = ctx.astype(x.dtype).reshape(s, b, heads * dv)
-        return _dense(hidden, ctx, dt, "o_proj"), kv_cache
-
-
-def _uncached(q, rows, w_kvb, nope: int, scale: float):
-    """The whole sequence at once in plain ``jax.numpy``, K and V explicit,
-    causal: ``q [s, b, H, dk]``, ``rows [s, b, rank + rope]``.  The tests'
-    path."""
-    s, b = rows.shape[:2]
-    rank, heads = w_kvb.shape[:2]
-    at = jnp.arange(s)
-    seen = at[None] <= at[:, None]
-    out = []
-    for lane in range(b):
-        stored = rows[:, lane]
-        kv = jnp.einsum("nr,rhd->nhd", stored[:, :rank], w_kvb,
-                        preferred_element_type=jnp.float32
-                        ).astype(stored.dtype)
-        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-            stored[:, None, rank:], (s, heads, stored.shape[-1] - rank))], -1)
-        sc = jnp.einsum("thd,shd->hts", q[:, lane], k,
-                        preferred_element_type=jnp.float32) * scale
-        probs = jax.nn.softmax(jnp.where(seen[None], sc, -jnp.inf), -1)
-        out.append(jnp.einsum("hts,shd->thd", probs.astype(kv.dtype),
-                              kv[..., nope:],
-                              preferred_element_type=jnp.float32))
-    return jnp.stack(out, axis=1)                          # [s, b, H, dv]
-
-
 class XingLayer(nn.Module):
     """The attention, then the MLP or the experts, each wrapped by its own
     hyper-connection: ``x [s, b, hc_mult, hidden]`` float32 in and out."""
@@ -372,7 +262,8 @@ class XingLayer(nn.Module):
                     s, lanes, n, hidden)
 
         post, res, h = enter("attn_hc")
-        out, kv_cache = XingAttention(cfg, params_dtype=dt, name="self_attn")(
+        out, kv_cache = LatentAttention(cfg, EVERY_ROW, params_dtype=dt,
+                                        name="self_attn")(
             norm(h, "input_layernorm"), kv_cache=kv_cache, layer_idx=i,
             position=position, slot=slot, length=length)
         x = leave(post, res, out)

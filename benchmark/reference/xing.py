@@ -40,8 +40,9 @@ qk_nope_head_dim``, ``rope = qk_rope_head_dim``)::
 
 rope: rotate-half over the ``rope`` channels, YaRN frequencies
 (``rope_scaling``: ``factor``, ``original_max_position_embeddings``,
-``beta_fast``, ``beta_slow``; ``ops/rope.py::yarn_inv_freq``'s ramp), cos and
-sin times ``m(mscale) / m(mscale_all_dim)`` (1 as published).
+``beta_fast``, ``beta_slow``; the ramp ``reference/mellum.py::inv_freq``
+writes out), cos and sin times ``m(mscale) / m(mscale_all_dim)`` (1 as
+published).
 
 MLP: the first ``first_k_dense_replace`` layers SwiGLU ``C ->
 intermediate_size -> C``; every other layer::
@@ -72,7 +73,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.ops.rope import yarn_inv_freq
+# YaRN's ramp, written out in Mellum's reference
+from benchmark.reference.mellum import inv_freq
 # the sigmoid top-k routing with a selection bias is Nemotron-H's, key for key
 from benchmark.reference.nemotron_h import route, router_scores  # noqa: F401
 
@@ -103,10 +105,11 @@ def _rope(x, config: dict):
     rotate-half, YaRN."""
     y = config["rope_scaling"]
     width = x.shape[-1]
-    inv = yarn_inv_freq(width, config["rope_theta"], factor=y["factor"],
-                        original_max_position_embeddings=y[
-                            "original_max_position_embeddings"],
-                        beta_fast=y["beta_fast"], beta_slow=y["beta_slow"])
+    inv, _ = inv_freq({"rope_type": "yarn", "rope_theta": config["rope_theta"],
+                       "attention_factor": 1.0,
+                       **{k: y[k] for k in (
+                           "factor", "original_max_position_embeddings",
+                           "beta_fast", "beta_slow")}}, width)
     gain = (mscale(y["factor"], y["mscale"])
             / mscale(y["factor"], y["mscale_all_dim"]))
     ang = jnp.arange(x.shape[0], dtype=jnp.float32)[:, None] * inv
@@ -327,9 +330,24 @@ def _head(h, table):
     return parts.transpose(1, 0, 2).reshape(h.shape[0], vocab)
 
 
-def logits_at(params, ids, positions, config: dict, *, held: int = 0):
+def margins(h, mlp, config: dict):
+    """Each row's routing margin: its ``num_experts_per_tok``-th largest
+    ``score + bias`` less the next one, float32 - how far the choice is from
+    a tie."""
+    k = config["num_experts_per_tok"]
+    top, _ = jax.lax.top_k(router_scores(h, mlp["router_kernel"])
+                           + mlp["router_bias"].astype(jnp.float32), k + 1)
+    return top[:, k - 1] - top[:, k]
+
+
+def logits_at(params, ids, positions, config: dict, *, held: int = 0,
+              with_margins: bool = False):
     """Next-token logits ``[len(positions), vocab]`` of the causal forward
-    over one sequence ``ids [s]``, at the given positions."""
+    over one sequence ``ids [s]``, at the given positions; with
+    ``with_margins`` also each position's routing margin in each expert
+    layer, ``[len(positions), expert layers]``."""
+    at = jnp.asarray(positions)
+    found = []
     with jax.default_matmul_precision("highest"):
         p = params["params"]
         x = streams(embed(params, ids), config)
@@ -337,7 +355,11 @@ def logits_at(params, ids, positions, config: dict, *, held: int = 0):
             layer = p[f"layers_{i}"]
             x = attention_sublayer(x, layer, config)
             post, res, h = mlp_rows(x, layer, config)
+            if with_margins and i >= config["first_k_dense_replace"]:
+                found.append(margins(h[at], layer["mlp"], config))
             x = sublayer_out(x, post, res,
                              mlp_out(h, layer, i, config, held=held))
-        x = x[jnp.asarray(positions)]
-        return _head(final_rows(x, p, config), p["lm_head"])
+        logits = _head(final_rows(x[at], p, config), p["lm_head"])
+    if not with_margins:
+        return logits
+    return logits, jnp.stack(found, axis=-1)

@@ -147,8 +147,8 @@ def test_the_cells_are_the_issues_traffic(benchmark_manifest):
     assert mlad["prompt_len"] == {"dist": "uniform", "min": 18432,
                                   "max": 22528}
     assert mlad["output_len"] == {"dist": "fixed", "value": 512}
-    assert (mlad["check"]["prompt_len"], mlad["check"]["decode_tokens"]) == (
-        4096, 8)
+    assert (mlad["check"]["prompt_len"], mlad["check"]["decode_tokens"],
+            mlad["check"]["routing_margin"]) == (4096, 28, 0.01)
     rag, chat = (_load(f"benchmark/traffic/{t}.json")
                  for t in ("rag-closed", "chat-closed"))
     assert (rag["runner"], rag["clients"], rag["engine"]) == (
@@ -353,11 +353,46 @@ def test_reference_check_passes_the_system_as_built(tiny):
     res = _check(tiny)
     assert res["reference_ok"], res
     # every logit the engine handed back is printed: the last row of each of
-    # four chunks (16 + 16 + 16 + a padded 11) and four steps; two decide
+    # four chunks (16 + 16 + 16 + a padded 11) and four steps
     assert len(res["reference_rel_err_each"]) == 8
     assert max(res["reference_rel_err_each"]) < 1e-5
-    # one number over the eight vectors stacked decides
+    # one number decides: at the toy's routing margin 0 every position is
+    # decided, and the largest reading is the number
+    assert res["reference_positions_decided"] == 8
+    assert res["reference_rel_err"] == pytest.approx(
+        max(res["reference_rel_err_each"]), rel=1e-2)
     assert res["reference_rel_err"] < 1e-5
+    assert res["reference_rel_err_stacked"] < 1e-5
+
+
+@pytest.mark.parametrize("case, readings, margins, worst, ok", [
+    # a far reading where the reference's routing is near a tie is left out
+    ("near_tie_left_out", [0.01, 0.3, 0.02, 0.01], [0.02, 0.001, 0.02, 0.5],
+     0.02, True),
+    # ... and counts where every expert layer's choice is decided
+    ("decided_counts", [0.01, 0.3, 0.02, 0.01], [0.02, 0.011, 0.02, 0.5],
+     0.3, False),
+    # no position decided: the median reading decides
+    ("none_decided", [0.01, 0.3, 0.02, 0.4], [0.0] * 4, 0.16, False),
+    ("none_decided_most_close", [0.01, 0.3, 0.02, 0.01], [0.0] * 4, 0.015,
+     True)])
+def test_judge_counts_the_positions_the_reference_routes_decisively(
+        case, readings, margins, worst, ok):
+    from benchmark.runners import serve_mhc as sm
+
+    traffic = {"engine": {"prefill_len": 1},
+               "check": {"prompt_len": 4, "decode_tokens": 0,
+                         "routing_margin": 0.01, "tolerance": 0.05}}
+    want = np.ones((4, 3), np.float32)
+    # a difference along one logit of the reading's size times the norm
+    got = want + np.asarray(readings, np.float32)[:, None] * np.sqrt(
+        3.0) * np.eye(3, dtype=np.float32)[0]
+    # two expert layers: a position's margin is its smaller one
+    res = sm.judge(got, want, np.stack([np.asarray(margins) + 1.0,
+                                        np.asarray(margins)], axis=-1),
+                   traffic)
+    assert res["reference_rel_err"] == pytest.approx(worst, rel=1e-3), case
+    assert res["reference_ok"] is ok, case
 
 
 @pytest.mark.parametrize("fault", [
@@ -440,16 +475,21 @@ def test_traced_rehearsal_reports_the_counters(traced):
 
 def test_lower_precision_tool_reads_far_above_the_tolerance():
     """The tolerance's two readings, on the CPU at toy size: the system's
-    own check reads 1e-7 and float8 weights read percents."""
+    own check reads 1e-7 and float8 weights read percents, which the
+    runner's own verdict refuses; in float32 the system's programs choose
+    the reference's experts at every position."""
     proc = subprocess.run(
         [sys.executable, "benchmark/tools/lower_precision_mhc.py",
          "--manifest", MANIFEST, "--workload", CELL, "--seeds", "5",
-         "--e4m3", "5", "--rehearse"],
+         "--e4m3", "5", "--routes", "5", "--rehearse"],
         cwd=ROOT, capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["reference_ok"] is True
-    for at in ("", "_first_token", "_after_decode"):
+    assert line["reference_ok"] is True and line["e4m3_weights_ok"] is False
+    for at in ("", "_stacked", "_first_token", "_after_decode"):
         assert line[f"reference_rel_err{at}"] < 1e-5
         assert line[f"e4m3_weights_rel_err{at}"] > 1e3 * line[
             "reference_tolerance"]
+    assert line["routes_differ_layers"] == [[]] * 8
+    assert len(line["routes_margin_min"]) == 8
+    assert min(line["routes_margin_min"]) > 0

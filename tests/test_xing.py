@@ -4,7 +4,8 @@ hyper-connections around each sublayer, sigmoid top-k experts with a shared
 expert) against the plain float32 reference, at toy widths on the CPU: the
 uncached forward; chunked prefill and then decode through the engine's cache
 against the reference's full forward; each part of the mathematics left out
-of the system fails that comparison; the read of a layer without a selector
+of the system fails that comparison; the rope at the published widths
+against the reference's own YaRN ramp; the read of a layer without a selector
 against a full masked read at every lane length."""
 
 import dataclasses
@@ -22,7 +23,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from apex_tpu import serving as sv  # noqa: E402
-from apex_tpu.models import xing  # noqa: E402
+from apex_tpu.models import dots3, mellum, xing  # noqa: E402
 from apex_tpu.serving import kv_cache as kvc  # noqa: E402
 from benchmark.reference import xing as ref  # noqa: E402
 from benchmark.runners import serve_mhc  # noqa: E402
@@ -205,3 +206,28 @@ def test_unselected_decode_read_is_a_full_masked_read():
     assert ctx.shape == (lanes, heads, rank)
     assert rel_err(ctx, want) < 1e-6
     assert cache.index.size == 0
+
+
+@pytest.mark.parametrize("ramp", ["yarn", "left_out"])
+def test_rope_matches_the_reference_at_the_published_widths(ramp,
+                                                            monkeypatch):
+    """The model's rope (YaRN at factor 64 over 64 channels) at every
+    position of the cell's ``max_len`` against the reference's rope, which
+    writes the ramp out itself; the model's frequencies without the ramp
+    are caught (at toy widths the scores are too flat to show them)."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "xing4-29b-l6.json")) as f:
+        real = json.load(f)
+    cfg = serve_mhc.build_model(real).config
+    if ramp == "left_out":
+        monkeypatch.setattr(mellum, "yarn_inv_freq", lambda dim, theta, **_:
+                            1.0 / theta ** (jnp.arange(0, dim, 2,
+                                                       dtype=jnp.float32)
+                                            / dim))
+    width = cfg.qk_rope_head_dim
+    x = jax.random.normal(jax.random.key(0), (24576, 1, 1, width))
+    err = rel_err(dots3._rope(x, cfg.rope(), None, width)[:, 0],
+                  ref._rope(x[:, 0], real))
+    # 5e-5 where right: the two write a frequency in forms an ulp apart,
+    # and an angle of 24,576 radians keeps that ulp
+    assert err < 1e-3 if ramp == "yarn" else err > 1e-2

@@ -395,16 +395,16 @@ class LlamaForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, labels=None, deterministic: bool = True,
                  *, kv_cache=None, position=None, slot=None, length=None,
-                 active=None):
+                 active=None, keep=None):
         """Forward pass; optionally in KV-cached serving mode.
 
         With ``kv_cache`` (what ``DecodeEngine`` builds from
         :meth:`cache_layers`) the call returns ``(logits, kv_cache)`` instead of logits/loss:
         ``input_ids [1, s>1]`` + ``slot`` (+ scalar ``position`` = the
         chunk's start offset, 0/None for a fresh prompt) prefills one
-        chunk of one slot — the serving engine slices the last real
-        row's logits for prefill and keeps EVERY row for speculative
-        verification — and ``input_ids [slots, 1]`` + ``position
+        chunk of one slot — the serving engine asks for the last real
+        row's logits for prefill (``keep``) and keeps EVERY row for
+        speculative verification — and ``input_ids [slots, 1]`` + ``position
         [slots]`` runs one batched decode step (see
         :class:`apex_tpu.serving.engine.DecodeEngine`).  ``labels``
         is a training-only argument and rejected in serving mode.  The
@@ -412,11 +412,15 @@ class LlamaForCausalLM(nn.Module):
         chunk's real rows) and ``active`` (a decode step's live lanes)
         are what ``DecodeEngine`` tells every model; K/V rows are hidden
         after the fact by the slot lengths, so this one ignores both.
+        ``keep`` (a traced int32 row index) cuts the hidden state to that
+        one row after the last layer: the final norm and the head run on
+        it alone and the logits are ``[1, b, vocab]``; ``None`` (training,
+        verification, reference checks) keeps every row.
         """
         del length, active
         cfg = self.config
-        if kv_cache is not None and labels is not None:
-            raise ValueError("kv_cache is a serving-mode argument; "
+        if labels is not None and (kv_cache is not None or keep is not None):
+            raise ValueError("kv_cache and keep are serving-mode arguments; "
                              "labels is training-only")
         with component(EMBED):
             x = VocabParallelEmbedding(
@@ -449,6 +453,8 @@ class LlamaForCausalLM(nn.Module):
             else:
                 x = layer(x, deterministic)
         with component(HEAD):
+            if keep is not None:
+                x = jax.lax.dynamic_slice_in_dim(x, keep, 1, axis=0)
             x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
                              param_dtype=self.params_dtype, name="norm")(x)
 

@@ -35,6 +35,7 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from apex_tpu.normalization import FusedRMSNorm
 from apex_tpu.obs.scopes import (
@@ -304,7 +305,10 @@ class MellumForCausalLM(nn.Module):
     returns ``(logits, kv_cache)``: ``input_ids [1, s > 1]`` + ``slot`` +
     scalar ``position`` + ``length`` prefills one chunk of one slot, of
     which the first ``length`` rows are real; ``input_ids [slots, 1]`` +
-    ``position [slots]`` + ``active [slots]`` runs one decode step."""
+    ``position [slots]`` + ``active [slots]`` runs one decode step.
+    ``keep`` (a traced int32 row index) cuts the hidden state to that one
+    row after the last layer, so that the final norm and the head run on it
+    alone and the logits are ``[1, b, vocab]``; ``None`` keeps every row."""
 
     config: MellumConfig
     params_dtype: Any = jnp.float32
@@ -330,7 +334,7 @@ class MellumForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, *, kv_cache=None, position=None, slot=None,
-                 length=None, active=None):
+                 length=None, active=None, keep=None):
         cfg = self.config
         if kv_cache is not None:
             s = input_ids.shape[1]
@@ -352,6 +356,8 @@ class MellumForCausalLM(nn.Module):
                 x, kv_cache=kv_cache, position=position, slot=slot,
                 length=length, active=active)
         with component(HEAD):
+            if keep is not None:
+                x = lax.dynamic_slice_in_dim(x, keep, 1, axis=0)
             x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.rms_norm_eps,
                              param_dtype=jnp.float32, name="norm")(x)
             head = self.param("lm_head", nn.initializers.normal(0.02),

@@ -445,7 +445,10 @@ class NemotronHForCausalLM(nn.Module):
     [1, s > 1]`` + ``slot`` + scalar ``position`` + ``length`` prefills one
     chunk of one slot, of which the first ``length`` rows are real;
     ``input_ids [slots, 1]`` + ``position [slots]`` + ``active [slots]``
-    runs one decode step."""
+    runs one decode step.
+    ``keep`` (a traced int32 row index) cuts the hidden state to that one
+    row after the last layer, so that the final norm and the head run on it
+    alone and the logits are ``[1, b, vocab]``; ``None`` keeps every row."""
 
     config: NemotronHConfig
     params_dtype: Any = jnp.float32
@@ -471,7 +474,7 @@ class NemotronHForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, *, kv_cache=None, position=None, slot=None,
-                 length=None, active=None):
+                 length=None, active=None, keep=None):
         cfg = self.config
         if kv_cache is not None:
             s = input_ids.shape[1]
@@ -495,6 +498,8 @@ class NemotronHForCausalLM(nn.Module):
                 x, kv_cache=kv_cache, layer_idx=cfg.index_among(i),
                 position=position, slot=slot, length=length, active=active)
         with component(HEAD):
+            if keep is not None:
+                x = lax.dynamic_slice_in_dim(x, keep, 1, axis=0)
             x = FusedRMSNorm((cfg.hidden_size,), eps=cfg.layer_norm_epsilon,
                              param_dtype=jnp.float32, name="norm_f")(x)
             head = self.param("lm_head", nn.initializers.normal(0.02),

@@ -47,6 +47,7 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from apex_tpu.models.dots3 import EVERY_ROW, GatedMLP, LatentAttention
 from apex_tpu.models.mellum import RopeParameters
@@ -307,7 +308,11 @@ class XingForCausalLM(nn.Module):
     returns ``(logits, kv_cache)``: ``input_ids [1, s > 1]`` + ``slot`` +
     scalar ``position`` + ``length`` prefills one chunk of one slot, of
     which the first ``length`` rows are real; ``input_ids [slots, 1]`` +
-    ``position [slots]`` + ``active [slots]`` runs one decode step."""
+    ``position [slots]`` + ``active [slots]`` runs one decode step.
+    ``keep`` (a traced int32 row index) cuts the streams to that one row
+    after the last layer, so that the read-out, the final norm and the head
+    run on it alone and the logits are ``[1, b, vocab]``; ``None`` keeps
+    every row."""
 
     config: XingConfig
     params_dtype: Any = jnp.float32
@@ -330,7 +335,7 @@ class XingForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, *, kv_cache=None, position=None, slot=None,
-                 length=None, active=None):
+                 length=None, active=None, keep=None):
         cfg = self.config
         if kv_cache is not None:
             s = input_ids.shape[1]
@@ -354,8 +359,10 @@ class XingForCausalLM(nn.Module):
                 cfg, i, params_dtype=self.params_dtype, name=f"layers_{i}")(
                 x, kv_cache=kv_cache, position=position, slot=slot,
                 length=length, active=active)
-        s, b = x.shape[:2]
         with component(RESIDUAL):
+            if keep is not None:
+                x = lax.dynamic_slice_in_dim(x, keep, 1, axis=0)
+            s, b = x.shape[:2]
             h = HyperConnection(cfg, read_out=True, name="head_hc")(
                 x.reshape(s * b, cfg.hc_mult, cfg.hidden_size))
             h = h.reshape(s, b, cfg.hidden_size).astype(e.dtype)

@@ -519,15 +519,15 @@ class DecodeEngine:
             # position (the next-token distribution after the final
             # chunk) + the filled cache.  The model is told the length
             # too: K/V rows of the padding are hidden afterwards by the
-            # commit below, a recurrent state must not take them in at all
+            # commit below, a recurrent state must not take them in at all.
+            # keep: the model cuts its hidden state to that one row before
+            # the final norm and the head, which never see the other rows
             logits, cache = model.apply(dq(params), ids, kv_cache=cache,
                                         slot=slot, position=offset,
-                                        length=length)
+                                        length=length, keep=length - 1)
             cache = commit_slot_length(cache, slot, offset + length)
             with component(HEAD):
-                last = lax.dynamic_index_in_dim(logits[:, 0, :], length - 1,
-                                                axis=0, keepdims=False)
-                return last.astype(jnp.float32), cache
+                return logits[0, 0].astype(jnp.float32), cache
 
         def _decode(params, cache, last, tokens, on_device, active):
             # tokens [slots] int32 (last sampled per slot); active [slots]
@@ -1175,8 +1175,11 @@ class DecodeEngine:
         n = len(tokens)
         bucket = self.bucket_for(n)      # raises on n < 1 / n too long
         offset = int(self._lengths_host[slot])
+        # head_rows: the rows the chunk program's head multiplies, the one
+        # kept row (a host constant: nothing is read back)
         with obs_trace.span("engine.prefill_chunk", slot=int(slot),
-                            bucket=bucket, tokens=n, offset=offset):
+                            bucket=bucket, tokens=n, offset=offset,
+                            head_rows=1):
             if offset + n > self.max_len:
                 raise ValueError(
                     f"chunk of {n} tokens at offset {offset} overruns "

@@ -813,3 +813,56 @@ def test_mla_chunk_reads_through_the_kernel_under_a_causal_mask(
     once a layer, on the stored rows."""
     text = mla_compiled("prefill").as_text()
     assert len(_kernel_calls(text, "latent_chunk_attention")) == MLA_LAYERS
+
+
+# -- the head of a prompt chunk on the one row the engine keeps -------------
+# ``_prefill`` asks the model for the chunk's last real row (``keep=``): the
+# final norm and the head run on that row alone, where they once multiplied
+# every row of the bucket and dropped all but one (5.05 ms of a 46.6 ms
+# 1,024-row chunk at a vocabulary of 131,072 on one v5e).  ``_verify`` keeps
+# every row: the acceptance reads each.  A vocabulary no other width of the model
+# shares, so that a dot of ``rows x vocab`` elements is the head's
+
+HEAD_CFG = LlamaConfig(vocab_size=1000, hidden_size=256, intermediate_size=384,
+                       num_hidden_layers=1, num_attention_heads=2,
+                       num_key_value_heads=1, max_position_embeddings=4096)
+
+
+def _dot_results(text):
+    """Element counts of the results of every ``dot`` and ``convolution``
+    of the compiled program, in fused computations too."""
+    return [math.prod(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(
+                r"= \w+\[([\d,]*)\]\S* (?:dot|convolution)\(", text)]
+
+
+@pytest.mark.parametrize("program", ["prefill", "verify"])
+def test_only_verify_runs_the_head_on_every_row(one_chip, program):
+    model = LlamaForCausalLM(HEAD_CFG)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    params = jax.tree.map(
+        lambda l: jnp.zeros(l.shape,
+                            jnp.bfloat16 if l.ndim >= 2 else l.dtype),
+        shapes)
+    engine = sv.DecodeEngine(model, params, slots=2, max_len=CHUNK,
+                             prefill_len=CHUNK, cache_dtype=jnp.bfloat16)
+    on_chip, arg = _placed(one_chip)
+    cache = on_chip(jax.eval_shape(lambda: init_cache(
+        model.cache_layers(), slots=2, max_len=MAX_LEN,
+        dtype=jnp.bfloat16)))
+    lower = engine._prefill if program == "prefill" else engine._verify
+    text = lower.lower(
+        on_chip(engine.params), cache, arg((1, CHUNK), jnp.int32),
+        arg((), jnp.int32), arg((), jnp.int32),
+        arg((), jnp.int32)).compile().as_text()
+    dots = _dot_results(text)
+    assert dots, "no dot found in the compiled text: the reader is stale"
+    every_row = CHUNK * HEAD_CFG.vocab_size
+    # on the chip one row's product is a multiply-reduce, no dot at all
+    if program == "prefill":
+        assert every_row not in dots, (
+            "the prefill program multiplies every row of its bucket by the "
+            "head: the chunk's kept row no longer reaches the model")
+    else:
+        assert every_row in dots

@@ -125,6 +125,8 @@ def test_three_steps_record_exactly_the_tables_spans(engine):
     chunks = [e["args"] for e in evs if e["name"] == "engine.prefill_chunk"]
     assert [(c["slot"], c["bucket"], c["tokens"]) for c in chunks] == [
         (0, engine.bucket_for(8), 8), (1, engine.bucket_for(9), 9)]
+    # the chunk program's head multiplies the one row the engine keeps
+    assert [c["head_rows"] for c in chunks] == [1, 1]
     decodes = [e["args"] for e in evs if e["name"] == "engine.decode"]
     assert [d["lanes"] for d in decodes] == [2, 2, 2]
     # kv_tokens = the host mirror's sum over the active lanes BEFORE the

@@ -431,6 +431,28 @@ SupervisorConfig(consistency_check_interval=K))` runs every K steps,
 persisted); an unrepairable desync (`ReplicaDesyncError`) counts as one
 unrecovered failure in the same escalation ladder as every other fault.
 """,
+    "models": """\
+## Serving call contract
+
+The five families `DecodeEngine` serves (`LlamaForCausalLM`,
+`NemotronHForCausalLM`, `Dots3NoteForCausalLM`, `MellumForCausalLM`,
+`XingForCausalLM`: those with `cache_layers()`) take the same keywords
+beside `input_ids`: `kv_cache=` (the cache the engine built from
+`cache_layers()`; the call then returns `(logits, kv_cache)`),
+`position=`, `slot=`, `length=` (a chunk's real rows), `active=` (a
+decode step's live lanes) and **`keep=`**.  `keep` is a traced int32
+row index: the model cuts its `[s, b, ...]` hidden state to that one
+row after the last layer and before everything per-row that follows it
+(the final RMSNorm and the LM head; in `XingForCausalLM` the read-out
+of the four streams too), and returns `[1, b, vocab]` logits - the
+same arithmetic as that row of the full forward, since those steps act
+on each token alone.  The layers before the cut run on every row, so
+caches, states and expert counters are written as without it.  The
+engine's prefill program passes the chunk's last real row; `keep=None`
+(the default) keeps every row, which is what training, speculative
+verification (acceptance reads each row) and the reference checks use.
+
+""",
     "serving": """\
 Serve a trained Llama from its resilience checkpoints: slotted KV-cached
 incremental decode plus continuous batching, with a *bounded* set of
@@ -660,6 +682,20 @@ grows; a length-bucketed cache *read* window would recover it but
 changes reduction extents (= forfeits bit-exactness vs the
 shape-stable forward) and multiplies the compile table — deliberately
 out of scope here.
+
+**The head runs on the one row a chunk hands back.**  `_prefill` calls
+the model with `keep=length - 1`: the model cuts its hidden state to
+the chunk's last real row after its last layer, so the final norm, the
+LM head (and `models.xing`'s read-out of its streams) run on that row
+alone and the program returns `[vocab]` float32 logits without ever
+computing the other rows' (at Xing's 131,072-token vocabulary a 1,024
+chunk's `[1024, vocab]` float32 logits are 0.54 GB).  Those steps act
+on each token alone, so the row is the same arithmetic as before.
+Under tp the cut comes before `parallel_lm_logits`: each rank's
+vocabulary shard is one row wide.  `_decode` is one row a lane already;
+`_verify` passes no `keep` and keeps every row, because acceptance
+reads each.  The `engine.prefill_chunk` span carries `head_rows`, the
+rows the chunk program's head multiplies (1), set on the host.
 
 ## Slot lifecycle and the prefill budget
 
@@ -1581,7 +1617,7 @@ before drafting and again after its decode).
 | `serving.readback` | THE blocking device read: this step's first tokens and the previous step's decoded tokens (everything in flight, at a settle point) | `what` = `decode` / `first_token` / `decode+first_token`; `lag` = 1 when a newer decode had already been enqueued |
 | `serving.finish` | token append + finish checks of what was just read | `finished` (set at exit) |
 | `serving.publish` | step counter, gauges, the `serving_step` event | — |
-| `engine.prefill_chunk` | `DecodeEngine.prefill_chunk`: input build and enqueue | `slot`, `bucket`, `tokens` |
+| `engine.prefill_chunk` | `DecodeEngine.prefill_chunk`: input build and enqueue | `slot`, `bucket`, `tokens`, `offset` (rows the slot held before the chunk), `head_rows` (rows the chunk program's head multiplies: 1) |
 | `engine.decode` | all of `DecodeEngine.decode`: checks, paging, enqueue | `lanes`, `kv_tokens` (cached tokens the active lanes attend, before the append) |
 | `engine.sample` | `DecodeEngine.sample` | — |
 | `engine.verify_draft` | `DecodeEngine.verify_draft` (its two reads included) | `slot`, `drafted` |

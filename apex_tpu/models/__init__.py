@@ -25,6 +25,11 @@ optimizers).  This package holds the production-shaped model definitions:
   attention over every earlier row under YaRN, a residual stream of
   ``hc_mult`` streams mixed around each sublayer by manifold-constrained
   hyper-connections, sigmoid-routed gated experts beside a shared expert.
+- :mod:`apex_tpu.models.zaya` — ZAYA1 decoder, serving only: compressed
+  convolutional attention over grouped K/V heads (queries and keys
+  convolved along the sequence and within each head, a convolution tail a
+  slot beside the K/V rows) and top-1 gated experts chosen by a router MLP
+  with a carry across layers, tied head.
 """
 
 from apex_tpu.models.dots3 import Dots3NoteConfig, Dots3NoteForCausalLM
@@ -40,10 +45,11 @@ from apex_tpu.models.mellum import MellumConfig, MellumForCausalLM
 from apex_tpu.models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
 from apex_tpu.models.vit import ViTConfig, ViTForImageClassification
 from apex_tpu.models.xing import XingConfig, XingForCausalLM
+from apex_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
 
 __all__ = ["Dots3NoteConfig", "Dots3NoteForCausalLM", "LlamaConfig", "LlamaForCausalLM", "LlamaPipeConfig",
            "build_llama_pipeline", "init_llama_pipeline_params",
            "make_llama_3d_train_step", "MellumConfig", "MellumForCausalLM",
            "NemotronHConfig",
            "NemotronHForCausalLM", "ViTConfig", "ViTForImageClassification",
-           "XingConfig", "XingForCausalLM"]
+           "XingConfig", "XingForCausalLM", "ZayaConfig", "ZayaForCausalLM"]

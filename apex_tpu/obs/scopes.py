@@ -34,7 +34,8 @@ scope               what is inside it
 ``apex.select``     a key selector's scores, thresholds / ``top_k`` and the
                     gather of the selected rows
 ``apex.state``      Mamba-2: convolution tail, chunked scan, one-token
-                    state update, a slot's state read
+                    state update, a slot's state read; a slot's
+                    convolution tail read (CCA)
 ``apex.mlp``        dense MLP, a shared expert
 ``apex.router``     router logits, top-k routing, the dispatch sort, the
                     call's counts
@@ -47,6 +48,10 @@ scope               what is inside it
                     iterations, the mix a sublayer reads, the combine it
                     writes back through, the expansion after the embedding
                     and the read-out before the final norm
+``apex.qk_mix``     compressed convolutional attention's mixing of queries
+                    and keys: the convolution along the sequence, the one
+                    within each head, the mean of queries and key a group
+                    adds back, the L2 norms and the per-head temperature
 ==================  ======================================================
 """
 
@@ -58,7 +63,7 @@ import jax
 
 __all__ = ["PREFIX", "VOCABULARY", "component", "EMBED", "NORM", "ATTN_PROJ",
            "CACHE_WRITE", "CACHE_READ", "SELECT", "STATE", "MLP", "ROUTER",
-           "EXPERTS", "HEAD", "SAMPLE", "RESIDUAL"]
+           "EXPERTS", "HEAD", "SAMPLE", "RESIDUAL", "QK_MIX"]
 
 PREFIX = "apex."
 
@@ -75,9 +80,10 @@ EXPERTS = "experts"
 HEAD = "head"
 SAMPLE = "sample"
 RESIDUAL = "residual"
+QK_MIX = "qk_mix"
 
 VOCABULARY = (EMBED, NORM, ATTN_PROJ, CACHE_WRITE, CACHE_READ, SELECT, STATE,
-              MLP, ROUTER, EXPERTS, HEAD, SAMPLE, RESIDUAL)
+              MLP, ROUTER, EXPERTS, HEAD, SAMPLE, RESIDUAL, QK_MIX)
 
 
 def _open(name: str):

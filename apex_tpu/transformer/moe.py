@@ -369,12 +369,16 @@ class GatedMoE(nn.Module):
     published ``top_k`` and weights - by ``scoring``, ``"sigmoid"`` with a
     selection bias (:func:`topk_sigmoid_route`: the chosen scores normalised
     over all ``top_k``, times ``routed_scaling_factor``) or ``"softmax"``
-    without one (:func:`topk_softmax_route`); this layer holds the
-    experts ``[experts_held[0], experts_held[0] + experts_held[1])`` and
-    computes ``sum over chosen e held here of w_e W_down_e (silu(W_gate_e x)
-    * W_up_e x)`` through :func:`held_pairs` and three grouped products;
-    what experts held elsewhere would add is left out.  ``x [tokens,
-    hidden]``, ``valid [tokens]``; returns ``(out, counts)``."""
+    without one (:func:`topk_softmax_route`), or ``"given"``: the model
+    routes itself (a router that is more than one linear map) and hands
+    ``route = (chosen [tokens, top_k] int32, weights [tokens, top_k]
+    float32)`` in, and this layer declares no router parameter; this layer
+    holds the experts ``[experts_held[0], experts_held[0] +
+    experts_held[1])`` and computes ``sum over chosen e held here of w_e
+    W_down_e (silu(W_gate_e x) * W_up_e x)`` through :func:`held_pairs` and
+    three grouped products; what experts held elsewhere would add is left
+    out.  ``x [tokens, hidden]``, ``valid [tokens]``; returns ``(out,
+    counts)``."""
 
     num_experts: int
     experts_held: Tuple[int, int]
@@ -387,7 +391,8 @@ class GatedMoE(nn.Module):
     scoring: str = "sigmoid"
 
     @nn.compact
-    def __call__(self, x, valid=None) -> Tuple[jax.Array, jax.Array]:
+    def __call__(self, x, valid=None,
+                 route=None) -> Tuple[jax.Array, jax.Array]:
         h = x.shape[1]
         held = _held_inside(self.experts_held, self.num_experts)
         normal = nn.initializers.normal(0.02)
@@ -397,11 +402,15 @@ class GatedMoE(nn.Module):
                             param_dtype=self.param_dtype, kernel_init=normal,
                             name=name)
 
-        if self.scoring not in ("sigmoid", "softmax"):
-            raise ValueError(f"scoring {self.scoring!r}: 'sigmoid' or "
-                             f"'softmax'")
-        router_kernel = self.param("router_kernel", normal,
-                                   (h, self.num_experts), jnp.float32)
+        if self.scoring not in ("sigmoid", "softmax", "given"):
+            raise ValueError(f"scoring {self.scoring!r}: 'sigmoid', "
+                             f"'softmax' or 'given'")
+        if (route is None) != (self.scoring != "given"):
+            raise ValueError("route= is handed in with scoring 'given' and "
+                             "with no other")
+        if self.scoring != "given":
+            router_kernel = self.param("router_kernel", normal,
+                                       (h, self.num_experts), jnp.float32)
         if self.scoring == "sigmoid":
             router_bias = self.param("router_bias", nn.initializers.zeros,
                                      (self.num_experts,), jnp.float32)
@@ -416,9 +425,11 @@ class GatedMoE(nn.Module):
                 chosen, weights = topk_sigmoid_route(
                     x, router_kernel, router_bias, self.top_k,
                     self.routed_scaling_factor)
-            else:
+            elif self.scoring == "softmax":
                 chosen, weights = topk_softmax_route(x, router_kernel,
                                                      self.top_k)
+            else:
+                chosen, weights = route
             pairs = held_pairs(chosen, self.experts_held, valid)
         with component(EXPERTS):
             rows = x[pairs.token_of]                          # [t k, hidden]

@@ -1,6 +1,6 @@
 """The scopes of ``apex_tpu/obs/scopes.py`` in the engine's compiled programs.
 
-Each of the six tiny serving configurations - the five families of the
+Each of the seven tiny serving configurations - the six families of the
 benchmark's tests and the Llama family behind a block table - has its
 ``_decode`` and its largest ``_prefill`` compiled here on the CPU, and the
 compiled text is read the way ``benchmark/lib/device_scopes.py`` reads a
@@ -45,6 +45,8 @@ FAMILIES = {
                EVERY | {"router", "experts"}),
     "xing": ("tiny-xing", "tiny-mladoc-closed", {},
              EVERY | {"mlp", "router", "experts", "residual"}),
+    "zaya": ("tiny-zaya", "tiny-reason-closed", {},
+             EVERY | {"state", "router", "experts", "qk_mix"}),
 }
 TIMED = ("dot", "fusion", "custom-call", "while", "sort")
 

@@ -866,3 +866,80 @@ def test_only_verify_runs_the_head_on_every_row(one_chip, program):
             "head: the chunk's kept row no longer reaches the model")
     else:
         assert every_row in dots
+
+
+# -- K/V rows beside a convolution tail: compressed convolutional attention
+# (ZAYA1) ---------------------------------------------------------------------
+# the reason cell's geometry (benchmark/configs/zaya1-8b-l10.json,
+# traffic/reason-closed.json): two layers at the published widths (8 query
+# and 2 K/V heads of 128, 16 experts of 2,048), 64 slots of 8,192 rows, a
+# 512-row chunk.  A small vocabulary: it keeps no rows.  The weights are
+# shapes alone: a program is lowered, nothing runs
+
+CCA_SLOTS, CCA_MAX_LEN, CCA_CHUNK, CCA_LAYERS = 64, 8192, 512, 2
+
+
+@pytest.fixture(scope="module")
+def cca_compiled(one_chip):
+    """``cca_compiled(program)``: the program compiled for the cell-sized
+    cache on the path the chip takes, once for the module."""
+    from apex_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
+
+    model = ZayaForCausalLM(ZayaConfig(vocab_size=256,
+                                       num_hidden_layers=CCA_LAYERS),
+                            params_dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    engine = sv.DecodeEngine(model, {"w": jnp.zeros((1,), jnp.bfloat16)},
+                             slots=2, max_len=64, prefill_len=64,
+                             cache_dtype=jnp.bfloat16)
+
+    def compiled(program):
+        on_chip, arg = _placed(one_chip)
+        cache = on_chip(jax.eval_shape(lambda: init_cache(
+            model.cache_layers(), slots=CCA_SLOTS, max_len=CCA_MAX_LEN,
+            dtype=jnp.bfloat16)))
+        assert cache.k.shape == (CCA_LAYERS, CCA_SLOTS, CCA_MAX_LEN, 2, 128)
+        assert cache.state.conv.shape == (CCA_LAYERS, CCA_SLOTS, 2688)
+        assert cache.state.ssm.size == 0
+        with mock.patch.object(_dispatch, "on_tpu", lambda: True):
+            if program == "decode":
+                lowered = engine._decode.lower(
+                    on_chip(shapes), cache,
+                    *_decode_vectors(arg, CCA_SLOTS))
+            else:
+                lowered = engine._prefill.lower(
+                    on_chip(shapes), cache, arg((1, CCA_CHUNK), jnp.int32),
+                    arg((), jnp.int32), arg((), jnp.int32),
+                    arg((), jnp.int32))
+        return lowered.compile()
+
+    return functools.cache(compiled)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_cca_programs_copy_no_rows_and_no_tails(cca_compiled, program):
+    """The K/V rows are read in place by the seam's kernels, one call a
+    layer, and neither they nor the convolution tails are cut out, turned
+    or copied whole: a decode step writes its lanes' tails into the donated
+    buffer, a chunk one slot's."""
+    text = cca_compiled(program).as_text()
+    kernel = ("cached_decode_attention" if program == "decode"
+              else "kv_chunk_attention")
+    assert len(_kernel_calls(text, kernel)) == CCA_LAYERS
+    slab = CCA_SLOTS * CCA_MAX_LEN * 2 * 128
+    found = _slab_sized_layout_copies(text, slab) + _slab_sized_cuts(
+        text, slab)
+    assert not found, f"{program} cuts or copies the K/V rows: {found}"
+    # a decode step reads one layer's tails of every lane, [64, 2688]: what
+    # it must; the whole buffer is every layer's
+    tails = re.compile(r"bf16\[%d,%d,2688\]" % (CCA_LAYERS, CCA_SLOTS))
+    moved = []
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if m and tails.search(m.group(2)) and (
+                m.group(3) in ("copy", "transpose", "slice")
+                or m.group(3) == "fusion" and m.group(1).startswith(
+                    ("copy", "transpose", "slice"))):
+            moved.append(line.strip()[:120])
+    assert not moved, f"{program} copies the tails whole: {moved}"

@@ -73,6 +73,7 @@ PAGES = {
         "apex_tpu.models.llama_pipeline", "apex_tpu.models.vit",
         "apex_tpu.models.nemotron_h", "apex_tpu.models.dots3",
         "apex_tpu.models.mellum", "apex_tpu.models.xing",
+        "apex_tpu.models.zaya",
     ]),
     "contrib": ("Contrib extensions", [
         "apex_tpu.contrib.xentropy", "apex_tpu.contrib.focal_loss",
@@ -434,9 +435,10 @@ unrecovered failure in the same escalation ladder as every other fault.
     "models": """\
 ## Serving call contract
 
-The five families `DecodeEngine` serves (`LlamaForCausalLM`,
+The six families `DecodeEngine` serves (`LlamaForCausalLM`,
 `NemotronHForCausalLM`, `Dots3NoteForCausalLM`, `MellumForCausalLM`,
-`XingForCausalLM`: those with `cache_layers()`) take the same keywords
+`XingForCausalLM`, `ZayaForCausalLM`: those with `cache_layers()`) take
+the same keywords
 beside `input_ids`: `kv_cache=` (the cache the engine built from
 `cache_layers()`; the call then returns `(logits, kv_cache)`),
 `position=`, `slot=`, `length=` (a chunk's real rows), `active=` (a
@@ -599,7 +601,7 @@ layers reach it through the seam's functions and name no cache class:
 |---|---|---|---|
 | `KVRows(kv_heads, head_dim)` | `[max_len, kv_heads, head_dim]` K and V | `decode_attend` / `prefill_attend` (a chunk's read is one of three, chosen once a trace from what is in hand: on a TPU, a dense float cache in the queries' dtype with heads, key blocks and chunk in whole tiles is walked a visible block at a time by the Pallas kernel `ops.kv_chunk_attention` over the slot's rows cut head-major once a call, at any extent - a 2,048-row slot's chunks as a 32,768-row slot's; everything else - the CPU, int8 rows, a block table, 64-wide heads, a verify's odd row count - attends the whole masked extent while its float32 scores stay within 128 MiB - 32 heads x 512 x 2,048 - and past that walks the visible blocks in a loop; the `kernel_dispatch` event `kv_chunk_attention` says kernel or not, the `read_dispatch` event `blocked_walk` or `full_extent`) | `models.llama`, `models.nemotron_h` (`*` layers), `models.mellum` (`full_attention` layers) |
 | `KVWindowRows(kv_heads, head_dim, window)` | a ring of `window` K and V rows in whole 16-row tiles, position `p` at row `p mod rows`, whatever `max_len` | `window_decode_attend` (append at `position mod rows`, then the in-place decode kernel on the ring buffers where the ring is exactly the window, else the ring under a mask) / `window_prefill_attend` (the `window - 1` rows before the chunk from the ring, the chunk's own from the ones in hand, then the chunk's last real rows into the ring) | `models.mellum` (`sliding_attention` layers) |
-| `RecurrentRows(ssm, conv)` | a float32 state and a convolution tail of fixed size | `slot_state` / `write_slot_state` / `write_lane_state` | `models.nemotron_h` (`M` layers) |
+| `RecurrentRows(ssm, conv)` | a float32 state and a convolution tail of fixed size | `slot_state` / `write_slot_state` / `write_lane_state` | `models.nemotron_h` (`M` layers), `models.zaya` (every layer: its convolution tail as `conv`, an empty `ssm`) |
 | `LatentRows(width, index_width, top_k)` | `[max_len, width]` latent rows (the compressed K/V and the shared rope key, stored in whole lane tiles) and `[max_len, index_width]` selector keys | `latent_decode_attend` (append, score the live rows' keys, `top_k`, gather, absorbed read) / `latent_prefill_attend` (chunk-write, blocked scores, the selection as a mask, blocked explicit read: on a TPU one Pallas kernel, `ops.latent_chunk_attention`, over the rows in place with a block's scores in fast memory, elsewhere a loop; the `kernel_dispatch` event `latent_chunk_attention` says which) | `models.dots3` (`full_attention` layers) |
 | `FullLatentRows(width)` | `[max_len, width]` latent rows as `LatentRows` stores them, no selector keys | `latent_decode_attend` / `latent_prefill_attend` with `select=None` (append, then every live row of each lane in the absorbed form, a block of 2,048 rows at a time up to the longest lane's length, in place; a chunk: chunk-write, then the read a selecting layer's chunk takes - kernel or loop - under the causal mask) | `models.xing` (every layer) |
 | `RingRows(width, window)` | a ring of `window` rows in whole 16-row tiles, position `p` at row `p mod rows`, whatever `max_len` | `ring_decode_attend` / `ring_prefill_attend` | `models.dots3` (`sliding_attention` layers) |
@@ -1656,13 +1658,14 @@ and the innermost `apex.<name>` of an instruction is its component.
 | `apex.cache_write` | append / chunk-write, ring writes, `write_slot_state` / `write_lane_state`, a slot's length | `serving/kv_cache.py`: inside every `*_attend` of the seam; `commit_slot_length` |
 | `apex.cache_read` | the read whichever path was chosen: the Pallas call **with its glue** (slot cut, head-major cut, casts, expansion), `cached_attention`, the blocked loops, the masked reads | the same functions; inside the chunk kernel's own `jit` too |
 | `apex.select` | a selector's scores, thresholds / `lax.top_k`, the gather of the selected rows | `latent_decode_attend`, `latent_prefill_attend` |
-| `apex.state` | Mamba-2: convolution tail, chunked scan, one-token state update, gated norm, `slot_state` | `Mamba2Mixer` |
+| `apex.state` | Mamba-2: convolution tail, chunked scan, one-token state update, gated norm, `slot_state`; CCA: a slot's convolution tail read | `Mamba2Mixer`, `models/zaya.py` |
 | `apex.mlp` | dense MLP, a shared expert, the residual add that closes the branch | `LlamaMLP`, `GatedMLP`, `LatentMoE` / `GatedMoE` |
 | `apex.router` | router logits, top-k routing, `held_pairs` (the dispatch sort), `add_counts` | `transformer/moe.py`, `kv_cache.add_counts` |
 | `apex.experts` | the grouped products (`grouped_matmul`), activation, weighting and combine, `LatentMoE`'s latent down / up | `transformer/moe.py` |
 | `apex.head` | final norm, the LM-head product, the logits the engine hands back | each served model's `__call__`, `DecodeEngine`'s programs |
 | `apex.sample` | the sampler | `serving/engine.py::_sample_one` |
 | `apex.residual` | a changed residual path (manifold-constrained hyper-connections): the streams' norm, the mappings' projection, sigmoids and Sinkhorn iterations, the mix a sublayer reads and the combine it writes back through, the expansion after the embedding, the read-out before the final norm | `models/xing.py` |
+| `apex.qk_mix` | compressed convolutional attention's mixing of queries and keys: the convolution along the sequence, the one within each head, the q-k mean added back, the L2 norms and the per-head temperature | `models/zaya.py` |
 
 **Where a running engine's device time goes.**  Capture a few steps -
 `obs.start_jax_profiler(logdir)` ... `obs.stop_jax_profiler()`, or

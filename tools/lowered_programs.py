@@ -1,7 +1,8 @@
 """Lowered text of the accepted engines' ``_decode`` and ``_prefill``
 programs at their cells' shapes, to compare two checkouts bit for bit (a
 change to ``serving/kv_cache.py`` that is not meant for the Mistral,
-Nemotron-H, dots3-note or Mellum cells leaves every file the same).
+Nemotron-H, dots3-note, Mellum or Xing4.0 cells leaves every file the
+same).
 
     python tools/lowered_programs.py <checkout> <out dir>     # once a tree
     diff -rq <out dir of the parent> <out dir of the change>
@@ -29,7 +30,9 @@ CELLS = {
     "dots3": ("benchmark/configs/dots3-note-ep8-l5.json",
               "benchmark/traffic/longdoc-closed.json"),
     "mellum": ("benchmark/configs/mellum2-12b-l8.json",
-               "benchmark/traffic/repo-closed.json")}
+               "benchmark/traffic/repo-closed.json"),
+    "xing": ("benchmark/configs/xing4-29b-l6.json",
+             "benchmark/traffic/mladoc-closed.json")}
 
 
 def main(root: str, out: str) -> None:
